@@ -325,6 +325,13 @@ def cmd_worker(args) -> int:
 
     _arm_crash_dump(args)
     cfg = _cfg(args, map_n=1)
+    if args.engine == "device":
+        # Take the chip now, not at the first task: a chip serves one
+        # process, and a second device worker must fail here, loudly —
+        # not hang, and not map on the CPU unnoticed.
+        from mapreduce_rust_tpu.runtime.driver import select_device
+
+        select_device(cfg.device)
     inputs, _bounds, _names = resolve_corpora(cfg)
     if getattr(args, "service", False):
         # Multi-job fleet member (ISSUE 14): app/inputs/dirs arrive

@@ -6,7 +6,7 @@ regression of that class is caught by ``python -m mapreduce_rust_tpu lint``
 in CI instead of by a human reading a heisenbug out of a crashed run.
 
 Rules are deliberately framework-specific: they know this repo's names
-(JobStats, ``_a2a_span``, ``Dictionary``, ``SHARD_MAP_NATIVE``) because
+(JobStats, ``_a2a_span``, ``Dictionary``) because
 the invariants are this framework's, not Python's. Precision beats recall:
 a rule that cries wolf gets baselined into silence, so every rule here is
 tuned to fire on the shipped bug pattern and stay quiet on the shipped
@@ -296,72 +296,6 @@ class TmpdirCleanupRule(Rule):
                     "between creation and cleanup leaks the directory into "
                     "a shared output/work dir",
                 )
-
-
-class DonationSafetyRule(Rule):
-    """donate_argnums on a shard_map computation must sit behind the
-    native-shard_map guard.
-
-    Incident: donating state buffers into the pre-0.6 experimental
-    ``shard_map`` corrupts the jaxlib 0.4.x CPU client heap (observed as a
-    glibc "corrupted double-linked list" under the spill-heavy mesh merge,
-    fixed in PR 1 by gating donation on ``_SHARD_MAP_NATIVE``). Donation is
-    a memory optimization, never a correctness requirement — unguarded it
-    is a latent heap corruption on every jax<0.6 image.
-    """
-
-    name = "donation-safety"
-    summary = "donate_argnums near shard_map must be gated on SHARD_MAP_NATIVE"
-
-    def run(self, tree, src, path):
-        # decorator Call → the FunctionDef it decorates
-        deco_owner: dict[int, ast.AST] = {}
-        for node in ast.walk(tree):
-            if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
-                for deco in node.decorator_list:
-                    for sub in ast.walk(deco):
-                        deco_owner[id(sub)] = node
-        for node in ast.walk(tree):
-            if not isinstance(node, ast.Call):
-                continue
-            if _kw(node, "donate_argnums") is None and _kw(node, "donate_argnames") is None:
-                continue
-            if self._guarded(node):
-                continue
-            owner = deco_owner.get(id(node))
-            near_shard_map = False
-            if owner is not None and any(
-                _mentions(d, "shard_map") for d in owner.decorator_list
-            ):
-                near_shard_map = True
-            else:
-                stmt = self._nearest_statement(node)
-                if stmt is not None and _mentions(stmt, "shard_map"):
-                    near_shard_map = True
-            if near_shard_map:
-                yield self.finding(
-                    path, node,
-                    "donate_argnums applied to a shard_map computation "
-                    "without the native-shard_map guard — donating into "
-                    "jax.experimental.shard_map corrupts the jaxlib 0.4.x "
-                    "heap; gate it on SHARD_MAP_NATIVE (see "
-                    "parallel/shuffle.py) or drop the donation",
-                )
-
-    def _guarded(self, node) -> bool:
-        for anc in ancestors(node):
-            test = None
-            if isinstance(anc, (ast.If, ast.IfExp)):
-                test = anc.test
-            if test is not None and _mentions(test, "SHARD_MAP_NATIVE", substring=True):
-                return True
-        return False
-
-    def _nearest_statement(self, node):
-        for anc in ancestors(node):
-            if isinstance(anc, ast.stmt):
-                return anc
-        return None
 
 
 class A2APurityRule(Rule):
@@ -2043,7 +1977,6 @@ ALL_RULES: list[Rule] = [
     StatsOwnershipRule(),
     ExecutorTeardownRule(),
     TmpdirCleanupRule(),
-    DonationSafetyRule(),
     A2APurityRule(),
     SpanBalanceRule(),
     SpilledDictApiRule(),

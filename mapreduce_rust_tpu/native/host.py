@@ -1,18 +1,22 @@
 """ctypes bridge to the native host scanner (loader.cpp).
 
 Builds the shared object on first use with g++ (no pybind11 in this image;
-the C ABI + ctypes keeps the binding dependency-free), caches it next to
-the source with an mtime check, and degrades gracefully: if the toolchain
-or compile is unavailable, callers fall back to the pure-Python path
-(runtime/dictionary.py works either way — tests cover both).
+the C ABI + ctypes keeps the binding dependency-free) and caches it next to
+the source under a name keyed by the source's digest and the host ISA
+(``-march=native`` code built on one CPU may SIGILL on another, and a copied
+tree keeps whatever was built before). If the toolchain or compile is
+unavailable, callers fall back to the pure-Python path (runtime/dictionary.py
+works either way — tests cover both); ``get_lib() is None`` says which.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import pathlib
+import platform
 import subprocess
 import threading  # noqa: F401 — thread-local scratch + build lock
 
@@ -21,28 +25,49 @@ import numpy as np
 log = logging.getLogger("mapreduce_rust_tpu.native")
 
 _SRC = pathlib.Path(__file__).with_name("loader.cpp")
-_SO = pathlib.Path(__file__).with_name("_mrnative.so")
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _tried = False
 
 
-def _build() -> bool:
+def host_isa() -> str:
+    """"<machine>:<cpu feature flags>" — what decides whether code compiled
+    for this host (``-march=native`` here, XLA's CPU AOT results in the
+    driver's compile cache) runs on another."""
     try:
-        if _SO.exists() and _SO.stat().st_mtime >= _SRC.stat().st_mtime:
-            return True
+        with open("/proc/cpuinfo") as f:
+            # x86 spells it "flags", aarch64 "Features" — either carries the
+            # ISA extensions whose mismatch makes a foreign binary crash.
+            flags = next(
+                (l for l in f if l.startswith(("flags", "Features"))), ""
+            )
+    except OSError:
+        flags = ""
+    return f"{platform.machine()}:{flags}"
+
+
+def _so_path() -> pathlib.Path:
+    h = hashlib.sha256(_SRC.read_bytes() + host_isa().encode()).hexdigest()[:16]
+    return _SRC.with_name(f"_mrnative-{h}.so")
+
+
+def _build() -> pathlib.Path | None:
+    try:
+        so = _so_path()
+        if so.exists():
+            return so
         # Compile to a per-process temp then atomically rename: concurrent
         # workers (README quickstart spawns several) must never observe a
         # half-written .so.
-        tmp = _SO.with_name(f".{_SO.name}.{os.getpid()}.tmp")
+        tmp = so.with_name(f".{so.name}.{os.getpid()}.tmp")
         cmd = ["g++", "-O3", "-march=native", "-shared", "-fPIC",
                "-o", str(tmp), str(_SRC)]
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _SO)
-        return True
+        os.replace(tmp, so)
+        return so
     except (OSError, subprocess.SubprocessError) as e:
         log.warning("native build unavailable (%s) — using Python fallback", e)
-        return False
+        return None
 
 
 def get_lib() -> ctypes.CDLL | None:
@@ -52,9 +77,10 @@ def get_lib() -> ctypes.CDLL | None:
             return _lib
         _tried = True
         try:
-            if not _build():
+            so = _build()
+            if so is None:
                 return None
-            lib = ctypes.CDLL(str(_SO))
+            lib = ctypes.CDLL(str(so))
             lib.mr_scan_unique.restype = ctypes.c_int64
             lib.mr_scan_unique.argtypes = [
                 ctypes.c_char_p, ctypes.c_int64,
@@ -103,8 +129,8 @@ def get_lib() -> ctypes.CDLL | None:
                 ctypes.c_int64,
             ]
         except (OSError, AttributeError) as e:
-            # AttributeError: a stale .so (fresh mtime, old ABI) missing a
-            # newer symbol must engage the Python fallback, not crash.
+            # AttributeError: a library missing a symbol this binding
+            # expects must engage the Python fallback, not crash.
             log.warning("native load failed (%s) — using Python fallback", e)
             return None
         _lib = lib

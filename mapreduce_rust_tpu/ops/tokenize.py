@@ -69,34 +69,22 @@ def _scan_combine_len(x, y):
     return (*out, jnp.where(fy, ly, lx + ly))
 
 
-def _pallas_eligible(n: int, with_len: bool, use_pallas: bool) -> bool:
-    """Use the fused Pallas kernel (ops/tokenize_pallas.py) when the CALLER
-    says the computation targets a TPU (`use_pallas`) and it applies:
-    block-aligned chunk, no length lane. Measured on v5e: 3.5 ms/MB vs
-    26 ms/MB for the associative_scan — the scan's ~40 log-depth HBM passes
-    collapsed into one. The caller must pass the target platform because
-    under a plugin backend the global default can claim "tpu" while this
-    very computation is placed on CPU devices (Config.device="cpu", the
-    virtual test meshes). MRTPU_NO_PALLAS=1 opts out globally."""
-    import os
-
-    if not use_pallas or with_len or os.environ.get("MRTPU_NO_PALLAS"):
-        return False
-    from mapreduce_rust_tpu.ops.tokenize_pallas import BLOCK
-
-    return n % BLOCK == 0
-
-
 def _tokenize(chunk: jnp.ndarray, last_is_boundary: bool, with_len: bool,
               use_pallas: bool = False):
     ws_tab, wc_tab = byte_class_tables()
     idx = chunk.astype(jnp.int32)
     is_ws = jnp.take(jnp.asarray(ws_tab), idx).astype(bool)
 
-    if _pallas_eligible(chunk.shape[0], with_len, use_pallas):
-        from mapreduce_rust_tpu.ops.tokenize_pallas import hash_scan_pallas
+    if use_pallas and not with_len:
+        # The fused Mosaic scan (ops/tokenize_pallas.py): one HBM pass in
+        # place of the associative_scan's log-depth passes. It walks whole
+        # BLOCKs, so a ragged chunk is padded with spaces — the scan is
+        # inclusive and causal, so the first n outputs are unchanged.
+        from mapreduce_rust_tpu.ops.tokenize_pallas import BLOCK, hash_scan_pallas
 
-        h1, h2, cnts = hash_scan_pallas(chunk)
+        n = chunk.shape[0]
+        padded = jnp.pad(chunk, (0, -n % BLOCK), constant_values=0x20)
+        h1, h2, cnts = (x[:n] for x in hash_scan_pallas(padded))
         tlen = None
     else:
         is_wc = jnp.take(jnp.asarray(wc_tab), idx).astype(bool)

@@ -17,8 +17,8 @@ way the scan path does. Equality with the scan path is asserted by
 tests/test_tokenize.py over random bytes and real corpus slices
 (interpret mode on CPU), so the two implementations cannot drift.
 
-Used automatically by ops/tokenize.tokenize_and_hash on the TPU backend
-(MRTPU_NO_PALLAS=1 opts out); other backends keep the associative_scan.
+Used by ops/tokenize.tokenize_and_hash whenever the caller targets a TPU
+(``use_pallas``); other backends keep the associative_scan.
 """
 
 from __future__ import annotations
@@ -150,19 +150,16 @@ def _kernel(x_ref, h1_ref, h2_ref, cnt_ref, carry_ref):
 def hash_scan_pallas(chunk: jnp.ndarray, interpret: bool = False):
     """(h1 uint32[N], h2 uint32[N], word_char_count int32[N]) — the
     inclusive segmented scan at every byte position, one HBM pass.
-    N must be a multiple of BLOCK (chunkers use power-of-two sizes)."""
+    N must be a multiple of BLOCK (ops/tokenize pads ragged chunks)."""
     n = chunk.shape[0]
     if n % BLOCK != 0:
         raise ValueError(f"chunk length {n} not a multiple of {BLOCK}")
     grid = n // BLOCK
     x = chunk.reshape(grid * _ROWS, _LANE)
-    try:
-        # Inside shard_map the outputs vary across the mesh axis exactly
-        # like the input; shard_map's vma check requires saying so.
-        vma = {"vma": jax.typeof(chunk).vma}
-    except AttributeError:  # older jax: no vma tracking
-        vma = {}
-    out = jax.ShapeDtypeStruct((grid * _ROWS, _LANE), jnp.int32, **vma)
+    # Inside shard_map the outputs vary across the mesh axis exactly like
+    # the input; shard_map's vma check requires saying so.
+    out = jax.ShapeDtypeStruct((grid * _ROWS, _LANE), jnp.int32,
+                               vma=jax.typeof(chunk).vma)
     h1, h2, cnt = pl.pallas_call(
         _kernel,
         grid=(grid,),

@@ -36,10 +36,7 @@ import jax.numpy as jnp
 from mapreduce_rust_tpu.core.kv import KVBatch
 from mapreduce_rust_tpu.ops.tokenize import tokenize_and_hash_with_len
 from mapreduce_rust_tpu.parallel.shuffle import AXIS
-try:  # jax >= 0.6 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # older jax: the experimental home
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

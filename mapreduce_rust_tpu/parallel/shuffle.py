@@ -39,23 +39,8 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-try:  # jax >= 0.6 exports shard_map at top level
-    from jax import shard_map
-
-    _SHARD_MAP_NATIVE = True
-except ImportError:  # older jax: the experimental home
-    from jax.experimental.shard_map import shard_map
-
-    _SHARD_MAP_NATIVE = False
-
-#: Public form of the guard: True iff this jax exports shard_map natively
-#: (>= 0.6), where buffer donation into a shard_map'ed jit is supported.
-#: The mrlint `donation-safety` rule requires any donate_argnums near a
-#: shard_map to sit behind a test of this name — import it rather than
-#: re-deriving the probe.
-SHARD_MAP_NATIVE = _SHARD_MAP_NATIVE
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
+from jax import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from mapreduce_rust_tpu.apps.base import App
 from mapreduce_rust_tpu.core.kv import KVBatch
@@ -73,15 +58,17 @@ AXIS = "shards"
 
 
 def make_mesh(n_devices: int | None = None, backend: str | None = None) -> Mesh:
-    """1-D device mesh. Prefers the default backend (TPU when present); falls
-    back to the (virtual-device) CPU backend when it is too small — the
-    SURVEY §4 strategy for testing multi-chip code on a 1-chip host."""
+    """1-D mesh over the first ``n_devices`` devices of ``backend`` (default:
+    JAX's default backend, all of its devices). Too few devices is an
+    error — a mesh never moves to another backend behind the caller's back
+    (tests get their virtual CPU devices from conftest's XLA_FLAGS)."""
     devs = jax.devices(backend) if backend else jax.devices()
     n = n_devices or len(devs)
-    if len(devs) < n and backend is None:
-        devs = jax.devices("cpu")
     if len(devs) < n:
-        raise RuntimeError(f"need {n} devices, have {len(devs)}")
+        raise RuntimeError(
+            f"a {n}-device mesh needs {n} {devs[0].platform} devices; "
+            f"this process has {len(devs)}"
+        )
     return Mesh(np.array(devs[:n]), (AXIS,))
 
 
@@ -248,18 +235,7 @@ def _build_shuffle_step_fns(app: App, u_cap: int, bucket_cap: int, mesh: Mesh,
             b_ovf[None],
         )
 
-    # Donating the state into a shard_map'ed jit corrupts the CPU client's
-    # heap on the pre-0.6 experimental shard_map (observed: glibc
-    # "corrupted double-linked list" under the spill-heavy merge on jaxlib
-    # 0.4.x). Donation is a memory optimization, not a correctness
-    # requirement — keep it only where shard_map is the supported
-    # top-level API.
-    _maybe_donate = (
-        functools.partial(jax.jit, donate_argnums=(0,))
-        if _SHARD_MAP_NATIVE else jax.jit
-    )
-
-    @_maybe_donate
+    @functools.partial(jax.jit, donate_argnums=(0,))
     @functools.partial(
         shard_map, mesh=mesh,
         in_specs=(P(AXIS, None), P(AXIS)),

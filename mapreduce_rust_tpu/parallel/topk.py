@@ -26,10 +26,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:  # jax >= 0.6 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # older jax: the experimental home
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from mapreduce_rust_tpu.core.kv import KVBatch

@@ -69,6 +69,10 @@ class JobStats:
     bucket_skew_replays: int = 0       # mesh groups re-run on the skew tier
     halo_truncations: int = 0     # sharded-stream tokens longer than the halo
                                   # (possibly truncated hash — exactness fault)
+    scan_tokenize_rounds: int = 0  # TPU rounds tokenized by the
+                                  # associative_scan, not the Pallas kernel
+                                  # (the sharded stream's halo path needs
+                                  # the token-length lane the kernel lacks)
     mesh_rounds: int = 0          # all_to_all rounds executed (incl. replays)
     shuffle_wire_bytes: int = 0   # bytes through the all_to_all: the padded
     # bucket payload every chip exchanges each round — D*D*bucket_cap

@@ -697,6 +697,7 @@ def platform_info() -> dict:
                 return info
             devs = jax.devices()
             info["backend"] = devs[0].platform
+            info["device_kind"] = devs[0].device_kind
             info["device_count"] = len(devs)
             info["process_count"] = jax.process_count()
         except Exception:  # backend probe failed — manifest still writes

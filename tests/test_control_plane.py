@@ -414,8 +414,11 @@ def test_rpc_timeout_surfaces_wedged_coordinator(tmp_path):
     from mapreduce_rust_tpu.coordinator.server import RpcTimeout
 
     async def go():
+        release = asyncio.Event()
+
         async def wedged(reader, writer):
-            await asyncio.sleep(30)  # accept, read nothing, answer nothing
+            await release.wait()  # accept, read nothing, answer nothing
+            writer.close()  # wait_closed() below waits for this connection
 
         server = await asyncio.start_server(wedged, "127.0.0.1", 0)
         port = server.sockets[0].getsockname()[1]
@@ -429,6 +432,7 @@ def test_rpc_timeout_surfaces_wedged_coordinator(tmp_path):
             assert not isinstance(RpcTimeout("x"), ConnectionError)
         finally:
             await client.close()
+            release.set()
             server.close()
             await server.wait_closed()
 
@@ -1132,6 +1136,8 @@ def test_call_retry_reconnects_after_transient_timeout(tmp_path):
             assert len(connections) == 2   # wedged once, retried once
         finally:
             await client.close()
+            for wr in connections:  # wait_closed() waits for every one
+                wr.close()
             server.close()
             await server.wait_closed()
 

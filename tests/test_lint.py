@@ -193,56 +193,6 @@ def test_tmpdir_cleanup_silent_with_finally_rmtree(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# donation-safety
-# ---------------------------------------------------------------------------
-
-def test_donation_safety_fires_on_unguarded_shard_map_donation(tmp_path):
-    findings, _ = run_lint(tmp_path, """
-        import functools
-        import jax
-        from jax.experimental.shard_map import shard_map
-
-        @functools.partial(jax.jit, donate_argnums=(0,))
-        @functools.partial(shard_map, mesh=None, in_specs=None, out_specs=None)
-        def merge(state, update):
-            return state
-    """)
-    assert [f.rule for f in findings] == ["donation-safety"]
-    assert "SHARD_MAP_NATIVE" in findings[0].message
-
-
-def test_donation_safety_silent_when_guarded(tmp_path):
-    assert rules_fired(tmp_path, """
-        import functools
-        import jax
-        from jax.experimental.shard_map import shard_map
-
-        _SHARD_MAP_NATIVE = False
-        _maybe_donate = (
-            functools.partial(jax.jit, donate_argnums=(0,))
-            if _SHARD_MAP_NATIVE else jax.jit
-        )
-
-        @_maybe_donate
-        @functools.partial(shard_map, mesh=None, in_specs=None, out_specs=None)
-        def merge(state, update):
-            return state
-    """) == []
-
-
-def test_donation_safety_silent_on_plain_jit(tmp_path):
-    # Donation into a plain (non-shard_map) jit is supported everywhere.
-    assert rules_fired(tmp_path, """
-        import functools
-        import jax
-
-        @functools.partial(jax.jit, donate_argnums=(0,))
-        def merge(state, update):
-            return state
-    """) == []
-
-
-# ---------------------------------------------------------------------------
 # a2a-purity
 # ---------------------------------------------------------------------------
 

@@ -149,3 +149,48 @@ def test_pallas_scan_cross_block_carry():
     for start, t in spans:
         end = start + len(t) - 1
         assert got[end] == hash_word(t), "cross-block hash carry is broken"
+
+
+def test_pallas_path_pads_ragged_chunk(monkeypatch):
+    """A chunk that is not a whole number of BLOCKs takes the Pallas path
+    too: space-padded to a BLOCK multiple, outputs sliced back. The kernel
+    is stood in for by a per-byte host loop of the same scan (the kernel's
+    own equality is the interpret-mode tests above), so this checks the
+    padding and slicing alone."""
+    import jax.numpy as jnp
+
+    from mapreduce_rust_tpu.core.hashing import (
+        H1_INIT, H1_MULT, H2_INIT, H2_MULT, byte_class_tables,
+    )
+    from mapreduce_rust_tpu.ops import tokenize_pallas
+    from mapreduce_rust_tpu.ops.tokenize import _tokenize, tokenize_and_hash
+
+    ws_tab, wc_tab = (np.asarray(t).astype(bool) for t in byte_class_tables())
+    init1, init2, mult1, mult2 = map(int, (H1_INIT, H2_INIT, H1_MULT, H2_MULT))
+    shapes = []
+
+    def host_scan(chunk):
+        shapes.append(chunk.shape[0])
+        out = np.zeros((3, chunk.shape[0]), dtype=np.int64)
+        h1, h2, cnt = init1, init2, 0
+        for i, c in enumerate(np.asarray(chunk).tolist()):
+            if ws_tab[c]:
+                h1, h2, cnt = init1, init2, 0
+            elif wc_tab[c]:
+                h1 = (h1 * mult1 + c + 1) & 0xFFFFFFFF
+                h2 = (h2 * mult2 + c + 1) & 0xFFFFFFFF
+                cnt += 1
+            out[:, i] = (h1, h2, cnt)
+        return (jnp.asarray(out[0], jnp.uint32), jnp.asarray(out[1], jnp.uint32),
+                jnp.asarray(out[2], jnp.int32))
+
+    monkeypatch.setattr(tokenize_pallas, "hash_scan_pallas", host_scan)
+    rng = np.random.default_rng(5)
+    words = [b"w%d," % rng.integers(0, 500) for _ in range(1000)]
+    text = b" ".join(words)[: tokenize_pallas.BLOCK - 777]
+    data = jnp.asarray(np.frombuffer(text, dtype=np.uint8))
+    padded, _ = _tokenize(data, False, with_len=False, use_pallas=True)
+    scan = tokenize_and_hash(data, last_is_boundary=False)
+    assert shapes == [tokenize_pallas.BLOCK]
+    for a, b in zip(padded, scan):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
