@@ -22,9 +22,8 @@ Three parts:
   tracks — ``bytes_in`` over the host-map scan seconds, ``spill_split``
   bytes over writer seconds, dispatch record bytes (the packed
   ``1 + 3·cap`` uint32 layout) over dispatch-thread seconds, a2a wire
-  bytes over collective seconds — plus the jitted merge fn's
-  ``jax.stages`` ``cost_analysis()`` (captured by the driver into the
-  manifest's ``merge_cost`` block) for device-merge intensity.
+  bytes over collective seconds. The device merge itself is measured
+  from the device trace (the benchmark's ``merge_roofline``), not here.
 - **CLI** (``run_cli``): the jax-free ``prof`` subcommand — render a
   manifest's ``stats.profile``, export its collapsed stacks as a
   ``.folded`` file, and with ``--roofline`` attach the attribution.
@@ -207,22 +206,6 @@ def stage_rows(manifest: dict, machine: dict) -> list:
             "dispatch", dispatch_bytes, dsp.get("dispatch_s"), host_roof,
             dispatches=dsp["dispatches"], roof="host-memcpy",
         ))
-        mc = manifest.get("merge_cost") or {}
-        if mc.get("bytes_accessed"):
-            flops = (mc.get("flops") or 0.0) * dsp["dispatches"]
-            mbytes = mc["bytes_accessed"] * dsp["dispatches"]
-            # No fallback roof here: the bytes are XLA's static estimate
-            # of buffer traffic, only honest against a real device HBM
-            # peak — against host memcpy it fabricates >100% fractions.
-            droof = device_roof_gbs(machine)
-            row = _row(
-                "device-merge", mbytes, dsp.get("dispatch_s"), droof,
-                roof="device-hbm" if droof else None,
-            )
-            if row is not None:
-                row["flops"] = flops
-                row["intensity_flops_per_byte"] = round(flops / mbytes, 4)
-                rows.append(row)
 
     ici = stats.get("ici_split") or {}
     rows.append(_row(

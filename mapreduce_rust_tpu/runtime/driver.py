@@ -888,15 +888,18 @@ class _IngestStream:
 
 def _stream_single(cfg: Config, app: App, inputs, stats, acc, dictionary,
                    doc_id_offset: int = 0) -> None:
-    enable_compilation_cache(cfg.compilation_cache_dir)
-    device = select_device(cfg.device)
-    use_pallas = device.platform == "tpu"
-    u_cap = cfg.effective_partial_capacity()
-    depth = max(cfg.pipeline_depth, 1)
-    map_combine, merge = make_step_fns(app, u_cap, use_pallas)
+    with stats.timed("job_setup_s", "engine.setup"):
+        enable_compilation_cache(cfg.compilation_cache_dir)
+        device = select_device(cfg.device)
+        use_pallas = device.platform == "tpu"
+        u_cap = cfg.effective_partial_capacity()
+        depth = max(cfg.pipeline_depth, 1)
+        map_combine, merge = make_step_fns(app, u_cap, use_pallas)
+        state = jax.device_put(KVBatch.empty(cfg.merge_capacity), device)
+        ingest = _IngestStream(cfg, inputs, stats, dictionary, doc_id_offset,
+                               host_mask=app.host_mask,
+                               lineage_range=app.partition_mode == "range")
     slow_fns = None  # full-width replay path, compiled only if ever needed
-
-    state = jax.device_put(KVBatch.empty(cfg.merge_capacity), device)
     pending: collections.deque = collections.deque()  # (ovf, ev_count, evicted, chunk_host, did)
 
     def replay_chunk(chunk_host: np.ndarray, doc_id) -> None:
@@ -941,9 +944,6 @@ def _stream_single(cfg: Config, app: App, inputs, stats, acc, dictionary,
             if int(ovf_n) > 0:
                 replay_chunk(chunk_host, did)
 
-    ingest = _IngestStream(cfg, inputs, stats, dictionary, doc_id_offset,
-                           host_mask=app.host_mask,
-                           lineage_range=app.partition_mode == "range")
     try:
         for chunk in ingest:
             with trace_span("chunk.dispatch"):
@@ -964,7 +964,8 @@ def _stream_single(cfg: Config, app: App, inputs, stats, acc, dictionary,
         ingest.close(abort=True)
         raise
     ingest.close()
-    acc.add_batch(state)
+    with stats.timed("state_readback_s", "state.readback"):
+        acc.add_batch(state)
 
 
 #: (app, cap) → merge_packed, LRU-bounded (ISSUE 13 satellite): the old
@@ -1032,39 +1033,6 @@ def make_packed_merge_fn(app: App, cap: int):
     return merge_packed
 
 
-def _merge_cost_analysis(app: App, cfg: Config) -> "dict | None":
-    """``jax.stages`` cost analysis of the jitted packed-merge fn
-    (ISSUE 19): flops + bytes accessed PER DISPATCH — the
-    operational-intensity input the roofline attribution uses for the
-    device-merge stage. Abstract lowering (ShapeDtypeStructs, the shapes
-    the run just used) — no device buffers; the executable cache makes
-    the ``compile()`` a lookup, not a second compile."""
-    cap = cfg.host_update_cap
-    n = cfg.merge_capacity
-    state = KVBatch(
-        k1=jax.ShapeDtypeStruct((n,), jnp.uint32),
-        k2=jax.ShapeDtypeStruct((n,), jnp.uint32),
-        value=jax.ShapeDtypeStruct((n,), jnp.int32),
-        valid=jax.ShapeDtypeStruct((n,), jnp.bool_),
-    )
-    flat = jax.ShapeDtypeStruct((1 + 3 * cap,), jnp.uint32)
-    lowered = make_packed_merge_fn(app, cap).lower(state, flat)
-    try:
-        ca = lowered.compile().cost_analysis()
-    except Exception:
-        ca = lowered.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
-    if not isinstance(ca, dict):
-        return None
-    out = {}
-    for key_name in ("flops", "bytes accessed", "transcendentals"):
-        v = ca.get(key_name)
-        if isinstance(v, (int, float)):
-            out[key_name.replace(" ", "_")] = float(v)
-    return out or None
-
-
 def _pack_update(keys: np.ndarray, values: np.ndarray, cap: int) -> np.ndarray:
     """Lay one window's (keys uint32[n,2], values) into the flat layout
     make_packed_merge_fn expects. The reference packer: allocates (and
@@ -1089,14 +1057,14 @@ class _PackStager:
     touches O(n) bytes plus whatever the LAST window dirtied — by
     construction byte-identical to :func:`_pack_update`'s output.
 
-    Reuse safety: ``jax.device_put`` COPIES the host buffer on the CPU
-    backend (measured on this image — mutate-after-put does not alter the
-    device array), so the buffer is free the moment the put returns. On
-    accelerator backends the host→device transfer may be asynchronous
-    w.r.t. the source buffer; ``needs_barrier`` tells the dispatch plane
-    to wait for the put (``block_until_ready`` on the INPUT array — a
-    dispatch-thread-local sync the router never sees) before this buffer
-    is dirtied again."""
+    Reuse safety: on the CPU backend ``jax.device_put`` may alias a
+    suitably aligned host buffer instead of copying it (zero-copy; even
+    ``block_until_ready`` leaves the alias), so the dispatch plane puts a
+    copy there. On accelerator backends the host→device transfer may be
+    asynchronous w.r.t. the source buffer; ``needs_barrier`` tells the
+    dispatch plane to wait for the put (``block_until_ready`` on the INPUT
+    array — a dispatch-thread-local sync the router never sees) before
+    this buffer is dirtied again."""
 
     SENTINEL = np.uint32(0xFFFFFFFF)
 
@@ -1440,12 +1408,16 @@ class _DispatchPlane:
         _chaos_slow_dispatch(self.merge_dispatches)
         flat = self._stager.pack(k1, k2, vals)
         with trace_span("dispatch.submit", n=len(k1)):
-            flat_dev = jax.device_put(flat, self.device)
             if self._stager.needs_barrier:
                 # Accelerator backends: the put may read the host buffer
-                # asynchronously — wait before the stager dirties it again
-                # (CPU copies eagerly; see _PackStager).
+                # asynchronously — wait before the stager dirties it again.
+                flat_dev = jax.device_put(flat, self.device)
                 flat_dev.block_until_ready()
+            else:
+                # CPU: the put may alias the host buffer (zero-copy), so
+                # the next pack would rewrite a merge still to run — put
+                # a copy, never the stager's buffer itself.
+                flat_dev = jax.device_put(flat.copy(), self.device)
             self.state, evicted, ev_count = self.merge_packed(
                 self.state, flat_dev
             )
@@ -1533,8 +1505,9 @@ class _DispatchPlane:
             self._finalize()
             return
         t0 = time.perf_counter()
-        self._q.put(self._SENTINEL)
-        self._thread.join()
+        with trace_span("host_map.dispatch_stall"):
+            self._q.put(self._SENTINEL)
+            self._thread.join()
         self.stall_s += time.perf_counter() - t0
         if self.poisoned.is_set():
             self._raise_error()
@@ -1793,10 +1766,11 @@ class _FoldShardPlane:
             return
         self._finished = True
         t0 = time.perf_counter()
-        for q in self.queues:
-            q.put(self._SENTINEL)
-        for t in self.threads:
-            t.join()
+        with trace_span("host_map.fold_stall"):
+            for q in self.queues:
+                q.put(self._SENTINEL)
+            for t in self.threads:
+                t.join()
         self.stall_s += time.perf_counter() - t0
         if self.poisoned.is_set():
             self._raise_error()
@@ -1942,26 +1916,13 @@ def _stream_host_map(cfg: Config, app: App, inputs, stats, acc, dictionary,
         scan_count_sharded_raw,
     )
 
-    enable_compilation_cache(cfg.compilation_cache_dir)
-    device = select_device(cfg.device)
-    workers = cfg.effective_host_map_workers()
-    stats.host_map_workers = workers
-    fold_n = (
-        dictionary.n_shards if isinstance(dictionary, ShardedDictionary) else 1
-    )
-    stats.fold_shards = fold_n
-    fold: "_FoldShardPlane | None" = None  # started right before the
-    # stream loop's try block — device setup below can raise, and fold
-    # threads started earlier would leak, blocked forever on q.get()
-    # The dispatch plane (ISSUE 13) owns the device state, the pending
-    # merges and their drain: the router below never books a device hop.
-    dispatch = _DispatchPlane(cfg, app, stats, acc, dictionary, device)
-    # Provenance (ISSUE 20): digest each window on ITS scan thread (the
-    # bytes are hot there), record on the consumer — in window order, so
-    # the ledger is identical for any (workers, shards) combination.
     from mapreduce_rust_tpu.runtime.lineage import active_ledger, chunk_digest
 
-    ledger = active_ledger()
+    # The closures below bind the engine's set-up (device, planes, pool),
+    # made after them in one timed block right before the stream loop.
+    fold: "_FoldShardPlane | None" = None  # started last in that block —
+    # device setup can raise, and fold threads started earlier would
+    # leak, blocked forever on q.get()
     lineage_range = app.partition_mode == "range"
 
     def lineage_record(doc_id, lin, keys, mask) -> None:
@@ -2096,13 +2057,7 @@ def _stream_host_map(cfg: Config, app: App, inputs, stats, acc, dictionary,
 
     from concurrent.futures import ThreadPoolExecutor
 
-    # In-flight budget: each submitted-but-unconsumed scan pins one memmap
-    # window plus (once done) its compacted result, so 2×workers + 2 keeps
-    # every worker busy while the consumer works through the ordered head —
-    # deep enough to ride out a slow (high-cardinality) window, shallow
-    # enough that memory stays flat.
     inflight: collections.deque = collections.deque()
-    budget = 2 * workers + 2
 
     def next_result():
         fut = inflight.popleft()
@@ -2116,20 +2071,42 @@ def _stream_host_map(cfg: Config, app: App, inputs, stats, acc, dictionary,
                       merges=len(dispatch.pending))  # benign-stale len read
         return res
 
-    pool = ThreadPoolExecutor(max_workers=workers, thread_name_prefix="mr/scan")
-    if fold_n > 1:
-        # Started HERE, not at function entry: everything that can raise
-        # during setup (device selection/state allocation, pool creation)
-        # is behind us, and the very next statement is the try whose
-        # except/finally owns the plane's teardown — no window where an
-        # exception strands S fold threads on q.get(). The dispatch plane
-        # started earlier (its ctor allocates device state), so a fold
-        # ctor failure must unwind it.
-        try:
-            fold = _FoldShardPlane(cfg, stats, dictionary.shards)
-        except BaseException:
-            dispatch.abort()
-            raise
+    with stats.timed("job_setup_s", "engine.setup"):
+        enable_compilation_cache(cfg.compilation_cache_dir)
+        device = select_device(cfg.device)
+        workers = cfg.effective_host_map_workers()
+        stats.host_map_workers = workers
+        fold_n = (
+            dictionary.n_shards if isinstance(dictionary, ShardedDictionary) else 1
+        )
+        stats.fold_shards = fold_n
+        # The dispatch plane owns the device state, the pending
+        # merges and their drain: the router never books a device hop.
+        dispatch = _DispatchPlane(cfg, app, stats, acc, dictionary, device)
+        # Provenance: digest each window on ITS scan thread (the
+        # bytes are hot there), record on the consumer — in window order, so
+        # the ledger is identical for any (workers, shards) combination.
+        ledger = active_ledger()
+        # In-flight budget: each submitted-but-unconsumed scan pins one
+        # memmap window plus (once done) its compacted result, so
+        # 2×workers + 2 keeps every worker busy while the consumer works
+        # through the ordered head — deep enough to ride out a slow
+        # (high-cardinality) window, shallow enough that memory stays flat.
+        budget = 2 * workers + 2
+        pool = ThreadPoolExecutor(max_workers=workers, thread_name_prefix="mr/scan")
+        if fold_n > 1:
+            # Started LAST: everything that can raise during setup (device
+            # selection/state allocation, pool creation) is behind us, and
+            # the very next statement is the try whose except/finally owns
+            # the plane's teardown — no window where an exception strands
+            # S fold threads on q.get(). The dispatch plane started earlier
+            # (its ctor allocates device state), so a fold ctor failure
+            # must unwind it.
+            try:
+                fold = _FoldShardPlane(cfg, stats, dictionary.shards)
+            except BaseException:
+                dispatch.abort()
+                raise
     try:
         for item in _iter_windows(cfg, inputs, stats):
             inflight.append(pool.submit(scan_window, item))
@@ -2163,7 +2140,8 @@ def _stream_host_map(cfg: Config, app: App, inputs, stats, acc, dictionary,
         # Queued futures cancel; the ≤ workers running scans finish their
         # pure work and are reaped before the stream frame exits.
         pool.shutdown(wait=True, cancel_futures=True)
-    acc.add_batch(dispatch.state)
+    with stats.timed("state_readback_s", "state.readback"):
+        acc.add_batch(dispatch.state)
 
 
 def _ckpt_paths(cfg: Config) -> tuple[str, str]:
@@ -2408,7 +2386,8 @@ def _stream_multihost(cfg: Config, app: App, inputs, stats, acc, dictionary) -> 
         ingest.close(abort=True)
         raise
     ingest.close()
-    acc.add_batch(local_batch(state))
+    with stats.timed("state_readback_s", "state.readback"):
+        acc.add_batch(local_batch(state))
 
     # Dictionary exchange over the shared work dir: each process publishes
     # its shard + a done marker, then merges everyone's (a chip may own
@@ -2480,18 +2459,19 @@ def _finish_mesh_state(app: App, mesh, state, stats, acc) -> None:
     except Exception:
         pass  # telemetry stays best-effort
     k = app.device_select_k
-    if k and stats.spill_events == 0:
-        from mapreduce_rust_tpu.parallel.topk import topk_candidates
+    with stats.timed("state_readback_s", "state.readback"):
+        if k and stats.spill_events == 0:
+            from mapreduce_rust_tpu.parallel.topk import topk_candidates
 
-        res = topk_candidates(mesh, state, k)
-        if res is not None:
-            keys, vals = res
-            acc.add(keys, vals)
-            log.info("device top-%d selection: %d candidates fetched", k, len(vals))
-            return
-        log.info("device top-%d selection ambiguous (value tie at boundary) "
-                 "— falling back to full state fetch", k)
-    acc.add_batch(state)
+            res = topk_candidates(mesh, state, k)
+            if res is not None:
+                keys, vals = res
+                acc.add(keys, vals)
+                log.info("device top-%d selection: %d candidates fetched", k, len(vals))
+                return
+            log.info("device top-%d selection ambiguous (value tie at boundary) "
+                     "— falling back to full state fetch", k)
+        acc.add_batch(state)
 
 
 def _stream_sharded(cfg: Config, app: App, inputs, stats, acc, dictionary) -> None:
@@ -2896,142 +2876,143 @@ def run_job(
     the matching ``corpus_bounds`` (resolve_corpora's) themselves.
     """
     t0 = time.perf_counter()
-    app = app or WordCount()
-    if inputs is None:
-        inputs, auto_bounds, _names = resolve_corpora(cfg)
-        if corpus_bounds is None:
-            corpus_bounds = auto_bounds
-    else:
-        inputs = list(inputs)
-    if not inputs:
-        raise ValueError("no input files")
-
-    budgeted = cfg.host_accum_budget_mb is not None or cfg.dictionary_budget_words is not None
-    if budgeted and (cfg.checkpoint_every_groups or cfg.resume or jax.process_count() > 1):
-        raise ValueError(
-            "egress budgets are incompatible with checkpoint/resume and "
-            "multi-process runs"
-        )
-    if budgeted and not write_outputs:
-        # Streaming egress delivers results ONLY through output files; a
-        # budgeted run without them would compute everything and return
-        # an empty table — silently discarding the job.
-        raise ValueError("egress budgets require write_outputs=True")
     # Sanitize-aware construction (analysis/sanitize.py): plain instances
     # unless Config.sanitize / MR_SANITIZE=1, in which case cross-thread
     # writes to stats or dictionary raise at the write site.
     from mapreduce_rust_tpu.analysis.sanitize import new_dictionary, new_job_stats
 
     stats = new_job_stats(cfg)
-    # Workload plane (ISSUE 15): bind corpus bounds and — for range apps —
-    # sampler-derived splitters onto the app BEFORE anything streams. The
-    # pre-pass is seeded and pure in (inputs, config), so every engine and
-    # every re-execution derives identical routing; its cost lands in
-    # stats.splitter_s/splitter_samples for the bench sort leg.
-    from mapreduce_rust_tpu.runtime.splitter import prepare_app
+    with stats.timed("job_setup_s", "job.setup"):
+        app = app or WordCount()
+        if inputs is None:
+            inputs, auto_bounds, _names = resolve_corpora(cfg)
+            if corpus_bounds is None:
+                corpus_bounds = auto_bounds
+        else:
+            inputs = list(inputs)
+        if not inputs:
+            raise ValueError("no input files")
 
-    app = prepare_app(app, cfg, inputs, corpus_bounds or (), stats=stats)
-    # Crash-safe run scavenging (ISSUE 11 satellite): a SIGKILLed job's
-    # remove_runs never ran, so its dictrun-*/accrun-* files leak forever
-    # in a shared work_dir. Reclaim orphans whose writer pid is gone (live
-    # concurrent jobs keep answering kill(pid, 0), so theirs are never
-    # touched); best-effort, before this job's own tiers exist.
-    from mapreduce_rust_tpu.runtime.spill import scavenge_stale_runs
+        budgeted = cfg.host_accum_budget_mb is not None or cfg.dictionary_budget_words is not None
+        if budgeted and (cfg.checkpoint_every_groups or cfg.resume or jax.process_count() > 1):
+            raise ValueError(
+                "egress budgets are incompatible with checkpoint/resume and "
+                "multi-process runs"
+            )
+        if budgeted and not write_outputs:
+            # Streaming egress delivers results ONLY through output files; a
+            # budgeted run without them would compute everything and return
+            # an empty table — silently discarding the job.
+            raise ValueError("egress budgets require write_outputs=True")
+        # Workload plane: bind corpus bounds and — for range apps —
+        # sampler-derived splitters onto the app BEFORE anything streams. The
+        # pre-pass is seeded and pure in (inputs, config), so every engine and
+        # every re-execution derives identical routing; its cost lands in
+        # stats.splitter_s/splitter_samples for the bench sort leg.
+        from mapreduce_rust_tpu.runtime.splitter import prepare_app
 
-    scavenge_stale_runs(cfg.work_dir, logger=log)
-    acc = HostAccumulator(
-        app.combine_op,
-        budget_bytes=(
-            cfg.host_accum_budget_mb << 20
-            if cfg.host_accum_budget_mb is not None else None
-        ),
-        spill_dir=cfg.work_dir,
-        async_spill=cfg.spill_async,
-    )
-    # Sharded egress fold (ISSUE 9): the single-process host-map engine
-    # splits the dictionary into S key-hash-disjoint shards, each owned by
-    # one fold thread of _FoldShardPlane. Every other engine keeps the
-    # single-dictionary fold (mesh tokenizes on device; multihost already
-    # merges per-PROCESS dictionary shards; checkpoint/resume persists the
-    # plain Dictionary). The word budget splits across shards so the
-    # bounded-memory contract is per-process, not per-shard×S.
-    fold_shards = 1
-    if (cfg.map_engine == "host"
-            and not (cfg.mesh_shape and cfg.mesh_shape > 1)
-            and jax.process_count() == 1):
-        fold_shards = cfg.effective_fold_shards()
-    if fold_shards > 1:
-        per_shard_budget = (
-            max(1, cfg.dictionary_budget_words // fold_shards)
-            if cfg.dictionary_budget_words is not None else None
+        app = prepare_app(app, cfg, inputs, corpus_bounds or (), stats=stats)
+        # Crash-safe run scavenging: a SIGKILLed job's
+        # remove_runs never ran, so its dictrun-*/accrun-* files leak forever
+        # in a shared work_dir. Reclaim orphans whose writer pid is gone (live
+        # concurrent jobs keep answering kill(pid, 0), so theirs are never
+        # touched); best-effort, before this job's own tiers exist.
+        from mapreduce_rust_tpu.runtime.spill import scavenge_stale_runs
+
+        scavenge_stale_runs(cfg.work_dir, logger=log)
+        acc = HostAccumulator(
+            app.combine_op,
+            budget_bytes=(
+                cfg.host_accum_budget_mb << 20
+                if cfg.host_accum_budget_mb is not None else None
+            ),
+            spill_dir=cfg.work_dir,
+            async_spill=cfg.spill_async,
         )
-        dictionary = ShardedDictionary([
-            new_dictionary(cfg, budget_words=per_shard_budget,
-                           spill_dir=cfg.work_dir,
-                           async_spill=cfg.spill_async)
-            for _ in range(fold_shards)
-        ])
-    else:
-        dictionary = new_dictionary(
-            cfg, budget_words=cfg.dictionary_budget_words,
-            spill_dir=cfg.work_dir, async_spill=cfg.spill_async,
-        )
-    # Compile instrumentation rides every run (cheap: two listeners, a
-    # list append per compile); the slice below scopes the process-global
-    # log to THIS run's interval.
-    _install_compile_listener()
-    compile_log_start = len(_COMPILE_LOG)
-    tracer = start_tracing(tag="driver") if cfg.trace_path else None
-    if tracer is not None:
-        # Flight recorder: the stream loops tick maybe_snapshot() per
-        # chunk/window, so a killed or wedged driver still leaves an
-        # atomic *.partial.json that `trace merge` accepts.
-        tracer.enable_flight_recorder(
-            partial_path(cfg.trace_path),
-            period_s=cfg.flight_record_period_s,
-        )
-    # Live metrics (ISSUE 8): the registry pulls JobStats aggregates into
-    # the time-series ring when the SAME loops that tick the flight
-    # recorder call metrics_tick() — no engine grows a second
-    # instrumentation site, nothing runs per record. Serialized into the
-    # manifest as stats.timeseries by build_manifest.
-    registry = None
-    if cfg.metrics_enabled:
-        registry = start_metrics(cfg.metrics_sample_period_s,
-                                 cfg.metrics_ring_points)
-        registry.add_collector(jobstats_collector(stats))
+        # Sharded egress fold: the single-process host-map engine
+        # splits the dictionary into S key-hash-disjoint shards, each owned by
+        # one fold thread of _FoldShardPlane. Every other engine keeps the
+        # single-dictionary fold (mesh tokenizes on device; multihost already
+        # merges per-PROCESS dictionary shards; checkpoint/resume persists the
+        # plain Dictionary). The word budget splits across shards so the
+        # bounded-memory contract is per-process, not per-shard×S.
+        fold_shards = 1
+        if (cfg.map_engine == "host"
+                and not (cfg.mesh_shape and cfg.mesh_shape > 1)
+                and jax.process_count() == 1):
+            fold_shards = cfg.effective_fold_shards()
+        if fold_shards > 1:
+            per_shard_budget = (
+                max(1, cfg.dictionary_budget_words // fold_shards)
+                if cfg.dictionary_budget_words is not None else None
+            )
+            dictionary = ShardedDictionary([
+                new_dictionary(cfg, budget_words=per_shard_budget,
+                               spill_dir=cfg.work_dir,
+                               async_spill=cfg.spill_async)
+                for _ in range(fold_shards)
+            ])
+        else:
+            dictionary = new_dictionary(
+                cfg, budget_words=cfg.dictionary_budget_words,
+                spill_dir=cfg.work_dir, async_spill=cfg.spill_async,
+            )
+        # Compile instrumentation rides every run (cheap: two listeners, a
+        # list append per compile); the slice below scopes the process-global
+        # log to THIS run's interval.
+        _install_compile_listener()
+        compile_log_start = len(_COMPILE_LOG)
+        tracer = start_tracing(tag="driver") if cfg.trace_path else None
         if tracer is not None:
-            tracer.metrics_registry = registry  # partials keep the series
-    # Sampling profiler (ISSUE 19): one thread walks sys._current_frames()
-    # at ~97 Hz, collapsed stacks keyed by the mr/ plane-thread names.
-    # Observational only — nothing the data plane reads is touched, so
-    # outputs stay bit-identical ON vs OFF. Lands in the manifest as
-    # stats.profile (build_manifest reads the still-active profiler).
-    sprof = None
-    if cfg.profile or profile_forced():
-        from mapreduce_rust_tpu.runtime.prof import start_profiler
+            # Flight recorder: the stream loops tick maybe_snapshot() per
+            # chunk/window, so a killed or wedged driver still leaves an
+            # atomic *.partial.json that `trace merge` accepts.
+            tracer.enable_flight_recorder(
+                partial_path(cfg.trace_path),
+                period_s=cfg.flight_record_period_s,
+            )
+        # Live metrics: the registry pulls JobStats aggregates into
+        # the time-series ring when the SAME loops that tick the flight
+        # recorder call metrics_tick() — no engine grows a second
+        # instrumentation site, nothing runs per record. Serialized into the
+        # manifest as stats.timeseries by build_manifest.
+        registry = None
+        if cfg.metrics_enabled:
+            registry = start_metrics(cfg.metrics_sample_period_s,
+                                     cfg.metrics_ring_points)
+            registry.add_collector(jobstats_collector(stats))
+            if tracer is not None:
+                tracer.metrics_registry = registry  # partials keep the series
+        # Sampling profiler: one thread walks sys._current_frames()
+        # at ~97 Hz, collapsed stacks keyed by the mr/ plane-thread names.
+        # Observational only — nothing the data plane reads is touched, so
+        # outputs stay bit-identical ON vs OFF. Lands in the manifest as
+        # stats.profile (build_manifest reads the still-active profiler).
+        sprof = None
+        if cfg.profile or profile_forced():
+            from mapreduce_rust_tpu.runtime.prof import start_profiler
 
-        sprof = start_profiler(cfg.profile_hz)
-        if tracer is not None:
-            tracer.profiler = sprof  # partials keep the flamegraph
-            sprof.tracer = tracer    # per-plane self-time counter tracks
-    # Provenance ledger (ISSUE 20): per-chunk content digests + partition
-    # routing recorded from the same consumer loops that tick the flight
-    # recorder. Observational only — outputs stay bit-identical ON vs
-    # OFF. Lands in the manifest as stats.lineage (build_manifest reads
-    # the still-active ledger) and in partials as body["lineage"].
-    ledger = None
-    if cfg.lineage or lineage_forced():
-        from mapreduce_rust_tpu.runtime.lineage import (
-            LEDGER_NAME,
-            start_ledger,
-        )
+            sprof = start_profiler(cfg.profile_hz)
+            if tracer is not None:
+                tracer.profiler = sprof  # partials keep the flamegraph
+                sprof.tracer = tracer    # per-plane self-time counter tracks
+        # Provenance ledger: per-chunk content digests + partition
+        # routing recorded from the same consumer loops that tick the flight
+        # recorder. Observational only — outputs stay bit-identical ON vs
+        # OFF. Lands in the manifest as stats.lineage (build_manifest reads
+        # the still-active ledger) and in partials as body["lineage"].
+        ledger = None
+        if cfg.lineage or lineage_forced():
+            from mapreduce_rust_tpu.runtime.lineage import (
+                LEDGER_NAME,
+                start_ledger,
+            )
 
-        os.makedirs(cfg.work_dir, exist_ok=True)
-        ledger = start_ledger(os.path.join(cfg.work_dir, LEDGER_NAME),
-                              inputs=inputs, reduce_n=cfg.reduce_n)
-        if tracer is not None:
-            tracer.lineage = ledger  # partials keep the provenance tail
+            os.makedirs(cfg.work_dir, exist_ok=True)
+            ledger = start_ledger(os.path.join(cfg.work_dir, LEDGER_NAME),
+                                  inputs=inputs, reduce_n=cfg.reduce_n)
+            if tracer is not None:
+                tracer.lineage = ledger  # partials keep the provenance tail
     output_files: list[str] = []
     table: dict = {}
 
@@ -3041,7 +3022,8 @@ def run_job(
             if cfg.profile_dir
             else contextlib.nullcontext()
         )
-        with stats.phase("stream"), prof:
+        # The profiler starts first, so its trace holds phase.stream too.
+        with prof, stats.phase("stream"):
             if cfg.map_engine == "host" and cfg.mesh_shape and cfg.mesh_shape > 1:
                 log.warning(
                     "map_engine='host' applies to the single-chip driver only; "
@@ -3121,109 +3103,101 @@ def run_job(
         stats.wall_seconds = time.perf_counter() - t0
         log.info("job %s done: %s", app.name, stats.summary())
     finally:
-        # Failure path still gets real wall time: the manifest is written
-        # even on a crash, and a 0.0-second crashed run would corrupt every
-        # post-mortem throughput comparison.
-        if not stats.wall_seconds:
-            stats.wall_seconds = time.perf_counter() - t0
-        # Fold this run's XLA compiles into the stats (count / seconds /
-        # persistent-cache hit-miss split) — the doctor's compile-bound
-        # attribution and the manifest's "compile" block.
-        for rec in _COMPILE_LOG[compile_log_start:]:
-            stats.compile_count += 1
-            stats.compile_s += rec["dur_s"]
-            if rec["cache"] == "hit":
-                stats.compile_cache_hits += 1
-            elif rec["cache"] == "miss":
-                stats.compile_cache_misses += 1
-            stats.record_hist("xla.compile_s", rec["dur_s"])
-        # Spill runs are job-scoped scratch: a shared work_dir must not
-        # accumulate accrun-*/dictrun-* files across jobs (or leak them on
-        # a failed run) — ADVICE r5. Their counts survive in the stats (and
-        # manifest) as the proof the disk tiers engaged.
-        stats.accum_spill_runs = acc.run_count
-        stats.dict_spill_runs = dictionary.run_count
-        # remove_runs closes (joins) every async spill writer, so the
-        # collection below reads FINAL counters — no thread still adding.
-        acc.remove_runs()
-        dictionary.remove_runs()
-        _collect_spill_stats(stats, dictionary, acc)
-        # Packed-merge jit cache hygiene (ISSUE 13 satellite): enforce the
-        # LRU bound at job teardown so a long-lived multi-job process
-        # (ROADMAP item 2) holds a bounded working set of compiled merges
-        # — clear_packed_fns() is the full-drop hook for embedders.
-        trim_packed_fns()
-        if sprof is not None:
-            # Freeze sampling before the artifact flush: the profile
-            # covers the job (stream/finalize/egress + spill joins), not
-            # manifest serialization. The stopped profiler stays in the
-            # global slot so build_manifest embeds its final aggregate.
-            sprof.stop()
-        if ledger is not None:
-            # Seal the jsonl (end record: folded corpus content digest)
-            # before the flush; the closed ledger stays in the global
-            # slot so build_manifest embeds stats.lineage.
-            try:
-                ledger.close()
-            except Exception:
-                log.warning("lineage ledger close failed", exc_info=True)
-        if tracer is not None:
-            stop_tracing()
-        if tracer is not None or cfg.manifest_path:
-            # Written even on failure (with an "error" field): a crashed
-            # run's manifest names what ran, which is the point. The whole
-            # block is best-effort — a telemetry failure (including a
-            # wedged distributed runtime below) must never mask the job's
-            # real exception.
-            import sys as _sys
-
-            from mapreduce_rust_tpu.runtime.telemetry import flush_run_artifacts
-
-            exc = _sys.exc_info()[1]
-            extra: dict = {}
-            if exc is not None:
-                extra["error"] = repr(exc)
-            if stats.merge_dispatches:
-                # Per-dispatch merge cost (flops / bytes accessed) for
-                # the roofline's device-merge intensity (ISSUE 19).
+        with stats.timed("job_teardown_s", "job.teardown"):
+            # Failure path still gets real wall time: the manifest is written
+            # even on a crash, and a 0.0-second crashed run would corrupt every
+            # post-mortem throughput comparison.
+            if not stats.wall_seconds:
+                stats.wall_seconds = time.perf_counter() - t0
+            # Fold this run's XLA compiles into the stats (count / seconds /
+            # persistent-cache hit-miss split) — the doctor's compile-bound
+            # attribution and the manifest's "compile" block.
+            for rec in _COMPILE_LOG[compile_log_start:]:
+                stats.compile_count += 1
+                stats.compile_s += rec["dur_s"]
+                if rec["cache"] == "hit":
+                    stats.compile_cache_hits += 1
+                elif rec["cache"] == "miss":
+                    stats.compile_cache_misses += 1
+                stats.record_hist("xla.compile_s", rec["dur_s"])
+            # Spill runs are job-scoped scratch: a shared work_dir must not
+            # accumulate accrun-*/dictrun-* files across jobs (or leak them on
+            # a failed run). Their counts survive in the stats (and
+            # manifest) as the proof the disk tiers engaged.
+            stats.accum_spill_runs = acc.run_count
+            stats.dict_spill_runs = dictionary.run_count
+            # remove_runs closes (joins) every async spill writer, so the
+            # collection below reads FINAL counters — no thread still adding.
+            acc.remove_runs()
+            dictionary.remove_runs()
+            _collect_spill_stats(stats, dictionary, acc)
+            # Packed-merge jit cache hygiene: enforce the
+            # LRU bound at job teardown so a long-lived multi-job process
+            # (ROADMAP item 2) holds a bounded working set of compiled merges
+            # — clear_packed_fns() is the full-drop hook for embedders.
+            trim_packed_fns()
+            if sprof is not None:
+                # Freeze sampling before the artifact flush: the profile
+                # covers the job (stream/finalize/egress + spill joins), not
+                # manifest serialization. The stopped profiler stays in the
+                # global slot so build_manifest embeds its final aggregate.
+                sprof.stop()
+            if ledger is not None:
+                # Seal the jsonl (end record: folded corpus content digest)
+                # before the flush; the closed ledger stays in the global
+                # slot so build_manifest embeds stats.lineage.
                 try:
-                    mc = _merge_cost_analysis(app, cfg)
-                    if mc:
-                        extra["merge_cost"] = mc
+                    ledger.close()
                 except Exception:
-                    pass  # telemetry stays best-effort
-            tag = None
-            try:
-                if jax.process_count() > 1:
-                    from mapreduce_rust_tpu.parallel.distributed import cluster_info
+                    log.warning("lineage ledger close failed", exc_info=True)
+            if tracer is not None:
+                stop_tracing()
+            if tracer is not None or cfg.manifest_path:
+                # Written even on failure (with an "error" field): a crashed
+                # run's manifest names what ran, which is the point. The whole
+                # block is best-effort — a telemetry failure (including a
+                # wedged distributed runtime below) must never mask the job's
+                # real exception.
+                import sys as _sys
 
-                    extra["cluster"] = cluster_info()
-                    # Per-process file names, like the .p{rank} output
-                    # suffix above: co-hosted federated drivers must not
-                    # clobber each other's trace/manifest.
-                    tag = f"p{jax.process_index()}"
-            except Exception as e:
-                log.warning("cluster telemetry unavailable: %s", e)
-            flush_run_artifacts(
-                cfg, tracer, tag=tag, logger=log,
-                stats=stats, app_name=app.name, inputs=inputs,
-                output_files=output_files, extra=extra or None,
-            )
-        if registry is not None:
-            # After the flush: build_manifest serialized the ring from the
-            # still-active registry. Compare-and-clear: an in-process
-            # co-hosted worker may have replaced the global slot.
-            stop_metrics(registry)
-        if sprof is not None:
-            # Same order and compare-and-clear discipline as the registry.
-            from mapreduce_rust_tpu.runtime.prof import stop_profiler
+                from mapreduce_rust_tpu.runtime.telemetry import flush_run_artifacts
 
-            stop_profiler(sprof)
-        if ledger is not None:
-            # Same order and compare-and-clear discipline as the profiler.
-            from mapreduce_rust_tpu.runtime.lineage import stop_ledger
+                exc = _sys.exc_info()[1]
+                extra: dict = {}
+                if exc is not None:
+                    extra["error"] = repr(exc)
+                tag = None
+                try:
+                    if jax.process_count() > 1:
+                        from mapreduce_rust_tpu.parallel.distributed import cluster_info
 
-            stop_ledger(ledger)
+                        extra["cluster"] = cluster_info()
+                        # Per-process file names, like the .p{rank} output
+                        # suffix above: co-hosted federated drivers must not
+                        # clobber each other's trace/manifest.
+                        tag = f"p{jax.process_index()}"
+                except Exception as e:
+                    log.warning("cluster telemetry unavailable: %s", e)
+                flush_run_artifacts(
+                    cfg, tracer, tag=tag, logger=log,
+                    stats=stats, app_name=app.name, inputs=inputs,
+                    output_files=output_files, extra=extra or None,
+                )
+            if registry is not None:
+                # After the flush: build_manifest serialized the ring from the
+                # still-active registry. Compare-and-clear: an in-process
+                # co-hosted worker may have replaced the global slot.
+                stop_metrics(registry)
+            if sprof is not None:
+                # Same order and compare-and-clear discipline as the registry.
+                from mapreduce_rust_tpu.runtime.prof import stop_profiler
+
+                stop_profiler(sprof)
+            if ledger is not None:
+                # Same order and compare-and-clear discipline as the profiler.
+                from mapreduce_rust_tpu.runtime.lineage import stop_ledger
+
+                stop_ledger(ledger)
     return JobResult(stats=stats, table=table, output_files=output_files)
 
 

@@ -90,6 +90,17 @@ class JobStats:
     unknown_keys: int = 0         # final keys missing from the dictionary
     wall_seconds: float = 0.0
     phase_seconds: dict = dataclasses.field(default_factory=dict)
+    # Job lifecycle outside the engines' steady state, each timed by its
+    # span (timed() below):
+    job_setup_s: float = 0.0      # "job.setup" (run_job entry to the
+    # stream phase: accumulator, dictionary shards, planes) plus
+    # "engine.setup" (engine entry to its first chunk/window: device
+    # selection, step fns, the state's device_put, pools)
+    state_readback_s: float = 0.0  # "state.readback": the final device
+    # state fetched into the host accumulator at the end of the stream
+    job_teardown_s: float = 0.0   # "job.teardown": run_job's finally
+    # (spill cleanup, profiler/ledger stop, manifest flush) — the manifest
+    # it writes is flushed inside this interval, so it reads 0 there
     # Utilization split (who is the bottleneck): time the consumer loop sat
     # idle waiting for host ingest (read→normalize→chunk) vs time it sat
     # blocked on device results. ingest_wait ≫ device_wait → host-bound.
@@ -277,6 +288,18 @@ class JobStats:
             dt = time.perf_counter() - t0
             self.phase_seconds[name] = self.phase_seconds.get(name, 0.0) + dt
             log.info("phase %-10s %8.3fs", name, dt)
+
+    @contextmanager
+    def timed(self, field: str, span: str):
+        """Add the block's seconds to the float ``field`` and trace it as
+        ``span`` — the pattern of phase(), for intervals that are not
+        phases (job set-up, the state readback, teardown)."""
+        t0 = time.perf_counter()
+        try:
+            with trace_span(span):
+                yield
+        finally:
+            setattr(self, field, getattr(self, field) + time.perf_counter() - t0)
 
     def summary(self) -> str:
         phases = " ".join(f"{k}={v:.2f}s" for k, v in self.phase_seconds.items())

@@ -13,16 +13,20 @@ in Perfetto (https://ui.perfetto.dev) or ``chrome://tracing``. Spans on one
 thread nest by call structure, so the viewer reconstructs the flame graph
 with no explicit parent links.
 
-When JAX is already imported, every span also enters a
-``jax.profiler.TraceAnnotation``: a ``Config.profile_dir`` XLA trace taken
-in the same run then shows these host spans on the profiler timeline,
-lined up with the device ops they dispatched. The import is lazy AND
-conditional on ``jax`` being in ``sys.modules`` — control-plane processes
-(coordinator) must be able to trace without dragging in a backend.
+When JAX is already imported, a span also enters a
+``jax.profiler.TraceAnnotation`` whenever a tracer is active or a
+``jax.profiler`` session is collecting: any XLA trace taken in the same
+run (``Config.profile_dir``, or a caller's ``jax.profiler.start_trace``)
+then shows these host spans on the profiler timeline, lined up with the
+device ops they dispatched — with no Chrome tracer, manifest or trace
+path needed. The import is lazy AND conditional on ``jax`` being in
+``sys.modules`` — control-plane processes (coordinator) must be able to
+trace without dragging in a backend.
 
-Tracing is OFF by default: ``trace_span`` with no active tracer is a
-single global read. ``run_job`` activates a tracer when
-``Config.trace_path`` is set and writes the file in its ``finally``.
+Tracing is OFF by default: ``trace_span`` with no active tracer and no
+collecting profiler is one global read and one static call.
+``run_job`` activates a tracer when ``Config.trace_path`` is set and
+writes the file in its ``finally``.
 
 Cross-process stitching (distributed-timeline tentpole): every tracer
 records a wall-clock anchor next to its ``perf_counter`` epoch, every
@@ -400,16 +404,21 @@ def active_tracer() -> "Tracer | None":
 
 @contextmanager
 def trace_span(name: str, **args):
-    """Span context: no-op (one global read) when tracing is off.
+    """Span context: no-op when tracing is off.
 
-    With a tracer active, also enters a ``jax.profiler.TraceAnnotation`` so
-    an XLA profile of the same interval shows this span on its timeline.
+    The span enters a ``jax.profiler.TraceAnnotation`` whenever a tracer
+    is active OR a ``jax.profiler`` session is collecting, so any XLA
+    profile (``Config.profile_dir``, a caller's ``start_trace``) shows it
+    on the device trace's clock, lined up with the ops it dispatched. The
+    Chrome buffer is written only with a tracer. Off path: one global
+    read and one static ``is_enabled()`` call; no annotation is built.
     """
     tr = _tracer
-    if tr is None:
+    ann_cls = _annotation_cls()
+    profiling = ann_cls is not None and ann_cls.is_enabled()
+    if tr is None and not profiling:
         yield
         return
-    ann_cls = _annotation_cls()
     ann = ann_cls(name) if ann_cls is not None else None
     if ann is not None:
         ann.__enter__()
@@ -420,7 +429,8 @@ def trace_span(name: str, **args):
         t1 = time.perf_counter()
         if ann is not None:
             ann.__exit__(None, None, None)
-        tr.add_span(name, t0, t1, args or None)
+        if tr is not None:
+            tr.add_span(name, t0, t1, args or None)
 
 
 def trace_counter(name: str, **values) -> None:

@@ -243,6 +243,37 @@ def test_pack_stager_tpu_requests_barrier():
     assert _PackStager(8, _Dev()).needs_barrier
 
 
+def test_cpu_put_never_ships_the_stager_buffer(tmp_path, monkeypatch):
+    """On the CPU backend a device_put may alias an aligned host buffer
+    (zero-copy), so a put of the stager's own buffer lets the next pack
+    rewrite a merge that has yet to run: counts then go wrong at random.
+    Every packed update put must be a buffer the stager never rewrites."""
+    import jax
+
+    from mapreduce_rust_tpu.runtime import driver
+
+    seen, stagers = [], []
+    real_put, real_init = jax.device_put, driver._PackStager.__init__
+
+    def put(x, *a, **k):
+        if isinstance(x, np.ndarray) and x.dtype == np.uint32 and x.ndim == 1:
+            seen.append(x)
+        return real_put(x, *a, **k)
+
+    def init(self, *a, **k):
+        real_init(self, *a, **k)
+        stagers.append(self)
+
+    monkeypatch.setattr(driver.jax, "device_put", put)
+    monkeypatch.setattr(driver._PackStager, "__init__", init)
+    for dtag in ("async", "sync"):
+        res = run_job(cfg_for(tmp_path, f"alias-{dtag}",
+                              **DISPATCH_CONFIGS[dtag]), write_inputs(tmp_path, TEXTS))
+        assert res.stats.merge_dispatches > 1
+    assert stagers and len(seen) >= 2
+    assert not any(np.shares_memory(x, s.flat) for x in seen for s in stagers)
+
+
 def test_coalesce_native_matches_py_fallback():
     from mapreduce_rust_tpu.native.host import coalesce_updates_into
     from mapreduce_rust_tpu.runtime.driver import _coalesce_updates_py

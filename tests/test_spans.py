@@ -1,0 +1,149 @@
+"""Program spans without the Chrome tracer: a ``jax.profiler`` session alone
+puts every ``trace_span`` on the XLA trace's host plane, the job's set-up,
+state readback and teardown are spans with ``JobStats`` fields of their
+own, and the off path builds no annotation."""
+
+import collections
+import glob
+
+import jax
+import pytest
+from jax.profiler import ProfileData
+
+from mapreduce_rust_tpu.config import Config
+from mapreduce_rust_tpu.core.normalize import reference_word_counts
+from mapreduce_rust_tpu.runtime import driver, telemetry, trace
+from mapreduce_rust_tpu.runtime.driver import run_job
+from mapreduce_rust_tpu.runtime.trace import start_tracing, stop_tracing, trace_span
+
+TEXTS = [
+    "the quick brown fox jumps over the lazy dog " * 40,
+    "pack my box with five dozen liquor jugs " * 30,
+]
+LIFECYCLE = {"job.setup", "engine.setup", "phase.stream", "state.readback",
+             "job.teardown"}
+ENGINES = {
+    "device": {},
+    "host": {"map_engine": "host", "host_map_workers": 2},
+    "mesh": {"mesh_shape": 4, "merge_capacity": 1 << 12},
+}
+
+
+@pytest.fixture(autouse=True)
+def _no_leaked_tracer():
+    stop_tracing()
+    yield
+    stop_tracing()
+
+
+def tiny_job(tmp_path, engine: str, **kw):
+    d = tmp_path / "in"
+    d.mkdir(exist_ok=True)
+    inputs = []
+    for i, t in enumerate(TEXTS):
+        p = d / f"doc-{i}.txt"
+        p.write_bytes(t.encode())
+        inputs.append(str(p))
+    cfg = Config(chunk_bytes=4096, input_dir=str(d), device="cpu",
+                 work_dir=str(tmp_path / "work"), output_dir=str(tmp_path / "out"),
+                 **ENGINES[engine], **kw)
+    return cfg, inputs
+
+
+def oracle() -> dict:
+    total = collections.Counter()
+    for t in TEXTS:
+        total.update(reference_word_counts(t.encode()))
+    return {w.encode(): c for w, c in total.items()}
+
+
+def host_span_names(trace_dir) -> set:
+    (xplane,) = glob.glob(str(trace_dir / "plugins/profile/*/*.xplane.pb"))
+    return {e.name for p in ProfileData.from_file(xplane).planes
+            if p.name.startswith("/host:") for line in p.lines for e in line.events}
+
+
+@pytest.mark.parametrize("engine", ["device", "host"])
+def test_profiler_alone_records_the_job_spans(tmp_path, monkeypatch, engine):
+    flushed = []
+    monkeypatch.setattr(telemetry, "flush_run_artifacts",
+                        lambda *a, **k: flushed.append(k))
+    cfg, inputs = tiny_job(tmp_path, engine)
+    assert cfg.trace_path is None and cfg.manifest_path is None
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path / "prof"), profiler_options=opts)
+    try:
+        res = run_job(cfg, inputs)
+    finally:
+        jax.profiler.stop_trace()
+    assert res.table == oracle()
+    names = host_span_names(tmp_path / "prof")
+    assert LIFECYCLE <= names
+    assert {"phase.finalize", "phase.egress"} <= names
+    assert ("host_map.scan" in names) == (engine == "host")
+    assert flushed == []  # no manifest, no Chrome trace
+    assert not list(tmp_path.rglob("*manifest*"))
+
+
+class _CountingAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation and counts constructions."""
+
+    made = 0
+
+    @staticmethod
+    def is_enabled() -> bool:
+        return jax.profiler.TraceAnnotation.is_enabled()
+
+    def __init__(self, name: str) -> None:
+        type(self).made += 1
+        self._ann = jax.profiler.TraceAnnotation(name)
+
+    def __enter__(self):
+        return self._ann.__enter__()
+
+    def __exit__(self, *exc):
+        return self._ann.__exit__(*exc)
+
+
+@pytest.mark.parametrize("mode,built", [("off", 0), ("tracer", 50), ("profiler", 50)])
+def test_span_builds_an_annotation_only_when_someone_collects(
+        tmp_path, monkeypatch, mode, built):
+    monkeypatch.setattr(_CountingAnnotation, "made", 0)
+    monkeypatch.setattr(trace, "_ANN", _CountingAnnotation)
+    tr = start_tracing() if mode == "tracer" else None
+    if mode == "profiler":
+        jax.profiler.start_trace(str(tmp_path / "prof"))
+    try:
+        for _ in range(50):
+            with trace_span("op", n=1):
+                pass
+    finally:
+        if mode == "profiler":
+            jax.profiler.stop_trace()
+        stop_tracing()
+    assert _CountingAnnotation.made == built
+    assert (len(tr) if tr is not None else 0) == (50 if mode == "tracer" else 0)
+
+
+@pytest.mark.parametrize("engine", ["device", "host", "mesh"])
+def test_setup_readback_and_teardown_are_timed(tmp_path, engine):
+    cfg, inputs = tiny_job(tmp_path, engine)
+    s = run_job(cfg, inputs).stats
+    assert s.job_setup_s > 0 and s.state_readback_s > 0 and s.job_teardown_s > 0
+    assert s.state_readback_s <= s.phase_seconds["stream"]
+    # engine.setup lies inside the stream phase, job.setup before it.
+    assert s.job_setup_s + s.state_readback_s <= s.wall_seconds
+
+
+def test_manifest_flush_compiles_nothing(tmp_path):
+    """The manifest is written at teardown without compiling any program:
+    every compile of the job falls inside the job's own phases."""
+    cfg, inputs = tiny_job(tmp_path, "host",
+                           manifest_path=str(tmp_path / "manifest.json"))
+    log0 = len(driver._COMPILE_LOG)
+    s = run_job(cfg, inputs).stats
+    assert s.merge_dispatches > 0
+    assert len(driver._COMPILE_LOG) - log0 == s.compile_count
+    m = telemetry.load_manifest(cfg.manifest_path)
+    assert "merge_cost" not in m
