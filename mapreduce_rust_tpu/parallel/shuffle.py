@@ -244,8 +244,8 @@ def _build_shuffle_step_fns(app: App, u_cap: int, bucket_cap: int, mesh: Mesh,
     def merge(state: KVBatch, local: KVBatch):
         st = KVBatch(*(x[0] for x in state))
         lc = KVBatch(*(x[0] for x in local))
-        # local is a count_unique output — key-sorted — so the rank-merge
-        # inserts it into the (always-sorted) state shard without a sort.
+        # local is a count_unique output — key-distinct — so it joins the
+        # state shard's sort as it is.
         new_state, evicted = merge_batches(st, lc, op=op, update_sorted=True)
         ev_count = jnp.sum(evicted.valid.astype(jnp.int32))
         return (

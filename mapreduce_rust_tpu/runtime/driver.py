@@ -329,8 +329,8 @@ def _build_step_fns(app: App, u_cap: int, use_pallas: bool = False):
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def merge(state: KVBatch, update: KVBatch):
-        # update is a count_unique output — already key-sorted, so the
-        # rank-merge inserts it without any sort at all.
+        # update is a count_unique output — already key-distinct, so it
+        # joins the state's sort as it is.
         new_state, evicted = merge_batches(state, update, op=op, update_sorted=True)
         ev_count = jnp.sum(evicted.valid.astype(jnp.int32))
         return new_state, evicted, ev_count
@@ -2160,9 +2160,9 @@ def _job_fingerprint(cfg: Config, app: App, inputs, d: int) -> str:
     for p in inputs:
         st = os.stat(p)
         h.update(f"{p}:{st.st_size}:{st.st_mtime_ns};".encode())
-    # state-v2: merge_batches now REQUIRES a sorted state (rank-merge); a
-    # checkpoint from the validity-only-clamp era can hold mid-array
-    # SENTINEL holes, which would silently mis-merge — reject it.
+    # state-v2: a checkpoint from the validity-only-clamp era can hold
+    # mid-array SENTINEL holes, which the front-packed state contract
+    # (ops/groupby.merge_batches) excludes — reject it.
     h.update(
         f"state-v2:{app.name}:{app.combine_op}:{cfg.chunk_bytes}:{d}:"
         f"{cfg.effective_partial_capacity()}:{cfg.merge_capacity}".encode()

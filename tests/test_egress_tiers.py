@@ -209,14 +209,6 @@ def test_spilled_dictionary_point_probes_raise(tmp_path):
     assert list(plain.items()) and len(plain) == 1
 
 
-def test_merge_sorted_runs_rejects_empty_haystack():
-    from mapreduce_rust_tpu.core.kv import KVBatch
-    from mapreduce_rust_tpu.ops.groupby import merge_sorted_runs
-
-    with pytest.raises(ValueError, match="zero capacity"):
-        merge_sorted_runs(KVBatch.empty(0), KVBatch.empty(4))
-
-
 def test_dictionary_spill_dedup_and_iter_sorted(tmp_path):
     plain = Dictionary()
     tiered = Dictionary(budget_words=64, spill_dir=str(tmp_path))

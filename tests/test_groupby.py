@@ -4,6 +4,7 @@ import collections
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from mapreduce_rust_tpu.core.hashing import SENTINEL
 from mapreduce_rust_tpu.core.kv import KVBatch
@@ -143,35 +144,128 @@ def test_distinct_op_merges_associatively():
     assert got == [((1, 1), 3), ((1, 1), 4), ((2, 2), 5)]
 
 
-def test_merge_sorted_runs_equals_sort():
-    # The rank-merge must produce exactly the sorted interleave lax.sort
-    # would: same multiset, globally key-sorted, padding at the back.
-    from mapreduce_rust_tpu.ops.groupby import merge_sorted_runs
+_FOLDS = {"sum": lambda a, b: a + b, "max": max, "min": min}
 
-    rng = np.random.default_rng(11)
-    for na, va, nb, vb in [(64, 40, 16, 9), (16, 3, 64, 50), (32, 0, 8, 5)]:
-        ka = np.sort(rng.choice(1 << 16, size=va, replace=False)).astype(np.uint32)
-        kb = np.sort(rng.choice(1 << 16, size=vb, replace=False)).astype(np.uint32)
-        a = make_batch(np.stack([ka, ka], 1).reshape(-1, 2), np.arange(va), na)
-        b = make_batch(np.stack([kb, kb], 1).reshape(-1, 2), 100 + np.arange(vb), nb)
-        out = merge_sorted_runs(a, b)
-        assert out.capacity == na + nb
-        k1 = np.asarray(out.k1)
-        valid = np.asarray(out.valid)
-        # Globally sorted (SENTINEL padding included) and nothing lost.
-        assert (k1[:-1] <= k1[1:]).all()
-        got = sorted(zip(k1[valid].tolist(), np.asarray(out.value)[valid].tolist()))
-        want = sorted(
-            list(zip(ka.tolist(), range(va))) + list(zip(kb.tolist(), range(100, 100 + vb)))
+
+def _rows(batch: KVBatch) -> list:
+    return list(zip(*(np.asarray(x).tolist() for x in batch)))
+
+
+def _reference_merge(state: KVBatch, update: KVBatch, op: str) -> list:
+    """merge_batches' contract, in plain Python, over the rows both sides
+    hold (valid and padding alike; the update already key-distinct): one
+    slot per distinct key — (k1, k2, value) for ``distinct`` — in ascending
+    order, holding the fold of its valid values, or SENTINEL padding where
+    the key has none; then padding to the sum of the capacities."""
+    S = int(SENTINEL)
+    runs: dict = {}
+    for k1, k2, v, ok in _rows(state) + _rows(update):
+        key = (k1, k2, v) if op == "distinct" else (k1, k2)
+        runs.setdefault(key, []).append((v, ok))
+    out = []
+    for key in sorted(runs):
+        vals = [v for v, ok in runs[key] if ok]
+        if not vals:
+            out.append((S, S, 0, False))
+        elif op == "distinct":
+            out.append((*key, True))
+        else:
+            acc = vals[0]
+            for v in vals[1:]:
+                acc = _FOLDS[op](acc, v)
+            out.append((*key, int(np.int32(acc)), True))
+    out += [(S, S, 0, False)] * (state.capacity + update.capacity - len(out))
+    return out
+
+
+@pytest.mark.parametrize("update_sorted", [True, False])
+@pytest.mark.parametrize("op", ["sum", "max", "min", "distinct"])
+def test_merge_batches_matches_reference_exactly(op, update_sorted):
+    # Exact (new_state, evicted) arrays, slot for slot, against the plain
+    # reference: cross-side key ties, a state that fills and evicts, and a
+    # real word hashing to the SENTINEL pair on both sides.
+    rng = np.random.default_rng(24)
+    S = int(SENTINEL)
+    cap, u_cap = 16, 8
+    for trial in range(12):
+        n_s = cap if trial % 3 == 0 else int(rng.integers(0, cap + 1))
+        s_keys = rng.choice(40, size=n_s, replace=False)
+        s_keys = np.stack([s_keys % 7, s_keys], 1).astype(np.uint32)
+        n_u = int(rng.integers(0, u_cap + 1))
+        u_keys = rng.integers(0, 40, size=n_u)
+        u_keys = np.stack([u_keys % 7, u_keys], 1).astype(np.uint32)
+        if trial % 2 and n_s and n_u:
+            s_keys[-1] = u_keys[0] = (S, S)  # the 2^-64 corner, both sides
+        lo, hi = (0, 4) if op == "distinct" else (-50, 50)
+        s_vals = rng.integers(lo, hi, size=n_s).astype(np.int32)
+        u_vals = rng.integers(lo, hi, size=n_u).astype(np.int32)
+        state = count_unique(make_batch(s_keys, s_vals, cap), op=op)
+        raw = make_batch(u_keys, u_vals, u_cap)
+        update = count_unique(raw, op=op)
+        new_state, evicted = merge_batches(
+            state, update if update_sorted else raw, op=op, update_sorted=update_sorted
         )
-        assert got == want
+        want = _reference_merge(state, update, op)
+        assert _rows(new_state) == want[:cap], (op, trial)
+        assert _rows(evicted) == want[cap:], (op, trial)
+
+
+def test_merge_programs_make_no_state_sized_gather_or_scatter():
+    # The merge programs at the benchmark cells' shapes (state 2**21; the
+    # device step's update 2**19, the packed host update 65,536), traced
+    # only: no gather or scatter may touch an array as long as the state.
+    import jax
+
+    from mapreduce_rust_tpu.apps.word_count import WordCount
+    from mapreduce_rust_tpu.runtime.driver import make_packed_merge_fn, make_step_fns
+
+    state_cap, u_cap, host_cap = 1 << 21, 1 << 19, 1 << 16
+
+    def batch(n):
+        return KVBatch(
+            jax.ShapeDtypeStruct((n,), jnp.uint32),
+            jax.ShapeDtypeStruct((n,), jnp.uint32),
+            jax.ShapeDtypeStruct((n,), jnp.int32),
+            jax.ShapeDtypeStruct((n,), jnp.bool_),
+        )
+
+    _map_combine, merge = make_step_fns(WordCount(), u_cap)
+    merge_packed = make_packed_merge_fn(WordCount(), host_cap)
+    programs = {
+        "merge": jax.make_jaxpr(merge)(batch(state_cap), batch(u_cap)),
+        "merge_packed": jax.make_jaxpr(merge_packed)(
+            batch(state_cap), jax.ShapeDtypeStruct((1 + 3 * host_cap,), jnp.uint32)
+        ),
+    }
+    for name, closed in programs.items():
+        eqns = list(_all_eqns(closed.jaxpr))
+        big = [
+            (eqn.primitive.name, shapes)
+            for eqn in eqns
+            if eqn.primitive.name == "gather" or eqn.primitive.name.startswith("scatter")
+            for shapes in [[v.aval.shape for v in eqn.invars + eqn.outvars]]
+            if any(dim >= state_cap for shape in shapes for dim in shape)
+        ]
+        assert big == [], (name, big)
+        assert any(eqn.primitive.name == "sort" for eqn in eqns), name
+
+
+def _all_eqns(jaxpr):
+    """Every equation of a jaxpr, those of its sub-jaxprs (pjit, cond,
+    scan bodies) included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else (param,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _all_eqns(sub)
 
 
 def test_merge_after_clamped_update_stays_sorted_and_exact():
-    # Regression for the rank-merge sortedness contract: a clamped
-    # (overflow) update must leave the state SORTED — clamp_batch turns its
-    # keys into SENTINEL padding, not mid-array holes — so later merges
-    # stay exact.
+    # Regression for the merged state's contract: a clamped (overflow)
+    # update must leave the state SORTED — clamp_batch turns its keys into
+    # SENTINEL padding, not mid-array holes — so later merges stay exact.
     from mapreduce_rust_tpu.ops.groupby import clamp_batch
 
     state = KVBatch.empty(8)
@@ -209,7 +303,7 @@ def test_merge_sentinel_hashed_word_exact():
 
 def test_merge_update_larger_than_state():
     # Replay tiers can pass an update WIDER than the state (full-width
-    # u_cap > merge_capacity): rank-merge must handle na < nb.
+    # u_cap > merge_capacity): the merge must handle na < nb.
     state = make_batch([(1, 1), (5, 5)], [3, 4], capacity=2)
     upd = make_batch([(0, 0), (1, 1), (6, 6), (7, 7), (9, 9)], [1] * 5, capacity=8)
     new_state, evicted = merge_batches(state, upd)
@@ -218,7 +312,7 @@ def test_merge_update_larger_than_state():
 
 
 def test_merge_batches_fuzz_vs_oracle():
-    # Property fuzz of the whole rank-merge + pair-combine path: random
+    # Property fuzz of the whole sort + pair-combine path: random
     # update streams (random sizes, duplicate raw keys, occasional
     # sentinel-pair keys, occasional clamped updates, all four ops) folded
     # through merge_batches; state + evictions must always equal the
