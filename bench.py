@@ -543,7 +543,9 @@ def micro_leg() -> None:
     jax.block_until_ready(jax.device_put(big, dev))
     h2d_mbps = (64 << 20) / (time.perf_counter() - t0) / 1e6
 
-    did = jax.device_put(np.int32(0), dev)
+    from mapreduce_rust_tpu.runtime.chunker import doc_table
+
+    did = jax.device_put(doc_table(0), dev)
     chunk_dev = jax.device_put(chunk, dev)
     state = jax.device_put(KVBatch.empty(cfg.merge_capacity), dev)
     upd, _ = map_combine(chunk_dev, did)

@@ -93,6 +93,7 @@ def pallas_in_map_combine() -> bool:
 
     from mapreduce_rust_tpu.apps.word_count import WordCount
     from mapreduce_rust_tpu.config import Config
+    from mapreduce_rust_tpu.runtime.chunker import DOC_SLOTS
     from mapreduce_rust_tpu.runtime.driver import make_step_fns
 
     cfg = Config()
@@ -104,7 +105,7 @@ def pallas_in_map_combine() -> bool:
     on_dev = jax.sharding.SingleDeviceSharding(jax.devices()[0])
     hlo = map_combine.lower(
         jax.ShapeDtypeStruct((cfg.chunk_bytes,), jnp.uint8, sharding=on_dev),
-        jax.ShapeDtypeStruct((), jnp.int32, sharding=on_dev),
+        jax.ShapeDtypeStruct((1 + DOC_SLOTS,), jnp.int32, sharding=on_dev),
     ).compile().as_text()
     return "tpu_custom_call" in hlo
 

@@ -7,7 +7,8 @@ count. Here an app is a first-class object the driver is parameterized by,
 split along the TPU-natural seams:
 
 - **device_map** — a jit-traceable transform applied on device to the
-  tokenized KVBatch of each chunk (e.g. stamp doc_id as the value). Runs
+  tokenized KVBatch of each chunk, given each record's doc id (e.g. stamp
+  it as the value). Runs
   inside the driver's compiled step; must be shape-preserving and pure.
 - **combine_op** — the associative reduce op (ops/groupby.REDUCE_OPS).
   Associativity is the load-bearing contract: it is what lets per-chunk
@@ -65,8 +66,9 @@ class App:
     #: attribute, like partition_mode.
     requires_corpora = 0
 
-    def device_map(self, kv: KVBatch, doc_id: jnp.ndarray) -> KVBatch:
-        """On-device per-chunk transform; doc_id is a traced int32 scalar."""
+    def device_map(self, kv: KVBatch, doc_ids: jnp.ndarray) -> KVBatch:
+        """On-device per-chunk transform; doc_ids is a traced int32 array,
+        each record's doc id (a chunk may hold several documents)."""
         return kv
 
     @property

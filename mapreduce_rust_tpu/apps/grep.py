@@ -74,7 +74,7 @@ class Grep(InvertedIndex):
     name: str = "grep"
     query: tuple[str, ...] = ()
 
-    def device_map(self, kv: KVBatch, doc_id: jnp.ndarray) -> KVBatch:
+    def device_map(self, kv: KVBatch, doc_ids: jnp.ndarray) -> KVBatch:
         from mapreduce_rust_tpu.core.hashing import SENTINEL
 
         qk = _query_keys(self.query)  # trace-time constant, Q is small
@@ -93,7 +93,7 @@ class Grep(InvertedIndex):
         return KVBatch(
             k1=jnp.where(valid, kv.k1, sent),
             k2=jnp.where(valid, kv.k2, sent),
-            value=jnp.where(valid, doc_id.astype(jnp.int32), 0),
+            value=jnp.where(valid, doc_ids, 0),
             valid=valid,
         )
 

@@ -273,6 +273,27 @@ def compact_front(batch: KVBatch, cap: int) -> tuple[KVBatch, jnp.ndarray]:
     return packed, ovf
 
 
+def slot_doc_ids(valid: jnp.ndarray, doc_table: jnp.ndarray, cap: int) -> jnp.ndarray:
+    """int32[cap]: the doc id of each slot compact_front(batch, cap) fills,
+    where ``valid`` is the batch's validity by byte position and
+    ``doc_table`` the chunk's (runtime/chunker.doc_table: first doc id,
+    then each document's start offset, NO_DOC-padded).
+
+    A token's doc id is the first doc id plus the number of starts at or
+    before it, minus one. Counted in slot space: a start at byte s is
+    passed by the slots at or past the number of tokens before s. Only
+    compares and sums over [DOC_SLOTS, positions], no gather: a chunk's
+    documents are consecutive files. An app that ignores doc ids leaves
+    all of it dead, and XLA drops it."""
+    starts = doc_table[1:]
+    pos = jnp.arange(valid.shape[0], dtype=jnp.int32)
+    before = jnp.sum((pos[None, :] < starts[:, None]) & valid[None, :],
+                     axis=1, dtype=jnp.int32)
+    slot = jnp.arange(cap, dtype=jnp.int32)
+    passed = jnp.sum(slot[None, :] >= before[:, None], axis=0, dtype=jnp.int32)
+    return doc_table[0] + passed - 1
+
+
 def clamp_batch(batch: KVBatch, keep) -> KVBatch:
     """Clamp a batch to empty unless ``keep`` (scalar bool) holds: validity
     drops AND keys become SENTINEL. Keys must go too: merge_batches makes

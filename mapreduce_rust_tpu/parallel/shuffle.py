@@ -50,6 +50,7 @@ from mapreduce_rust_tpu.ops.groupby import (
     compaction_cap,
     count_unique,
     merge_batches,
+    slot_doc_ids,
 )
 from mapreduce_rust_tpu.ops.partition import bucket_scatter
 from mapreduce_rust_tpu.ops.tokenize import tokenize_and_hash
@@ -105,14 +106,16 @@ def make_shuffle_step_fns(app: App, u_cap: int, bucket_cap: int, mesh: Mesh,
     return fns
 
 
-def _chip_shuffle_tail(kv: KVBatch, doc_id, app: App, u_cap: int,
+def _chip_shuffle_tail(kv: KVBatch, doc_table, app: App, u_cap: int,
                        bucket_cap: int, d: int, replicate_flags: bool):
     """THE shuffle body, shared by every map_shuffle variant (chunk-input,
-    kv-input, flag-replicating): device_map → combine → bucket scatter →
-    all_to_all → combine, with the clamp-on-overflow contract: if ANY chip
-    overflowed, every chip's local result clamps to empty (the psum makes
-    them agree) and the driver replays through a wider tier — which is what
-    lets merges dispatch before any flag reaches the host.
+    kv-input, flag-replicating): device_map (each record stamped with its
+    doc id from the chip's doc table, ops/groupby.slot_doc_ids) → combine
+    → bucket scatter → all_to_all → combine, with the clamp-on-overflow
+    contract: if ANY chip overflowed, every chip's local result clamps to
+    empty (the psum makes them agree) and the driver replays through a
+    wider tier — which is what lets merges dispatch before any flag
+    reaches the host.
 
     Returns (local KVBatch, p_flag, b_flag): per-chip raw counters, or the
     psum-reduced (replicated) totals when replicate_flags — the form a
@@ -126,8 +129,10 @@ def _chip_shuffle_tail(kv: KVBatch, doc_id, app: App, u_cap: int,
     with jax.named_scope("shuffle.map_combine"):
         # Compact before sorting — count_unique pays for tokens, not byte
         # positions; ops/groupby.compaction_cap is the shared sizing policy.
-        kv, c_ovf = compact_front(kv, compaction_cap(u_cap, kv.capacity))
-        mine = app.device_map(kv, doc_id)
+        cap_c = compaction_cap(u_cap, kv.capacity)
+        doc_ids = slot_doc_ids(kv.valid, doc_table, cap_c)
+        kv, c_ovf = compact_front(kv, cap_c)
+        mine = app.device_map(kv, doc_ids)
         partial = count_unique(mine, op=op)
         update = partial.take_front(u_cap)
         p_ovf = jnp.sum(partial.valid[u_cap:].astype(jnp.int32)) + c_ovf
@@ -161,8 +166,9 @@ _KV_SHUFFLE_FNS: dict = {}  # (app, u_cap, bucket_cap, mesh, width) → fn
 
 def make_kv_shuffle_step_fns(app: App, u_cap: int, bucket_cap: int, mesh: Mesh):
     """map_shuffle over PRE-TOKENIZED records: KVBatch [D, W] (one row of
-    tokens per chip, e.g. parallel/halo.make_sharded_tokenizer output) →
-    (local KVBatch [D, D*bucket_cap], partial_ovf [D], bucket_ovf [D]).
+    tokens per chip, e.g. parallel/halo.make_sharded_tokenizer output) and
+    doc tables [D, 1 + DOC_SLOTS] → (local KVBatch [D, D*bucket_cap],
+    partial_ovf [D], bucket_ovf [D]).
     The combine → bucket scatter → all_to_all → combine tail is identical
     to make_shuffle_step_fns; only the tokenizer is elsewhere. Pair with
     make_shuffle_step_fns(...)[1] for the merge."""
@@ -182,9 +188,9 @@ def _build_kv_shuffle(app: App, u_cap: int, bucket_cap: int, mesh: Mesh):
         in_specs=(P(AXIS, None), P(AXIS)),
         out_specs=(P(AXIS), P(AXIS), P(AXIS)),
     )
-    def map_shuffle_kv(kv: KVBatch, doc_ids: jnp.ndarray):
+    def map_shuffle_kv(kv: KVBatch, doc_tables: jnp.ndarray):
         local, p_ovf, b_ovf = _chip_shuffle_tail(
-            KVBatch(*(x[0] for x in kv)), doc_ids[0], app, u_cap, bucket_cap,
+            KVBatch(*(x[0] for x in kv)), doc_tables[0], app, u_cap, bucket_cap,
             d, replicate_flags=False,
         )
         return (
@@ -200,7 +206,8 @@ def _build_shuffle_step_fns(app: App, u_cap: int, bucket_cap: int, mesh: Mesh,
                             replicate_flags: bool = False):
     """(map_shuffle, merge) — the group-of-D-chunks mesh pipeline.
 
-    map_shuffle: chunks [D, chunk_bytes], doc_ids [D] →
+    map_shuffle: chunks [D, chunk_bytes], doc tables [D, 1 + DOC_SLOTS]
+        (runtime/chunker.doc_table) →
         (local KVBatch [D, D*bucket_cap], partial_ovf [D], bucket_ovf [D]).
         partial_ovf counts capacity faults on the map side — distinct keys
         past u_cap plus raw tokens past the compaction cap
@@ -223,10 +230,10 @@ def _build_shuffle_step_fns(app: App, u_cap: int, bucket_cap: int, mesh: Mesh,
         in_specs=(P(AXIS), P(AXIS)),
         out_specs=(P(AXIS), P(AXIS), P(AXIS)),
     )
-    def map_shuffle(chunks: jnp.ndarray, doc_ids: jnp.ndarray):
+    def map_shuffle(chunks: jnp.ndarray, doc_tables: jnp.ndarray):
         local, p_ovf, b_ovf = _chip_shuffle_tail(
             tokenize_and_hash(chunks[0], use_pallas=use_pallas),
-            doc_ids[0], app, u_cap, bucket_cap,
+            doc_tables[0], app, u_cap, bucket_cap,
             d, replicate_flags,
         )
         return (

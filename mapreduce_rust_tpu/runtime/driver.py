@@ -70,9 +70,15 @@ from mapreduce_rust_tpu.ops.groupby import (
     compaction_cap,
     count_unique,
     merge_batches,
+    slot_doc_ids,
 )
 from mapreduce_rust_tpu.ops.tokenize import tokenize_and_hash
-from mapreduce_rust_tpu.runtime.chunker import chunk_stream, resolve_corpora
+from mapreduce_rust_tpu.runtime.chunker import (
+    doc_table,
+    open_documents,
+    pack_documents,
+    resolve_corpora,
+)
 from mapreduce_rust_tpu.runtime.dictionary import (
     Dictionary,
     ShardedDictionary,
@@ -284,7 +290,10 @@ _STEP_FNS: dict = {}  # (app, u_cap, use_pallas) → (map_combine, merge)
 def make_step_fns(app: App, u_cap: int, use_pallas: bool = False):
     """(map_combine, merge) jitted for one app + update capacity.
 
-    map_combine: chunk bytes → compacted per-chunk partial + overflow count.
+    map_combine: (chunk bytes, its doc table — runtime/chunker.doc_table)
+    → compacted per-chunk partial + overflow count. Each token's doc id
+    (ops/groupby.slot_doc_ids) reaches only app.device_map: an app that
+    ignores it (word count) compiles to the same program as without it.
     merge: fold the partial into the running state, returning the evicted
     tail and its record count (donates the old state's buffers).
     use_pallas: target is a TPU — tokenize with the fused Mosaic kernel.
@@ -306,15 +315,17 @@ def _build_step_fns(app: App, u_cap: int, use_pallas: bool = False):
     op = app.combine_op
 
     @jax.jit
-    def map_combine(chunk: jnp.ndarray, doc_id: jnp.ndarray):
+    def map_combine(chunk: jnp.ndarray, doc_table: jnp.ndarray):
         kv = tokenize_and_hash(chunk, use_pallas=use_pallas)
         # Compact before sorting: count_unique pays for tokens, not bytes
         # (~6x fewer sort slots on text); ops/groupby.compaction_cap is the
         # shared sizing policy. NOTE: the overflow flag below therefore
         # covers BOTH distinct keys > u_cap AND raw tokens > cap_c — either
         # replays the chunk through the full-width tier.
-        kv, c_ovf = compact_front(kv, compaction_cap(u_cap, chunk.shape[0]))
-        kv = app.device_map(kv, doc_id)
+        cap_c = compaction_cap(u_cap, chunk.shape[0])
+        doc_ids = slot_doc_ids(kv.valid, doc_table, cap_c)
+        kv, c_ovf = compact_front(kv, cap_c)
+        kv = app.device_map(kv, doc_ids)
         partial = count_unique(kv, op=op)
         update = partial.take_front(u_cap)
         ovf = jnp.sum(partial.valid[u_cap:].astype(jnp.int32)) + c_ovf
@@ -734,8 +745,10 @@ class _IngestStream:
     """Shared ingest: a prefetch thread runs read→normalize→chunk ahead of
     the consumer (bounded queue), and a thread pool runs the dictionary
     scans; scan results fold into the Dictionary only on the consumer
-    thread. doc_id = position in inputs + doc_id_offset (a worker's map
-    task passes its task id so inverted_index doc ids stay global)."""
+    thread. The producer packs consecutive documents into chunks
+    (runtime/chunker.pack_documents); doc_id = position in inputs +
+    doc_id_offset (a worker's map task passes its task id so
+    inverted_index doc ids stay global), or ``doc_ids[i]``."""
 
     def __init__(self, cfg: Config, inputs: Sequence[str], stats: JobStats,
                  dictionary: Dictionary, doc_id_offset: int = 0,
@@ -793,16 +806,18 @@ class _IngestStream:
         # (disjoint from the consumer's fields); under the sanitizer it must
         # say so, or its first write raises. No-op otherwise.
         stats.register_writer()
+
+        def opened(path) -> None:
+            stats.bytes_in += os.path.getsize(path)
+
+        doc_ids = self._doc_ids or range(doc_id_offset, doc_id_offset + len(inputs))
         try:
-            for i, path in enumerate(inputs):
-                doc = self._doc_ids[i] if self._doc_ids else doc_id_offset + i
-                stats.bytes_in += os.path.getsize(path)
-                with open(path, "rb") as f:
-                    for chunk in chunk_stream(f, doc, self.cfg.chunk_bytes):
-                        stats.chunks += 1
-                        stats.forced_cuts += int(chunk.forced_cut)
-                        if not self._put(chunk):
-                            return
+            docs = open_documents(inputs, doc_ids, on_open=opened)
+            for chunk in pack_documents(docs, self.cfg.chunk_bytes):
+                stats.chunks += 1
+                stats.forced_cuts += int(chunk.forced_cut)
+                if not self._put(chunk):
+                    return
         except BaseException as e:  # re-raised on the consumer thread
             self.err = e
         finally:
@@ -853,7 +868,7 @@ class _IngestStream:
                 continue
             self.scans.append(
                 (self.pool.submit(scan, bytes(chunk.data[: chunk.nbytes])),
-                 chunk.doc_id)
+                 chunk.first_doc)
             )
             # Backpressure: each pending future pins a chunk-sized payload;
             # fold the oldest (blocking) once the backlog exceeds the pool.
@@ -900,9 +915,9 @@ def _stream_single(cfg: Config, app: App, inputs, stats, acc, dictionary,
                                host_mask=app.host_mask,
                                lineage_range=app.partition_mode == "range")
     slow_fns = None  # full-width replay path, compiled only if ever needed
-    pending: collections.deque = collections.deque()  # (ovf, ev_count, evicted, chunk_host, did)
+    pending: collections.deque = collections.deque()  # (ovf, ev_count, evicted, chunk_host, table)
 
-    def replay_chunk(chunk_host: np.ndarray, doc_id) -> None:
+    def replay_chunk(chunk_host: np.ndarray, table) -> None:
         # More distinct keys in the chunk than partial_capacity: the fast
         # path clamped its update to empty (make_step_fns), so re-run the
         # whole chunk at full width. Exact, never silent (VERDICT r1 weak 3).
@@ -911,12 +926,13 @@ def _stream_single(cfg: Config, app: App, inputs, stats, acc, dictionary,
         if slow_fns is None:
             slow_fns = make_step_fns(app, cfg.chunk_bytes, use_pallas)
         with trace_span("chunk.replay"):
-            update, _ = slow_fns[0](jax.device_put(chunk_host, device), doc_id)
+            update, _ = slow_fns[0](jax.device_put(chunk_host, device), table)
             state, evicted, ev_count = slow_fns[1](state, update)
             if int(ev_count) > 0:
                 stats.spill_events += 1
                 stats.spilled_keys += int(ev_count)
-                acc.add_batch(evicted)
+                with stats.timed("spill_evict_s", "spill"):
+                    acc.add_batch(evicted)
 
     def drain(n: int) -> None:
         # Resolve the oldest n pipeline steps with ONE batched readback:
@@ -933,28 +949,30 @@ def _stream_single(cfg: Config, app: App, inputs, stats, acc, dictionary,
         stats.device_wait_s += dt
         stats.record_hist("device.drain_s", dt)
         _sample_device_memory(stats)
-        for (ovf, evc, evicted, chunk_host, did), ovf_n, ev_n in zip(
+        for (ovf, evc, evicted, chunk_host, table), ovf_n, ev_n in zip(
             batch, flat[::2], flat[1::2]
         ):
             if int(ev_n) > 0:
                 stats.spill_events += 1
                 stats.spilled_keys += int(ev_n)
-                with trace_span("spill", keys=int(ev_n)):
+                with stats.timed("spill_evict_s", "spill", keys=int(ev_n)):
                     acc.add_batch(evicted)
             if int(ovf_n) > 0:
-                replay_chunk(chunk_host, did)
+                replay_chunk(chunk_host, table)
 
     try:
         for chunk in ingest:
             with trace_span("chunk.dispatch"):
-                chunk_dev = jax.device_put(chunk.data, device)
-                did = jax.device_put(np.int32(chunk.doc_id), device)
-                update, ovf = map_combine(chunk_dev, did)
+                # One put for the chunk and its doc table.
+                chunk_dev, table = jax.device_put((chunk.data, chunk.doc_table), device)
+                stats.chunks_dispatched += 1
+                stats.chunk_payload_bytes += chunk.nbytes
+                update, ovf = map_combine(chunk_dev, table)
                 # Merge dispatches immediately — an overflowed update is
                 # empty on device, so merging before the flag reaches the
                 # host is safe.
                 state, evicted, ev_count = merge(state, update)
-                pending.append((ovf, ev_count, evicted, chunk.data, did))
+                pending.append((ovf, ev_count, evicted, chunk.data, table))
             # Keep one window in flight while draining the previous one, so
             # the batched readback's round trip overlaps dispatched work.
             if len(pending) >= 2 * depth:
@@ -2162,9 +2180,11 @@ def _job_fingerprint(cfg: Config, app: App, inputs, d: int) -> str:
         h.update(f"{p}:{st.st_size}:{st.st_mtime_ns};".encode())
     # state-v2: a checkpoint from the validity-only-clamp era can hold
     # mid-array SENTINEL holes, which the front-packed state contract
-    # (ops/groupby.merge_batches) excludes — reject it.
+    # (ops/groupby.merge_batches) excludes — reject it. state-v3: chunks
+    # pack documents, so an older checkpoint's group count means other
+    # chunks.
     h.update(
-        f"state-v2:{app.name}:{app.combine_op}:{cfg.chunk_bytes}:{d}:"
+        f"state-v3:{app.name}:{app.combine_op}:{cfg.chunk_bytes}:{d}:"
         f"{cfg.effective_partial_capacity()}:{cfg.merge_capacity}".encode()
     )
     return h.hexdigest()
@@ -2301,9 +2321,7 @@ def _stream_multihost(cfg: Config, app: App, inputs, stats, acc, dictionary) -> 
     def run_round(chunks_np: np.ndarray, docs_np: np.ndarray, have: int) -> bool:
         nonlocal state
         chunks_g = to_global(chunks_np, (d, cfg.chunk_bytes))
-        docs_g = jax.make_array_from_process_local_data(
-            flag_shard, docs_np, global_shape=(d,)
-        )
+        docs_g = to_global(docs_np, (d, docs_np.shape[1]))
         stats.mesh_rounds += 1
         stats.shuffle_wire_bytes += wire_bytes_per_round(d, bucket_cap)
         with _a2a_span(stats, round=stats.mesh_rounds, tier="fast",
@@ -2365,21 +2383,21 @@ def _stream_multihost(cfg: Config, app: App, inputs, stats, acc, dictionary) -> 
     try:
         while True:
             rows: list[np.ndarray] = []
-            docs: list[int] = []
+            docs: list[np.ndarray] = []
             while not exhausted and len(rows) < d_local:
                 try:
                     chunk = next(it)
                     rows.append(chunk.data)
-                    docs.append(chunk.doc_id)
+                    docs.append(chunk.doc_table)
+                    stats.chunks_dispatched += 1
+                    stats.chunk_payload_bytes += chunk.nbytes
                 except StopIteration:
                     exhausted = True
             have = 1 if rows else 0
             while len(rows) < d_local:  # pad my contribution with spaces
                 rows.append(np.full(cfg.chunk_bytes, 0x20, dtype=np.uint8))
-                docs.append(0)
-            any_data = run_round(
-                np.stack(rows), np.asarray(docs, dtype=np.int32), have
-            )
+                docs.append(doc_table(0))
+            any_data = run_round(np.stack(rows), np.stack(docs), have)
             if not any_data:
                 break
     except BaseException:
@@ -2526,6 +2544,10 @@ def _stream_sharded(cfg: Config, app: App, inputs, stats, acc, dictionary) -> No
     pending: collections.deque = collections.deque()
     shard_bytes = max(cfg.chunk_bytes, 2 * cfg.max_word_len + 8)
 
+    def tables(doc_id: int) -> np.ndarray:
+        # A window is one document: every chip's share of it starts there.
+        return np.stack([doc_table(doc_id)] * d)
+
     def replay_group(group_bytes: bytes, doc_id: int, p_n: int) -> None:
         # The fast path clamped the whole group to empty on device, so
         # re-run it through the full-width tier (u_cap = the whole token
@@ -2539,7 +2561,7 @@ def _stream_sharded(cfg: Config, app: App, inputs, stats, acc, dictionary) -> No
             wide["fns"] = make_kv_shuffle_step_fns(app, w_cap, w_cap, mesh)
             wide["merge"] = make_shuffle_step_fns(app, w_cap, w_cap, mesh)[1]
         shards = jax.device_put(shard_stream(group_bytes, mesh, pad=shard_bytes), in_shard)
-        docs = jax.device_put(np.full(d, doc_id, dtype=np.int32), rep)
+        docs = jax.device_put(tables(doc_id), rep)
         stats.mesh_rounds += 1
         stats.shuffle_wire_bytes += wire_bytes_per_round(
             d, cfg.max_word_len + shard_bytes + 1
@@ -2635,9 +2657,7 @@ def _stream_sharded(cfg: Config, app: App, inputs, stats, acc, dictionary) -> No
                 shards = jax.device_put(
                     shard_stream(group, mesh, pad=shard_bytes), in_shard
                 )
-                docs = jax.device_put(
-                    np.full(d, doc_id, dtype=np.int32), rep
-                )
+                docs = jax.device_put(tables(doc_id), rep)
                 kv, trunc = tokenize(shards)
                 local, p_ovf, b_ovf = kv_shuffle(kv, docs)
                 state, evicted, ev_counts = merge(state, local)
@@ -2757,15 +2777,15 @@ def _stream_mesh(cfg: Config, app: App, inputs, stats, acc, dictionary) -> None:
                 replay_group(chunks_host, docs_host, p_n)
 
     group_chunks: list[np.ndarray] = []
-    group_docs: list[int] = []
+    group_docs: list[np.ndarray] = []
 
     def submit_group() -> None:
         nonlocal state, groups_done
         while len(group_chunks) < d:  # pad the tail group with space chunks
             group_chunks.append(np.full(cfg.chunk_bytes, 0x20, dtype=np.uint8))
-            group_docs.append(0)
+            group_docs.append(doc_table(0))
         chunks_host = np.stack(group_chunks)
-        docs_host = np.asarray(group_docs, dtype=np.int32)
+        docs_host = np.stack(group_docs)
         group_chunks.clear()
         group_docs.clear()
         stats.mesh_rounds += 1
@@ -2801,7 +2821,9 @@ def _stream_mesh(cfg: Config, app: App, inputs, stats, acc, dictionary) -> None:
     try:
         for chunk in ingest:
             group_chunks.append(chunk.data)
-            group_docs.append(chunk.doc_id)
+            group_docs.append(chunk.doc_table)
+            stats.chunks_dispatched += 1
+            stats.chunk_payload_bytes += chunk.nbytes
             if len(group_chunks) == d:
                 submit_group()
         if group_chunks:
@@ -3047,6 +3069,14 @@ def run_job(
                 "into RAM (exact, but unbounded)", app.name
             )
 
+        is_distinct = app.combine_op == "distinct"
+
+        def postings():
+            """A distinct app's grouping of (term, doc) rows into posting
+            lists and their formatting (as in _stream_finalize)."""
+            return (stats.timed("posting_format_s", "egress.postings")
+                    if is_distinct else contextlib.nullcontext())
+
         if streaming:
             # _stream_finalize opens its own finalize/egress phase blocks —
             # nesting both here would double-count one interval under two keys.
@@ -3054,12 +3084,11 @@ def run_job(
                 cfg, app, stats, acc, dictionary, write_outputs
             )
         else:
-            with stats.phase("finalize"):
+            with stats.phase("finalize"), postings():
                 stats.distinct_keys = len(acc.table)
                 stats.dictionary_words = len(dictionary)
                 stats.hash_collisions = len(dictionary.collisions)
                 items = []
-                is_distinct = app.combine_op == "distinct"
                 lookup = dictionary.lookup
                 if dictionary.spilled:
                     # Rehydrate fallback: serve point lookups from the full
@@ -3076,7 +3105,8 @@ def run_job(
                     table[word] = value
 
             with stats.phase("egress"):
-                parts = app.finalize(items, cfg.reduce_n)
+                with postings():
+                    parts = app.finalize(items, cfg.reduce_n)
                 if write_outputs:
                     os.makedirs(cfg.output_dir, exist_ok=True)
                     # Multi-process: each process emits ITS hash classes'
@@ -3315,12 +3345,13 @@ def _stream_finalize(cfg: Config, app: App, stats: JobStats, acc: HostAccumulato
                     # per line: the formatted lines batch through a join.
                     blk_lines: list[list] = [[] for _ in range(cfg.reduce_n)]
                     if is_distinct:
-                        for w, r, i, j2 in zip(
-                            words, rr, pos_h.tolist(), ends_g[hits].tolist()
-                        ):
-                            blk_lines[r].extend(
-                                emit(w, sorted(rows[i:j2, 2].tolist()))
-                            )
+                        with stats.timed("posting_format_s", "egress.postings"):
+                            for w, r, i, j2 in zip(
+                                words, rr, pos_h.tolist(), ends_g[hits].tolist()
+                            ):
+                                blk_lines[r].extend(
+                                    emit(w, sorted(rows[i:j2, 2].tolist()))
+                                )
                     else:
                         for w, r, v in zip(
                             words, rr, rows[pos_h, 2].tolist()

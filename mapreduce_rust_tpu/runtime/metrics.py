@@ -61,6 +61,10 @@ log = logging.getLogger("mapreduce_rust_tpu")
 class JobStats:
     bytes_in: int = 0
     chunks: int = 0
+    chunks_dispatched: int = 0    # chunks the device engines sent to the
+    # device (single chip, mesh, multihost; replays not counted again)
+    chunk_payload_bytes: int = 0  # document bytes those chunks carried:
+    # over chunks_dispatched x chunk_bytes, how full the packing kept them
     forced_cuts: int = 0          # tokens longer than chunk_bytes, split
     distinct_keys: int = 0        # final distinct key count
     spill_events: int = 0         # merges whose evicted tail was non-empty
@@ -98,6 +102,10 @@ class JobStats:
     # selection, step fns, the state's device_put, pools)
     state_readback_s: float = 0.0  # "state.readback": the final device
     # state fetched into the host accumulator at the end of the stream
+    spill_evict_s: float = 0.0    # "spill": the single-chip stream moving
+    # evicted state tails device → host accumulator (drain, replays)
+    posting_format_s: float = 0.0  # "egress.postings": a distinct app's
+    # per-term grouping of (term, doc) rows and posting-list formatting
     job_teardown_s: float = 0.0   # "job.teardown": run_job's finally
     # (spill cleanup, profiler/ledger stop, manifest flush) — the manifest
     # it writes is flushed inside this interval, so it reads 0 there
@@ -290,13 +298,13 @@ class JobStats:
             log.info("phase %-10s %8.3fs", name, dt)
 
     @contextmanager
-    def timed(self, field: str, span: str):
+    def timed(self, field: str, span: str, **span_args):
         """Add the block's seconds to the float ``field`` and trace it as
         ``span`` — the pattern of phase(), for intervals that are not
         phases (job set-up, the state readback, teardown)."""
         t0 = time.perf_counter()
         try:
-            with trace_span(span):
+            with trace_span(span, **span_args):
                 yield
         finally:
             setattr(self, field, getattr(self, field) + time.perf_counter() - t0)

@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 from mapreduce_rust_tpu.core.normalize import reference_word_counts
-from mapreduce_rust_tpu.runtime.chunker import Chunk, chunk_document, split_points
+from mapreduce_rust_tpu.runtime.chunker import NO_DOC, Chunk, chunk_document
 
 CORPUS = pathlib.Path("/root/reference/src/data")
 
@@ -24,18 +24,23 @@ def chunk_word_counts(raw: bytes, chunk_bytes: int, **kw) -> collections.Counter
 
 def test_spans_cover_and_align():
     data = b"the quick brown fox jumps over the lazy dog " * 50
-    spans = split_points(data, 64)
-    assert spans[0][0] == 0 and spans[-1][1] == len(data)
-    for (s0, e0, f0), (s1, e1, f1) in zip(spans, spans[1:]):
-        assert e0 == s1
-        assert data[e0 - 1 : e0] in (b" ", b"\n")  # whitespace-aligned cut
-        assert not f0 and not f1
+    chunks = list(chunk_document(data, 0, 64, normalize=False))
+    payloads = [bytes(c.data[: c.nbytes]) for c in chunks]
+    assert b"".join(payloads) == data  # the chunks cover the document
+    off = 0
+    for p in payloads[:-1]:
+        assert p[-1:] in (b" ", b"\n")  # whitespace-aligned cut
+        off += len(p)
+        # greedy: the next word and its space would not have fit
+        assert len(p) + len(data[off:].split(b" ", 1)[0]) + 1 > 64
+    assert not any(c.forced_cut for c in chunks)
 
 
 def test_forced_cut_flagged():
     data = b"x" * 200  # one giant token
-    spans = split_points(data, 64)
-    assert any(f for _, _, f in spans)
+    chunks = list(chunk_document(data, 0, 64, normalize=False))
+    assert [c.nbytes for c in chunks] == [64, 64, 64, 8]
+    assert [c.forced_cut for c in chunks] == [True, True, True, False]
     chunks = list(chunk_document(data, 0, 64, normalize=False, window_bytes=64))
     assert any(c.forced_cut for c in chunks[:-1])
 
@@ -62,7 +67,8 @@ def test_chunks_are_fixed_shape_and_space_padded():
     chunks = list(chunk_document(raw, 3, 64))
     assert len(chunks) == 1
     c = chunks[0]
-    assert isinstance(c, Chunk) and c.doc_id == 3 and c.seq == 0
+    assert isinstance(c, Chunk) and c.first_doc == 3 and c.seq == 0
+    assert c.doc_table[1] == 0 and (c.doc_table[2:] == NO_DOC).all()
     assert c.data.shape == (64,) and c.data.dtype == np.uint8
     assert bytes(c.data[c.nbytes :]) == b" " * (64 - c.nbytes)
 

@@ -41,8 +41,10 @@ _WORKER = textwrap.dedent(
         chunks[j, : len(row)] = np.frombuffer(row, dtype=np.uint8)
     sh = NamedSharding(mesh, P(AXIS))
     chunks_g = jax.make_array_from_process_local_data(sh, chunks, global_shape=(d, 256))
+    from mapreduce_rust_tpu.runtime.chunker import doc_table
+    tables = np.stack([doc_table(0)] * nloc)
     docs_g = jax.make_array_from_process_local_data(
-        sh, np.zeros(nloc, np.int32), global_shape=(d,))
+        sh, tables, global_shape=(d, tables.shape[1]))
     local, p_ovf, b_ovf = fns[0](chunks_g, docs_g)
     state, evicted, ev = fns[1](state, local)
     n_local_keys = sum(

@@ -11,6 +11,7 @@ from mapreduce_rust_tpu.apps import InvertedIndex, WordCount
 from mapreduce_rust_tpu.config import Config
 from mapreduce_rust_tpu.core.normalize import reference_word_counts
 from mapreduce_rust_tpu.parallel.shuffle import make_mesh, make_shuffle_step_fns
+from mapreduce_rust_tpu.runtime.chunker import doc_table
 from mapreduce_rust_tpu.runtime.driver import run_job
 
 CORPUS = pathlib.Path("/root/reference/src/data")
@@ -131,7 +132,8 @@ def test_shuffle_partitions_keys_by_hash_class():
     chunks = np.full((4, 512), 0x20, dtype=np.uint8)
     for i, t in enumerate(texts):
         chunks[i, : len(t)] = np.frombuffer(t, dtype=np.uint8)
-    local, p_ovf, b_ovf = fns[0](chunks, np.zeros(4, dtype=np.int32))
+    tables = np.stack([doc_table(0)] * 4)
+    local, p_ovf, b_ovf = fns[0](chunks, tables)
     assert int(np.sum(p_ovf)) == 0 and int(np.sum(b_ovf)) == 0
     k1 = np.asarray(local.k1)
     valid = np.asarray(local.valid)

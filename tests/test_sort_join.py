@@ -371,9 +371,11 @@ def test_resolve_corpora_bounds_and_chunk_tagging(tmp_path):
     cfg = _join_cfg(tmp_path, "bounds")
     inputs, bounds, names = resolve_corpora(cfg)
     assert len(inputs) == 5 and bounds == (2,) and names == ("a", "b")
-    # The chunker tags each chunk with its document's corpus id.
-    corpora = {c.doc_id: c.corpus
-               for c in iter_chunks(inputs, 4096, corpus_bounds=bounds)}
+    # The chunker packs each corpus on its own and tags each chunk with
+    # its documents' corpus id.
+    corpora = {d: c.corpus
+               for c in iter_chunks(inputs, 4096, corpus_bounds=bounds)
+               for d in range(c.first_doc, c.first_doc + c.n_docs)}
     assert corpora == {0: 0, 1: 0, 2: 1, 3: 1, 4: 1}
 
 

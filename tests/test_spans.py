@@ -147,3 +147,32 @@ def test_manifest_flush_compiles_nothing(tmp_path):
     assert len(driver._COMPILE_LOG) - log0 == s.compile_count
     m = telemetry.load_manifest(cfg.manifest_path)
     assert "merge_cost" not in m
+
+
+def test_dispatch_counters_spill_and_posting_spans(tmp_path):
+    """chunks_dispatched and chunk_payload_bytes count what the device
+    engine sent; spill_evict_s times the "spill" span of the stream's
+    evictions and posting_format_s the "egress.postings" span of a distinct
+    app's egress, both seen on the profiler's host plane."""
+    from mapreduce_rust_tpu.apps import InvertedIndex
+
+    cfg, inputs = tiny_job(tmp_path, "device", merge_capacity=8)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path / "prof"), profiler_options=opts)
+    try:
+        s = run_job(cfg, inputs, app=InvertedIndex()).stats
+    finally:
+        jax.profiler.stop_trace()
+    # Two documents of 1,760 and 1,200 bytes share one 4,096-byte chunk.
+    assert s.chunks_dispatched == 1
+    assert s.chunk_payload_bytes == sum(len(t) for t in TEXTS)
+    assert s.spill_events > 0 and s.spill_evict_s > 0
+    assert s.posting_format_s > 0
+    assert s.posting_format_s <= s.phase_seconds["finalize"] + s.phase_seconds["egress"]
+    assert {"spill", "egress.postings"} <= host_span_names(tmp_path / "prof")
+    # Word count groups no postings and, here, spills nothing.
+    (tmp_path / "wc").mkdir()
+    w = run_job(*tiny_job(tmp_path / "wc", "device")).stats
+    assert w.posting_format_s == 0 and w.spill_evict_s == 0 and w.spill_events == 0
+    assert w.chunks_dispatched == 1
