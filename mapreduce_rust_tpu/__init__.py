@@ -7,7 +7,7 @@ shuffle, sort-and-group reduce, pluggable map/reduce apps) designed TPU-first:
 - Data plane: JAX/XLA. Tokenize→hash runs on-chip over padded uint8 byte
   arrays (segmented associative-scan polynomial hashing), the shuffle is a
   ``lax.all_to_all`` over ICI inside ``shard_map``, and the group-by reduce is
-  ``lax.sort`` + ``segment_sum``. Strings exist only at ingest/egress.
+  ``lax.sort`` + neighbour compares. Strings exist only at ingest/egress.
 - Control plane: a small asyncio JSON-RPC coordinator preserving the
   reference's scheduler semantics (worker registration barrier, -1/-2/-3
   task sentinels, leases with expiry + re-execution) — see

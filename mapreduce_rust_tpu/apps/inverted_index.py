@@ -7,7 +7,7 @@ file's index (runtime/chunker.py). The TPU formulation:
 
 - the chunker packs consecutive documents into one chunk with a table of
   where each starts; device_map stamps every record with its own doc_id
-  (ops/groupby.slot_doc_ids), so the stream becomes (term-hash, doc_id)
+  (ops/groupby.position_doc_ids), so the stream becomes (term-hash, doc_id)
   pairs;
 - combine_op "distinct" makes the value part of the sort key
   (ops/groupby.py): duplicates of (term, doc) collapse on device, and the

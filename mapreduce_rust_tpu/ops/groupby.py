@@ -1,13 +1,14 @@
-"""Sort + segment group-by reduce — the TPU-native reduce engine.
+"""Sort + run reduce group-by — the TPU-native reduce engine.
 
 Replaces the reference reduce path (src/mr/worker.rs:157-193): there, all
 pairs of a partition are parsed from files, ``sort_by`` key
 (worker.rs:162-164), then a streaming group-by calls the reduce UDF per key
 run (worker.rs:169-184 — with the last group silently dropped, a bug we do
 not reproduce). Here the same shape is ``lax.sort`` on the hash-pair key
-(lexicographic, num_keys=2) followed by segment-boundary detection and
-``jax.ops.segment_sum`` — every group flushed, including the last, by
-construction.
+(lexicographic) followed by run-boundary detection by neighbour compares,
+one representative row a run, and a second sort that packs the
+representatives to the front — every group flushed, including the last,
+by construction.
 
 Reduce ops (the associative-combiner contract every app must satisfy):
 
@@ -22,13 +23,11 @@ Reduce ops (the associative-combiner contract every app must satisfy):
 All functions keep static shapes: outputs are padded to the input capacity
 with SENTINEL keys so they stay jit/shard_map-friendly.
 
-The running-state merge (merge_batches) is sorts and neighbour compares
-only: one ``lax.sort`` interleaves state and update, shifted compares
-combine each key's (at most two) copies, and a second sort on the run
-heads' ranks compacts them to the front. It makes no gather or scatter
-whose index count scales with the state's capacity: on the v5e every
-data-dependent element access costs roughly 10-15 ns, while XLA's sort
-streams.
+Both the map-side combine (combine_chunk, count_unique) and the
+running-state merge (merge_batches) are sorts, prefix sums and neighbour
+compares only. They make no gather or scatter whose index count scales
+with a chunk, an update or the state: on the v5e every data-dependent
+element access costs roughly 10-15 ns, while XLA's sort streams.
 """
 
 from __future__ import annotations
@@ -53,9 +52,8 @@ def sort_kv(batch: KVBatch, by_value: bool = False) -> KVBatch:
     """
     num_keys = 3 if by_value else 2
     # Unstable: ~25% cheaper comparator (measured on XLA CPU at 320K rows,
-    # 163→123 ms) and tie order is immaterial — every consumer aggregates
-    # whole key segments (segment_reduce_sorted, combine_adjacent_unique's
-    # commutative pair combine), so records tied on the full key set
+    # 163→123 ms) and tie order is immaterial — combine_adjacent_unique's
+    # pair combine is commutative, so records tied on the full key set
     # produce identical results in any order.
     k1, k2, value, valid = jax.lax.sort(
         (batch.k1, batch.k2, batch.value, batch.valid.astype(jnp.int32)),
@@ -79,12 +77,11 @@ def combine_adjacent_unique(merged: KVBatch, op: str = "sum") -> KVBatch:
     update are both count_unique-style reduced).
 
     Same output contract as segment_reduce_sorted — front-packed distinct
-    keys, SENTINEL fill — but via shifted compares plus ONE compaction sort
-    instead of seven segment ops (each a scatter over the whole batch).
+    keys, SENTINEL fill — by shifted compares that fold each key's two
+    copies in place, plus the compaction sort (_compact_runs).
     """
     if op not in REDUCE_OPS:
         raise ValueError(f"unknown reduce op: {op}")
-    n = merged.capacity
     k1, k2, val, valid = merged.k1, merged.k2, merged.value, merged.valid
     by_value = op in _VALUE_KEYED_OPS
     eq = (k1[:-1] == k1[1:]) & (k2[:-1] == k2[1:])
@@ -135,9 +132,16 @@ def combine_adjacent_unique(merged: KVBatch, op: str = "sum") -> KVBatch:
         head = first & is_sent
         live = live | (head & jnp.any(valid & is_sent))
         pairv = jnp.where(head, sent_total, pairv)
-    # Compact run heads to the front, in order, by one sort on their rank;
-    # every other row (all SENTINEL, 0, invalid) ties at n behind them, so
-    # the unstable sort is exact. Liveness rides in the key's low bit.
+    return _compact_runs(first, live, k1, k2, pairv)
+
+
+def _compact_runs(first, live, k1, k2, value) -> KVBatch:
+    """Pack the rows marked ``first`` (one a run) to the front, in order,
+    by one sort on their rank; every other row ties at n behind them as
+    (SENTINEL, 0, invalid), so the unstable sort is exact. ``live`` (a
+    subset of ``first``) marks the runs that hold a record; it rides in
+    the key's low bit and becomes the output's validity."""
+    n = k1.shape[0]
     dest = jnp.where(first, jnp.cumsum(first.astype(jnp.int32)) - 1, n)
     sent = jnp.uint32(SENTINEL)
     key, k1, k2, value = jax.lax.sort(
@@ -145,7 +149,7 @@ def combine_adjacent_unique(merged: KVBatch, op: str = "sum") -> KVBatch:
             2 * dest + live.astype(jnp.int32),
             jnp.where(live, k1, sent),
             jnp.where(live, k2, sent),
-            jnp.where(live, pairv, 0),
+            jnp.where(live, value, 0),
         ),
         num_keys=1,
         is_stable=False,
@@ -153,72 +157,61 @@ def combine_adjacent_unique(merged: KVBatch, op: str = "sum") -> KVBatch:
     return KVBatch(k1, k2, value, (key & 1) == 1)
 
 
+def sort_valid_first(batch: KVBatch, op: str = "sum") -> KVBatch:
+    """Sort a batch into the order segment_reduce_sorted reduces: by
+    (k1, k2), valid records before padding on a tie, then by value for
+    every op but ``sum``. Invalid records become (SENTINEL, SENTINEL, 0)
+    first, so padding is one run at the very end and the valid records
+    are a prefix — a real word hashing to the SENTINEL pair included."""
+    sent = jnp.uint32(SENTINEL)
+    valid = batch.valid
+    k1, k2, pad, value = jax.lax.sort(
+        (
+            jnp.where(valid, batch.k1, sent),
+            jnp.where(valid, batch.k2, sent),
+            (~valid).astype(jnp.int32),
+            jnp.where(valid, batch.value, 0),
+        ),
+        # Unstable: rows tied on every key are interchangeable for a sum.
+        num_keys=3 if op == "sum" else 4,
+        is_stable=False,
+    )
+    return KVBatch(k1, k2, value, pad == 0)
+
+
 def segment_reduce_sorted(batch: KVBatch, op: str = "sum") -> KVBatch:
-    """Reduce a key-sorted batch: one output record per distinct key.
+    """Reduce a sort_valid_first-ordered batch: one output record per
+    distinct key.
 
     op: "sum" (word count totals), "max"/"min" over values, or "distinct"
-    (batch must be sorted with ``by_value=True``; one record per distinct
-    (key, value) pair, value preserved).
+    (one record per distinct (key, value) pair, value preserved).
 
     Output is padded to the same capacity; slot i holds the i-th distinct
     key (sorted ascending), so real records sit at the front.
+
+    A run is a stretch of equal (k1, k2, validity) — and value, for
+    distinct — found by neighbour compares. Each run gives one row: its
+    last for max (values ascend within the run) and sum, its first for
+    min and distinct. For sum that row carries the prefix sum of the
+    values; once the rows are packed to the front, a run's total is its
+    prefix sum less the one before it (the padding run, which adds
+    nothing, is last). No segment op, so no scatter over the batch.
     """
     if op not in REDUCE_OPS:
         raise ValueError(f"unknown reduce op: {op}")
-    n = batch.capacity
-    prev_k1 = jnp.concatenate([batch.k1[:1], batch.k1[:-1]])
-    prev_k2 = jnp.concatenate([batch.k2[:1], batch.k2[:-1]])
-    first = jnp.arange(n) == 0
-    boundary = first | (batch.k1 != prev_k1) | (batch.k2 != prev_k2)
+    k1, k2, value, valid = batch
+    same = (k1[1:] == k1[:-1]) & (k2[1:] == k2[:-1]) & (valid[1:] == valid[:-1])
     if op in _VALUE_KEYED_OPS:
-        prev_val = jnp.concatenate([batch.value[:1], batch.value[:-1]])
-        boundary = boundary | (batch.value != prev_val)
-    # Padding (SENTINEL,SENTINEL) forms at most one trailing segment.
-    seg = jnp.cumsum(boundary.astype(jnp.int32)) - 1
-
-    # seg is a cumsum — monotone — so every segment op below can promise
-    # sorted indices to XLA's scatter lowering.
-    masked_val = jnp.where(batch.valid, batch.value, 0)
+        same = same & (value[1:] == value[:-1])
+    edge = jnp.ones((1,), bool)
+    rep = jnp.concatenate([edge, ~same] if op in ("min", "distinct") else [~same, edge])
     if op == "sum":
-        totals = jax.ops.segment_sum(
-            masked_val, seg, num_segments=n, indices_are_sorted=True
-        )
-    elif op == "max":
-        big = jnp.where(batch.valid, batch.value, jnp.iinfo(jnp.int32).min)
-        totals = jax.ops.segment_max(
-            big, seg, num_segments=n, indices_are_sorted=True
-        )
-    elif op == "min":
-        small = jnp.where(batch.valid, batch.value, jnp.iinfo(jnp.int32).max)
-        totals = jax.ops.segment_min(
-            small, seg, num_segments=n, indices_are_sorted=True
-        )
-    else:  # distinct: every record in the segment shares one value
-        big = jnp.where(boundary, batch.value, jnp.iinfo(jnp.int32).min)
-        totals = jax.ops.segment_max(
-            big, seg, num_segments=n, indices_are_sorted=True
-        )
-
-    live = jax.ops.segment_sum(
-        batch.valid.astype(jnp.int32), seg, num_segments=n, indices_are_sorted=True
-    )
-    uk1 = jax.ops.segment_max(
-        jnp.where(boundary, batch.k1, 0), seg, num_segments=n, indices_are_sorted=True
-    )
-    uk2 = jax.ops.segment_max(
-        jnp.where(boundary, batch.k2, 0), seg, num_segments=n, indices_are_sorted=True
-    )
-
-    # Slot j is real iff j < number of segments containing >=1 valid record.
-    # Valid records sort before padding, so those segments are a prefix.
-    slot_valid = live > 0
-    sent = jnp.uint32(SENTINEL)
-    return KVBatch(
-        k1=jnp.where(slot_valid, uk1, sent),
-        k2=jnp.where(slot_valid, uk2, sent),
-        value=jnp.where(slot_valid, totals, 0),
-        valid=slot_valid,
-    )
+        value = jnp.cumsum(value)  # padding's values are 0
+    out = _compact_runs(rep, rep & valid, k1, k2, value)
+    if op == "sum":
+        before = jnp.concatenate([jnp.zeros((1,), jnp.int32), out.value[:-1]])
+        out = out._replace(value=jnp.where(out.valid, out.value - before, 0))
+    return out
 
 
 def count_unique(batch: KVBatch, op: str = "sum") -> KVBatch:
@@ -226,12 +219,12 @@ def count_unique(batch: KVBatch, op: str = "sum") -> KVBatch:
     combiner — every app's combine op is associative, so per-chunk partials
     merge exactly (word count: partial sums; inverted_index: distinct
     (term, doc) pairs)."""
-    return segment_reduce_sorted(sort_kv(batch, by_value=op in _VALUE_KEYED_OPS), op=op)
+    return segment_reduce_sorted(sort_valid_first(batch, op), op=op)
 
 
 def compaction_cap(u_cap: int, capacity: int) -> int:
-    """Token-slot budget for compact_front in the map paths — THE single
-    policy both the single-chip and mesh kernels use. Scales with BOTH the
+    """Record budget of combine_chunk's reduce — THE single policy the
+    single-chip and mesh map paths share. Scales with BOTH the
     distinct-key budget (2*u_cap) and a token-density floor (capacity/4 ≈
     1.5x typical English density), so tuning partial_capacity down for
     low-cardinality data cannot strangle the fast path into replaying
@@ -241,57 +234,42 @@ def compaction_cap(u_cap: int, capacity: int) -> int:
     return min(max(2 * u_cap, capacity // 4, 1024), capacity // 2 + 1)
 
 
-def compact_front(batch: KVBatch, cap: int) -> tuple[KVBatch, jnp.ndarray]:
-    """Scatter the valid records into the front of a cap-sized batch.
-
-    (packed KVBatch[cap], overflow_count). The device map step's sort
-    (count_unique) costs O(N log N) over EVERY byte position of a chunk,
-    but only ~N/6 positions hold tokens in real text — compacting first
-    makes the sort pay for tokens, not bytes. Records past cap are counted,
-    never dropped silently: the driver replays the chunk through a tier
-    whose cap is the exact worst case (ceil(N/2) one-char tokens), the same
-    contract as every other capacity fault.
-    """
-    n = batch.capacity
-    idx = jnp.cumsum(batch.valid.astype(jnp.int32)) - 1
-    total = idx[n - 1] + 1
-    ovf = jnp.maximum(total - cap, 0)
-    # Invalid records and overflow scatter into the dump slot at cap.
-    dest = jnp.where(batch.valid & (idx < cap), idx, cap)
-    sent = jnp.uint32(SENTINEL)
-
-    def place(x, fill):
-        buf = jnp.full((cap + 1,), fill, x.dtype)
-        return buf.at[dest].set(x, mode="drop")[:cap]
-
-    packed = KVBatch(
-        k1=place(batch.k1, sent),
-        k2=place(batch.k2, sent),
-        value=place(batch.value, jnp.int32(0)),
-        valid=jnp.arange(cap, dtype=jnp.int32) < jnp.minimum(total, cap),
-    )
-    return packed, ovf
-
-
-def slot_doc_ids(valid: jnp.ndarray, doc_table: jnp.ndarray, cap: int) -> jnp.ndarray:
-    """int32[cap]: the doc id of each slot compact_front(batch, cap) fills,
-    where ``valid`` is the batch's validity by byte position and
-    ``doc_table`` the chunk's (runtime/chunker.doc_table: first doc id,
-    then each document's start offset, NO_DOC-padded).
-
-    A token's doc id is the first doc id plus the number of starts at or
-    before it, minus one. Counted in slot space: a start at byte s is
-    passed by the slots at or past the number of tokens before s. Only
-    compares and sums over [DOC_SLOTS, positions], no gather: a chunk's
+def position_doc_ids(doc_table: jnp.ndarray, n: int) -> jnp.ndarray:
+    """int32[n]: the doc id of the token ending at each byte position of
+    a chunk, given its ``doc_table`` (runtime/chunker.doc_table: first doc
+    id, then each document's start offset, NO_DOC-padded): the first doc
+    id plus the number of starts at or before the position, minus one.
+    Only compares and sums over [DOC_SLOTS, n], no gather: a chunk's
     documents are consecutive files. An app that ignores doc ids leaves
     all of it dead, and XLA drops it."""
     starts = doc_table[1:]
-    pos = jnp.arange(valid.shape[0], dtype=jnp.int32)
-    before = jnp.sum((pos[None, :] < starts[:, None]) & valid[None, :],
-                     axis=1, dtype=jnp.int32)
-    slot = jnp.arange(cap, dtype=jnp.int32)
-    passed = jnp.sum(slot[None, :] >= before[:, None], axis=0, dtype=jnp.int32)
+    pos = jnp.arange(n, dtype=jnp.int32)
+    passed = jnp.sum(starts[:, None] <= pos[None, :], axis=0, dtype=jnp.int32)
     return doc_table[0] + passed - 1
+
+
+def combine_chunk(kv: KVBatch, doc_table: jnp.ndarray, device_map, op: str,
+                  u_cap: int) -> tuple[KVBatch, jnp.ndarray]:
+    """The map-side combine of one chunk's tokens, shared by the
+    single-chip step and the mesh shuffle: (update, overflow count).
+
+    ``kv`` holds a record at each token's end byte (ops/tokenize);
+    ``device_map(kv, doc_ids)`` is the app's per-record transform, given
+    each position's doc id. One sort over every position puts the valid
+    records, key-sorted, in front (sort_valid_first); the reduce then
+    runs over the first compaction_cap rows only. Records past that cap,
+    and distinct keys past ``u_cap``, are counted in the overflow, never
+    dropped silently: the caller clamps the update to empty and the driver
+    replays the chunk through a tier whose caps are the exact worst case.
+    The update is the first ``u_cap`` distinct keys, front-packed and
+    sorted (at most compaction_cap of them)."""
+    n = kv.capacity
+    cap = compaction_cap(u_cap, n)
+    kv = device_map(kv, position_doc_ids(doc_table, n))
+    records = jnp.sum(kv.valid.astype(jnp.int32))
+    partial = segment_reduce_sorted(sort_valid_first(kv, op).take_front(cap), op=op)
+    ovf = jnp.maximum(records - cap, 0) + jnp.sum(partial.valid[u_cap:].astype(jnp.int32))
+    return partial.take_front(u_cap), ovf
 
 
 def clamp_batch(batch: KVBatch, keep) -> KVBatch:

@@ -7,8 +7,9 @@ Instead of per-word string allocations and one awaited file write per pair
 (src/mr/worker.rs:131-136), the whole chunk is processed as one fixed-shape
 uint8 array:
 
-1. byte classes via 256-entry lookup tables (whitespace / word-char —
-   encoding the reference's ``[^\\w\\s]`` strip as data, not control flow);
+1. byte classes by compares on the byte value (whitespace / word-char,
+   ``byte_classes`` — the reference's ``[^\\w\\s]`` strip as arithmetic,
+   not control flow, and no per-byte table lookup);
 2. a *segmented* associative scan computes, per byte position, the
    polynomial hash pair of the current whitespace-delimited token with
    punctuation bytes contributing the identity transform (so "don't" hashes
@@ -32,7 +33,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from mapreduce_rust_tpu.core.hashing import (
     H1_INIT,
@@ -40,7 +40,6 @@ from mapreduce_rust_tpu.core.hashing import (
     H2_INIT,
     H2_MULT,
     SENTINEL,
-    byte_class_tables,
 )
 from mapreduce_rust_tpu.core.kv import KVBatch
 
@@ -69,11 +68,26 @@ def _scan_combine_len(x, y):
     return (*out, jnp.where(fy, ly, lx + ly))
 
 
+def byte_classes(c):
+    """(is_whitespace, is_word_char) of each byte value of the int32 array
+    ``c``, by compares alone: the rules core/hashing.byte_class_tables
+    tabulates for the host (tests/test_tokenize.py holds them equal). The
+    scan path here and the Pallas kernel (ops/tokenize_pallas.py) share
+    it; a 256-entry table lookup would be a gather over every byte."""
+    is_ws = (c == 32) | ((c >= 9) & (c <= 13))
+    lower = c | 32
+    is_wc = (
+        ((lower >= ord("a")) & (lower <= ord("z")))
+        | ((c >= ord("0")) & (c <= ord("9")))
+        | (c == ord("_"))
+        | (c >= 128)
+    )
+    return is_ws, is_wc
+
+
 def _tokenize(chunk: jnp.ndarray, last_is_boundary: bool, with_len: bool,
               use_pallas: bool = False):
-    ws_tab, wc_tab = byte_class_tables()
-    idx = chunk.astype(jnp.int32)
-    is_ws = jnp.take(jnp.asarray(ws_tab), idx).astype(bool)
+    is_ws, is_wc = byte_classes(chunk.astype(jnp.int32))
 
     if use_pallas and not with_len:
         # The fused Mosaic scan (ops/tokenize_pallas.py): one HBM pass in
@@ -87,7 +101,6 @@ def _tokenize(chunk: jnp.ndarray, last_is_boundary: bool, with_len: bool,
         h1, h2, cnts = (x[:n] for x in hash_scan_pallas(padded))
         tlen = None
     else:
-        is_wc = jnp.take(jnp.asarray(wc_tab), idx).astype(bool)
         one = jnp.uint32(1)
         zero = jnp.uint32(0)
         cplus1 = chunk.astype(jnp.uint32) + one

@@ -37,6 +37,7 @@ from mapreduce_rust_tpu.core.hashing import (
     H2_INIT,
     H2_MULT,
 )
+from mapreduce_rust_tpu.ops.tokenize import byte_classes
 
 # The kernel runs in int32 (TPU's native 32-bit lane); the uint32 hash
 # constants above 2^31 enter as their wrapped bit patterns — int32 mul/add
@@ -88,17 +89,7 @@ def _scan_inclusive(lanes, size: int):
 
 def _kernel(x_ref, h1_ref, h2_ref, cnt_ref, carry_ref):
     c = x_ref[:].astype(jnp.int32)  # (ROWS, LANE) byte values
-
-    # Byte classes, arithmetically (the 256-entry tables in
-    # core/hashing.byte_class_tables encode exactly these rules).
-    is_ws = (c == 32) | ((c >= 9) & (c <= 13))
-    lower = c | 32
-    is_wc = (
-        ((lower >= ord("a")) & (lower <= ord("z")) & (c < 128))
-        | ((c >= ord("0")) & (c <= ord("9")))
-        | (c == ord("_"))
-        | (c >= 128)
-    )
+    is_ws, is_wc = byte_classes(c)
 
     one = jnp.int32(1)
     zero = jnp.int32(0)

@@ -8,7 +8,7 @@ files with one awaited write + one println per pair
 array and the routing is one ``lax.all_to_all`` over the ICI mesh inside
 ``shard_map``:
 
-    per chip:  tokenize → app.device_map → count_unique (map-side combiner)
+    per chip:  tokenize → app.device_map → combine_chunk (map-side combiner)
                → bucket_scatter into D buckets (bucket = k1 % D)
     all chips: all_to_all — bucket d of every chip lands on chip d
     per chip:  count_unique over the received records → this chip's
@@ -46,11 +46,9 @@ from mapreduce_rust_tpu.apps.base import App
 from mapreduce_rust_tpu.core.kv import KVBatch
 from mapreduce_rust_tpu.ops.groupby import (
     clamp_batch,
-    compact_front,
-    compaction_cap,
+    combine_chunk,
     count_unique,
     merge_batches,
-    slot_doc_ids,
 )
 from mapreduce_rust_tpu.ops.partition import bucket_scatter
 from mapreduce_rust_tpu.ops.tokenize import tokenize_and_hash
@@ -110,12 +108,12 @@ def _chip_shuffle_tail(kv: KVBatch, doc_table, app: App, u_cap: int,
                        bucket_cap: int, d: int, replicate_flags: bool):
     """THE shuffle body, shared by every map_shuffle variant (chunk-input,
     kv-input, flag-replicating): device_map (each record stamped with its
-    doc id from the chip's doc table, ops/groupby.slot_doc_ids) → combine
-    → bucket scatter → all_to_all → combine, with the clamp-on-overflow
-    contract: if ANY chip overflowed, every chip's local result clamps to
-    empty (the psum makes them agree) and the driver replays through a
-    wider tier — which is what lets merges dispatch before any flag
-    reaches the host.
+    doc id from the chip's doc table) and combine, as the single-chip step
+    does them (ops/groupby.combine_chunk) → bucket scatter → all_to_all →
+    combine, with the clamp-on-overflow contract: if ANY chip overflowed,
+    every chip's local result clamps to empty (the psum makes them agree)
+    and the driver replays through a wider tier — which is what lets
+    merges dispatch before any flag reaches the host.
 
     Returns (local KVBatch, p_flag, b_flag): per-chip raw counters, or the
     psum-reduced (replicated) totals when replicate_flags — the form a
@@ -127,15 +125,7 @@ def _chip_shuffle_tail(kv: KVBatch, doc_table, app: App, u_cap: int,
     # (runtime/trace.py) — the ICI-vs-compute attribution VERDICT r5 asks
     # for, readable straight off the xprof timeline.
     with jax.named_scope("shuffle.map_combine"):
-        # Compact before sorting — count_unique pays for tokens, not byte
-        # positions; ops/groupby.compaction_cap is the shared sizing policy.
-        cap_c = compaction_cap(u_cap, kv.capacity)
-        doc_ids = slot_doc_ids(kv.valid, doc_table, cap_c)
-        kv, c_ovf = compact_front(kv, cap_c)
-        mine = app.device_map(kv, doc_ids)
-        partial = count_unique(mine, op=op)
-        update = partial.take_front(u_cap)
-        p_ovf = jnp.sum(partial.valid[u_cap:].astype(jnp.int32)) + c_ovf
+        update, p_ovf = combine_chunk(kv, doc_table, app.device_map, op, u_cap)
         # Shared partition seam (ops/partition.py): the ICI shuffle always
         # routes state ownership by hash — chip d owns hash class k1 % d.
         # Range apps (sort) still shuffle by hash here; their RANGE order

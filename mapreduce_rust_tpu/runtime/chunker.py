@@ -17,7 +17,7 @@ feeds device chunks relies on:
   id, then the byte offset where each of its documents starts (the first
   at 0), padded with ``NO_DOC``. A token's doc id is the first doc id plus
   the number of starts at or before the token, minus one
-  (ops/groupby.slot_doc_ids). The table holds ``DOC_SLOTS`` starts; a
+  (ops/groupby.position_doc_ids). The table holds ``DOC_SLOTS`` starts; a
   chunk closes when it is full, or when the next doc id is not the next
   file (a chunk's documents are consecutive files);
 - chunks are cut at whitespace boundaries, so no token ever straddles a
@@ -47,7 +47,7 @@ from mapreduce_rust_tpu.core.normalize import normalize_unicode
 _ASCII_WS = frozenset(WHITESPACE_BYTES)
 
 #: Document starts one chunk's table holds. The device map pays
-#: O(tokens x DOC_SLOTS) compares to stamp doc ids, and documents smaller
+#: O(chunk bytes x DOC_SLOTS) compares to stamp doc ids, and documents smaller
 #: than chunk_bytes / DOC_SLOTS leave part of a chunk empty.
 DOC_SLOTS = 64
 #: Unused table slots: past every byte of any chunk.

@@ -7,7 +7,7 @@ per-record writes, a single host loop streams whitespace-aligned chunks
 distinct-key state on device:
 
     chunk bytes ──device_put──▶ tokenize_and_hash ─▶ app.device_map
-        ─▶ count_unique (map-side combiner)  ─▶ merge into state
+        ─▶ combine_chunk (map-side combiner) ─▶ merge into state
                                                    │
          evicted tail (rare) ◀─────────────────────┘
               └─▶ host spill accumulator (exact, nothing dropped)
@@ -66,11 +66,8 @@ from mapreduce_rust_tpu.config import (
 from mapreduce_rust_tpu.core.kv import KVBatch
 from mapreduce_rust_tpu.ops.groupby import (
     clamp_batch,
-    compact_front,
-    compaction_cap,
-    count_unique,
+    combine_chunk,
     merge_batches,
-    slot_doc_ids,
 )
 from mapreduce_rust_tpu.ops.tokenize import tokenize_and_hash
 from mapreduce_rust_tpu.runtime.chunker import (
@@ -291,9 +288,10 @@ def make_step_fns(app: App, u_cap: int, use_pallas: bool = False):
     """(map_combine, merge) jitted for one app + update capacity.
 
     map_combine: (chunk bytes, its doc table — runtime/chunker.doc_table)
-    → compacted per-chunk partial + overflow count. Each token's doc id
-    (ops/groupby.slot_doc_ids) reaches only app.device_map: an app that
-    ignores it (word count) compiles to the same program as without it.
+    → per-chunk partial + overflow count (ops/groupby.combine_chunk).
+    Each token's doc id (ops/groupby.position_doc_ids) reaches only
+    app.device_map: an app that ignores it (word count) compiles to the
+    same program as without it.
     merge: fold the partial into the running state, returning the evicted
     tail and its record count (donates the old state's buffers).
     use_pallas: target is a TPU — tokenize with the fused Mosaic kernel.
@@ -317,18 +315,11 @@ def _build_step_fns(app: App, u_cap: int, use_pallas: bool = False):
     @jax.jit
     def map_combine(chunk: jnp.ndarray, doc_table: jnp.ndarray):
         kv = tokenize_and_hash(chunk, use_pallas=use_pallas)
-        # Compact before sorting: count_unique pays for tokens, not bytes
-        # (~6x fewer sort slots on text); ops/groupby.compaction_cap is the
-        # shared sizing policy. NOTE: the overflow flag below therefore
-        # covers BOTH distinct keys > u_cap AND raw tokens > cap_c — either
-        # replays the chunk through the full-width tier.
-        cap_c = compaction_cap(u_cap, chunk.shape[0])
-        doc_ids = slot_doc_ids(kv.valid, doc_table, cap_c)
-        kv, c_ovf = compact_front(kv, cap_c)
-        kv = app.device_map(kv, doc_ids)
-        partial = count_unique(kv, op=op)
-        update = partial.take_front(u_cap)
-        ovf = jnp.sum(partial.valid[u_cap:].astype(jnp.int32)) + c_ovf
+        # One sort over every byte position, then the reduce over the
+        # compaction cap's rows. The overflow covers BOTH distinct keys >
+        # u_cap AND records > ops/groupby.compaction_cap — either replays
+        # the chunk through the full-width tier.
+        update, ovf = combine_chunk(kv, doc_table, app.device_map, op, u_cap)
         # An overflowing chunk contributes NOTHING (update clamps to empty,
         # keys included — ops/groupby.clamp_batch keeps the merged state
         # sorted): the driver replays it full-width later. This makes the
@@ -340,7 +331,7 @@ def _build_step_fns(app: App, u_cap: int, use_pallas: bool = False):
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def merge(state: KVBatch, update: KVBatch):
-        # update is a count_unique output — already key-distinct, so it
+        # update is a combine_chunk output — already key-distinct, so it
         # joins the state's sort as it is.
         new_state, evicted = merge_batches(state, update, op=op, update_sorted=True)
         ev_count = jnp.sum(evicted.valid.astype(jnp.int32))

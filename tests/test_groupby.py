@@ -9,10 +9,12 @@ import pytest
 from mapreduce_rust_tpu.core.hashing import SENTINEL
 from mapreduce_rust_tpu.core.kv import KVBatch
 from mapreduce_rust_tpu.ops.groupby import (
+    combine_chunk,
+    compaction_cap,
     count_unique,
     merge_batches,
     segment_reduce_sorted,
-    sort_kv,
+    sort_valid_first,
 )
 from mapreduce_rust_tpu.ops.partition import bucket_scatter
 
@@ -70,7 +72,7 @@ def test_segment_reduce_max_min_vs_oracle():
         for (a, b), v in zip(keys.tolist(), vals.tolist()):
             k = (a, b)
             oracle[k] = fold(oracle[k], v) if k in oracle else v
-        out = segment_reduce_sorted(sort_kv(batch), op=op)
+        out = segment_reduce_sorted(sort_valid_first(batch, op), op=op)
         keys_out, vals_out = out.to_host()
         got = {tuple(k): v for k, v in zip(keys_out.tolist(), vals_out.tolist())}
         assert got == oracle, op
@@ -428,9 +430,14 @@ def test_bucket_scatter_preserves_totals_random():
     assert got == oracle
 
 
-def test_compact_front_exact_and_overflow():
-    from mapreduce_rust_tpu.ops.groupby import compact_front
+def _keep_all(kv, doc_ids):
+    return kv
 
+
+def test_compact_front_exact_and_overflow():
+    # combine_chunk's one sort packs the valid records in front; the reduce
+    # reads the first compaction_cap of them. Invalid records carry real
+    # keys and values here: none may leak into the update.
     rng = np.random.default_rng(5)
     n = 4096
     valid = rng.random(n) < 0.2
@@ -438,15 +445,190 @@ def test_compact_front_exact_and_overflow():
     k2 = rng.integers(0, 2**32, n, dtype=np.uint32)
     val = rng.integers(0, 100, n, dtype=np.int32)
     batch = KVBatch(jnp.asarray(k1), jnp.asarray(k2), jnp.asarray(val), jnp.asarray(valid))
+    doc_table = jnp.zeros((65,), jnp.int32)
     total = int(valid.sum())
-    # Roomy cap: everything packed, order preserved, nothing lost.
-    packed, ovf = compact_front(batch, cap=total + 7)
+    order = np.lexsort((k2[valid], k1[valid]))
+    # Roomy caps: every record kept, keys ascending, nothing lost.
+    update, ovf = combine_chunk(batch, doc_table, _keep_all, "sum", u_cap=1024)
     assert int(ovf) == 0
-    assert int(np.asarray(packed.valid).sum()) == total
-    assert np.array_equal(np.asarray(packed.k1)[:total], k1[valid])
-    assert np.array_equal(np.asarray(packed.value)[:total], val[valid])
-    # Tight cap: overflow counted, the first cap records kept in order.
-    cap = total // 2
-    packed2, ovf2 = compact_front(batch, cap=cap)
-    assert int(ovf2) == total - cap
-    assert np.array_equal(np.asarray(packed2.k1)[:cap], k1[valid][:cap])
+    assert int(np.asarray(update.valid).sum()) == total
+    assert np.array_equal(np.asarray(update.k1)[:total], k1[valid][order])
+    assert np.array_equal(np.asarray(update.value)[:total], val[valid][order])
+    # Tight caps: 1,024 records reach the reduce (compaction_cap's floor)
+    # and 256 distinct keys the update; the rest are counted, and the
+    # update holds the smallest keys.
+    u_cap = 256
+    valid2 = rng.random(n) < 0.4
+    batch2 = batch._replace(valid=jnp.asarray(valid2))
+    update2, ovf2 = combine_chunk(batch2, doc_table, _keep_all, "sum", u_cap=u_cap)
+    total2 = int(valid2.sum())
+    cap = compaction_cap(u_cap, n)
+    assert total2 > cap == 1024
+    assert int(ovf2) == (total2 - cap) + (cap - u_cap)
+    order2 = np.lexsort((k2[valid2], k1[valid2]))
+    assert np.array_equal(np.asarray(update2.k1), k1[valid2][order2][:u_cap])
+
+
+def _reference_combine(batch: KVBatch, op: str, u_cap: int) -> tuple[list, int]:
+    """combine_chunk's contract in plain Python, with a device_map that
+    keeps every record: the valid records in key order, the first
+    compaction_cap of them folded per key — (k1, k2, value) for
+    ``distinct`` — into ascending rows; then SENTINEL padding. The
+    overflow counts the records past the cap and the keys past u_cap."""
+    S = int(SENTINEL)
+    recs = sorted((a, b, v) for a, b, v, ok in _rows(batch) if ok)
+    cap = compaction_cap(u_cap, batch.capacity)
+    runs: dict = {}
+    for a, b, v in recs[:cap]:
+        runs.setdefault((a, b, v) if op == "distinct" else (a, b), []).append(v)
+    out = []
+    for key in sorted(runs):
+        if op == "distinct":
+            out.append((*key, True))
+            continue
+        acc = runs[key][0]
+        for v in runs[key][1:]:
+            acc = _FOLDS[op](acc, v)
+        out.append((*key, int(np.int32(np.int64(acc) & 0xFFFFFFFF)), True))
+    ovf = max(len(recs) - cap, 0) + max(len(out) - u_cap, 0)
+    width = min(u_cap, cap)
+    out = out[:width]
+    return out + [(S, S, 0, False)] * (width - len(out)), ovf
+
+
+_COMBINE_CASES = {
+    # case: (valid share, distinct keys, SENTINEL-pair records)
+    "long_runs": (0.2, 8, 0),         # ~100 copies of each key
+    "sentinel_word": (0.2, 8, 37),    # a word hashing to the padding key
+    "all_padding": (0.0, 8, 0),
+    "records_past_cap": (0.4, 8, 0),  # ~1,640 records, cap 1,024
+    "keys_past_u_cap": (0.2, 400, 0),  # ~350 distinct keys, u_cap 64
+}
+
+
+@pytest.mark.parametrize("case", sorted(_COMBINE_CASES))
+@pytest.mark.parametrize("op", ["sum", "max", "min", "distinct"])
+def test_combine_chunk_matches_reference(op, case):
+    # Exact update rows and overflow count against the plain reference;
+    # invalid rows carry real keys and values, which must not leak.
+    share, n_keys, n_sent = _COMBINE_CASES[case]
+    rng = np.random.default_rng(26)
+    S = int(SENTINEL)
+    n, u_cap = 4096, 64
+    ids = rng.integers(0, n_keys, size=n)
+    k1 = (ids % 3).astype(np.uint32)
+    k2 = (ids * 7919).astype(np.uint32)
+    sent = rng.choice(n, size=n_sent, replace=False)
+    k1[sent] = k2[sent] = S
+    lo, hi = (0, 4) if op == "distinct" else (-2**31, 2**31)
+    vals = rng.integers(lo, hi, size=n, dtype=np.int64).astype(np.int32)
+    valid = rng.random(n) < share
+    valid[sent] = True
+    batch = KVBatch(*(jnp.asarray(x) for x in (k1, k2, vals, valid)))
+    update, ovf = combine_chunk(batch, jnp.zeros((65,), jnp.int32), _keep_all, op, u_cap)
+    want, want_ovf = _reference_combine(batch, op, u_cap)
+    assert int(ovf) == want_ovf
+    if case.endswith("past_cap") or case.endswith("u_cap"):
+        assert want_ovf > 0
+    if want_ovf == 0:
+        assert _rows(update) == want
+    # Runs much longer than two, as a chunk of a few hundred words gives.
+    if case == "long_runs":
+        assert max(collections.Counter(ids[valid].tolist()).values()) > 50
+
+
+def test_map_combine_doc_ids_across_packed_documents():
+    # Several documents share one chunk: every (term, doc) pair of the
+    # chunk, and no other, comes out of map_combine; word count of the
+    # same chunk ignores the documents.
+    import io
+
+    from mapreduce_rust_tpu.apps import get_app
+    from mapreduce_rust_tpu.core.hashing import hash_word
+    from mapreduce_rust_tpu.runtime.chunker import pack_documents
+    from mapreduce_rust_tpu.runtime.driver import make_step_fns
+
+    rng = np.random.default_rng(3)
+    vocab = [b"w%d" % i for i in range(40)]
+    docs = [b" ".join(vocab[j] for j in rng.integers(0, 40, size=int(rng.integers(5, 60))))
+            + b" " for _ in range(9)]
+    (chunk,) = pack_documents(((i, io.BytesIO(d)) for i, d in enumerate(docs)),
+                              4096, normalize=False)
+    assert chunk.n_docs == len(docs)
+    counts = collections.Counter(hash_word(w) for d in docs for w in d.split())
+    for app, want in (
+        ("inverted_index", {(*hash_word(w), i) for i, d in enumerate(docs) for w in d.split()}),
+        ("word_count", {(*k, c) for k, c in counts.items()}),
+    ):
+        map_combine, _ = make_step_fns(get_app(app), 512)
+        update, ovf = map_combine(jnp.asarray(chunk.data), jnp.asarray(chunk.doc_table))
+        assert int(ovf) == 0
+        keys, vals = update.to_host()
+        got = {(int(a), int(b), int(v)) for (a, b), v in zip(keys.tolist(), vals.tolist())}
+        assert got == want, app
+
+
+def test_map_combine_clamps_an_overflowing_chunk():
+    # More distinct words than u_cap: the overflow is counted and the
+    # update clamps to empty padding, keys included, for the replay.
+    from mapreduce_rust_tpu.apps import get_app
+    from mapreduce_rust_tpu.runtime.chunker import doc_table
+    from mapreduce_rust_tpu.runtime.driver import make_step_fns
+
+    text = b" ".join(b"t%d" % i for i in range(300)).ljust(4096)
+    map_combine, _ = make_step_fns(get_app("word_count"), 128)
+    update, ovf = map_combine(jnp.asarray(np.frombuffer(text, np.uint8)),
+                              jnp.asarray(doc_table(0)))
+    assert int(ovf) == 300 - 128
+    assert not np.asarray(update.valid).any()
+    assert (np.asarray(update.k1) == SENTINEL).all() and (np.asarray(update.value) == 0).all()
+
+
+def _big_gathers_and_scatters(stablehlo: str, limit: int = 1024) -> list:
+    """The gather and scatter ops of a StableHLO module whose index
+    operand (their second) holds ``limit`` or more elements."""
+    import re
+
+    found = []
+    for line in stablehlo.splitlines():
+        if "stablehlo.gather" not in line and "stablehlo.scatter" not in line:
+            continue
+        types = re.findall(r"tensor<([0-9x]*)x?[a-z]+[0-9]*>", line.split(" : ")[-1])
+        dims = [int(d) for d in types[1].split("x") if d] if len(types) > 1 else []
+        if int(np.prod(dims)) >= limit:
+            found.append(line.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("app", ["word_count", "inverted_index"])
+def test_map_combine_makes_no_chunk_sized_gather_or_scatter(app):
+    # map_combine at the device cells' shapes (4 MiB chunk, u_cap 2**19),
+    # lowered only: byte classes, the combine and doc ids are arithmetic,
+    # sorts and neighbour compares. Word count's program has no doc-id
+    # work at all: its doc table parameter is dropped.
+    import jax
+
+    from mapreduce_rust_tpu.apps import get_app
+    from mapreduce_rust_tpu.runtime.chunker import DOC_SLOTS
+    from mapreduce_rust_tpu.runtime.driver import make_step_fns
+
+    map_combine, _ = make_step_fns(get_app(app), 1 << 19)
+    text = map_combine.lower(
+        jax.ShapeDtypeStruct((1 << 22,), jnp.uint8),
+        jax.ShapeDtypeStruct((1 + DOC_SLOTS,), jnp.int32),
+    ).as_text()
+    assert _big_gathers_and_scatters(text) == []
+    assert "stablehlo.sort" in text
+    main = next(line for line in text.splitlines() if "func.func public @main" in line)
+    assert ("tensor<65xi32>" in main) == (app == "inverted_index")
+    assert (f"tensor<{DOC_SLOTS}x4194304x" in text) == (app == "inverted_index")
+
+
+def test_big_gather_finder_sees_a_table_lookup():
+    # The guard above would catch the byte-class table lookup it replaced.
+    import jax
+
+    table = jnp.arange(256, dtype=jnp.int32)
+    text = jax.jit(lambda c: jnp.take(table, c.astype(jnp.int32))).lower(
+        jax.ShapeDtypeStruct((4096,), jnp.uint8)).as_text()
+    assert len(_big_gathers_and_scatters(text)) == 1

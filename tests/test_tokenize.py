@@ -194,3 +194,17 @@ def test_pallas_path_pads_ragged_chunk(monkeypatch):
     assert shapes == [tokenize_pallas.BLOCK]
     for a, b in zip(padded, scan):
         assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_byte_classes_equal_the_host_tables():
+    """The compare rules the device paths share (ops/tokenize.byte_classes)
+    are the host's 256-entry tables, byte for byte."""
+    import jax.numpy as jnp
+
+    from mapreduce_rust_tpu.core.hashing import byte_class_tables
+    from mapreduce_rust_tpu.ops.tokenize import byte_classes
+
+    is_ws, is_wc = byte_classes(jnp.arange(256, dtype=jnp.int32))
+    ws_tab, wc_tab = byte_class_tables()
+    assert np.array_equal(np.asarray(is_ws), ws_tab.astype(bool))
+    assert np.array_equal(np.asarray(is_wc), wc_tab.astype(bool))
