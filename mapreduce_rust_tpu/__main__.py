@@ -163,8 +163,7 @@ def _cfg(args, map_n: int = 1, worker_n: int = 1) -> Config:
     if getattr(args, "sanitize", False):
         # Export the env form too: the env-only checkpoints (native arena
         # ownership in native/host, trace validation in Tracer.write) and
-        # any child process must see the same enablement as Config.sanitize
-        # — bench.py does the same for its legs.
+        # any child process must see the same enablement as Config.sanitize.
         os.environ["MR_SANITIZE"] = "1"
     chaos = getattr(args, "chaos", None)
     if chaos:
@@ -1015,7 +1014,7 @@ def main(argv: list[str] | None = None) -> int:
     )
     p.add_argument("paths", nargs="*",
                    help="files/dirs to lint (default: the installed package, "
-                   "tests/, bench.py and __graft_entry__.py)")
+                   "tests/ and __graft_entry__.py)")
     p.add_argument("--format", choices=["text", "json"], default="text",
                    help="json: one machine-readable document (findings + "
                    "suppression accounting) for CI diffs")

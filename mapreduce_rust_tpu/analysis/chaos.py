@@ -41,9 +41,7 @@ an int or ``*``; SECONDS a float)::
                                  ``p=`` samples runs by seeded hash of the
                                  run index). The slow-disk straggler the
                                  ASYNC spill writer hides behind compute
-                                 while the sync plane stalls per run —
-                                 bench.py --chaos measures exactly that
-                                 pair (ISSUE 11)
+                                 while the sync plane stalls per run
     slow_dispatch:SECONDS        every device-merge dispatch sleeps
                                  SECONDS first (runtime/driver.py — ONE
                                  checkpoint in the dispatch plane, firing
@@ -52,8 +50,7 @@ an int or ``*``; SECONDS a float)::
                                  slow-device-hop straggler the ASYNC
                                  dispatch plane hides on its own thread
                                  while --sync-dispatch eats every delay
-                                 on the router's wall — bench.py --chaos
-                                 measures exactly that pair (ISSUE 13)
+                                 on the router's wall
 
 Trailing ``KEY=VAL`` args refine any fault: ``attempt=N`` (default 1 —
 a fault that re-fired on the recovery attempt would loop forever; ``*``
@@ -77,9 +74,8 @@ SITES = (
 _NEEDS_SECONDS = ("pause", "delay_finish", "slow_scan", "slow_disk",
                   "slow_dispatch")
 
-#: Canonical scenario specs shared by ``bench.py --chaos`` and the chaos
-#: test suite — one copy, so the benched and the tested faults are the
-#: same faults. Keyed by scenario name; every spec is seeded. The
+#: Canonical scenario specs of the chaos test suite's full matrix.
+#: Keyed by scenario name; every spec is seeded. The
 #: wedge_renewal scenario pairs the wedge with a pause: a task that
 #: finishes inside its lease would make the dead heartbeat unobservable —
 #: the pause keeps the task alive past expiry, so the recovery under test
@@ -91,14 +87,10 @@ SCENARIOS: dict[str, str] = {
     "wedge_renewal": "seed=4;wedge_renewal:map:0;pause:map:0:3.0",
     "slow_scan": "seed=5;slow_scan:w0:2.5",
     # Fires only where a spill tier engages (the cluster legs run
-    # unbudgeted, so there it is a fault-free control); the bench's
-    # dedicated --chaos slow-disk pair runs it against a BUDGETED job,
-    # async vs sync, to measure what the background writer hides.
+    # unbudgeted, so there it is a fault-free control).
     "slow_disk": "seed=6;slow_disk:0.05",
     # Fires on every packed device merge the host engine dispatches
-    # (cluster workers run map_engine='host'); the bench's dedicated
-    # --chaos slow-dispatch pair runs it async-vs-sync against a real
-    # window stream to measure what the dispatch thread hides (ISSUE 13).
+    # (cluster workers run map_engine='host').
     "slow_dispatch": "seed=7;slow_dispatch:0.02",
 }
 

@@ -975,32 +975,31 @@ def run_live_cli(args) -> int:
 TREND_SERIES: dict[str, str] = {
     "value": "down",
     "zipf_gbs": "down",
-    # Live-metrics sampler tax (ISSUE 8): bench measures a metrics-on vs
-    # metrics-off pair each run; a creeping overhead fraction is exactly
-    # the slow-boil regression class trend exists for.
+    # Live-metrics sampler tax: a metrics-on vs metrics-off pair's
+    # overhead; a creeping overhead fraction is exactly the slow-boil
+    # regression class trend exists for.
     "metrics_overhead_frac": "up",
     # Dispatch-plane coalescing effectiveness (ISSUE 13): mean update
     # fill drifting DOWN means dispatches go out emptier round over round
     # — the coalesce factor eroding (a vocabulary shift, a threshold
     # regression) long before the wall number moves.
     "merge_fill_frac": "down",
-    # Job-service throughput (ISSUE 14): the bench service leg's
-    # jobs-per-minute over a fixed mixed-submission stream. Drifting DOWN
-    # means the control plane itself (admission, dispatch, per-job
-    # bookkeeping) got slower — the regression class a single-job wall
-    # number can never see.
+    # Job-service throughput: jobs-per-minute over a fixed
+    # mixed-submission stream. Drifting DOWN means the control plane
+    # itself (admission, dispatch, per-job bookkeeping) got slower — the
+    # regression class a single-job wall number can never see.
     "service_jobs_per_min": "down",
-    # Workload plane (ISSUE 15): the bench sort leg's wall and its
-    # realized partition-bytes skew ratio. Wall drifting UP is the
-    # range-partitioned path slowing; skew drifting UP is the sampled
-    # splitters degrading (sampler regression, corpus-generator drift) —
-    # each invisible to the hash legs.
+    # Workload plane: a sort job's wall and its realized partition-bytes
+    # skew ratio. Wall drifting UP is the range-partitioned path slowing;
+    # skew drifting UP is the sampled splitters degrading (sampler
+    # regression, corpus-generator drift) — each invisible to the hash
+    # legs.
     "sort_wall_s": "up",
     "sort_skew": "up",
-    # Fleet profiler (ISSUE 16): the bench service leg's cross-job
-    # accounting. Bubble fraction drifting UP means more fleet
-    # worker-seconds lost to the map barrier / admission queue; util
-    # drifting DOWN is the same loss seen from the other side; the
+    # Fleet profiler: the job service's cross-job accounting. Bubble
+    # fraction drifting UP means more fleet worker-seconds lost to the
+    # map barrier / admission queue; util drifting DOWN is the same loss
+    # seen from the other side; the
     # pipelining opportunity drifting UP means the barrier is leaving
     # ever more reclaimable headroom on the table (ROADMAP item 1's
     # before/after number).
@@ -1008,27 +1007,26 @@ TREND_SERIES: dict[str, str] = {
     "fleet_util_frac": "down",
     "pipelining_opportunity_s": "up",
     # Model checker (ISSUE 18): mrmodel exploration throughput over the
-    # fixed bench budget. Drifting DOWN means the real control plane (or
+    # fixed time budget. Drifting DOWN means the real control plane (or
     # the invariant replay it runs per schedule) got slower — and since
     # CI explores under a fixed time box, a slower loop silently shrinks
     # the schedule space actually covered.
     "model_schedules_per_s": "down",
-    # Roofline attribution (ISSUE 19): the zipf leg's host-map scan
-    # achieved GB/s and its fraction of the calibrated memcpy roof.
-    # Either drifting DOWN means the scan is moving AWAY from the
-    # hardware — a native-scan regression or a machine/calibration shift
-    # — exactly the efficiency erosion a wall-seconds series hides when
-    # corpus size drifts with it.
+    # Roofline attribution: the host-map scan's achieved GB/s and its
+    # fraction of the calibrated memcpy roof. Either drifting DOWN means
+    # the scan is moving AWAY from the hardware — a native-scan regression
+    # or a machine/calibration shift — exactly the efficiency erosion a
+    # wall-seconds series hides when corpus size drifts with it.
     "scan_achieved_gbs": "down",
     "roofline_frac": "down",
-    # Sampler tax (ISSUE 19): the --profile-overhead interleaved pair's
-    # min-of-N estimate; creeping UP is the profiler outgrowing its ≤2%
-    # budget (the metrics_overhead_frac twin).
+    # Sampler tax: a profile-on vs profile-off pair's min-of-N estimate;
+    # creeping UP is the profiler outgrowing its ≤2% budget (the
+    # metrics_overhead_frac twin).
     "profile_overhead_frac": "up",
-    # Provenance plane (ISSUE 20): the --lineage-overhead pair's ledger
-    # tax creeping UP is the digest/ledger path outgrowing its ≤2%
-    # budget; the blast-radius leg's memo_hit_frac drifting DOWN on the
-    # fixed +1% grown corpus means chunking stability eroded — a window
+    # Provenance plane: a lineage-on vs lineage-off pair's ledger tax
+    # creeping UP is the digest/ledger path outgrowing its ≤2% budget;
+    # memo_hit_frac drifting DOWN on a fixed +1% grown corpus means
+    # chunking stability eroded — a window
     # boundary shift silently shrinking what incremental re-execution
     # (ROADMAP item 4) could ever skip.
     "lineage_overhead_frac": "up",

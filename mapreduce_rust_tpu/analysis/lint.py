@@ -236,13 +236,13 @@ def discover_files(paths: Sequence[str]) -> list[str]:
 
 def default_roots() -> list[str]:
     """What ``lint`` checks with no path arguments: the package itself plus
-    the repo-root siblings that ship with it (tests, bench, graft entry) —
+    the repo-root siblings that ship with it (tests, graft entry) —
     derived from the package location, not the CWD, so the gate test checks
     the same tree no matter where pytest runs."""
     pkg = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     repo = os.path.dirname(pkg)
     roots = [pkg]
-    for sib in ("tests", "bench.py", "__graft_entry__.py"):
+    for sib in ("tests", "__graft_entry__.py"):
         p = os.path.join(repo, sib)
         if os.path.exists(p):
             roots.append(p)

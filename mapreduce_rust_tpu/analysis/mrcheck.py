@@ -72,7 +72,7 @@ artifacts — double-win, report-after-revoke, grant-over-live-lease,
 dropped flow terminator, write race — so every invariant has a known-bad
 fixture proving it fires (tests/test_mrcheck.py), while the fault-free
 run and the full chaos matrix prove zero false positives
-(tests/test_check_clean.py, bench.py --chaos).
+(tests/test_check_clean.py, tests/test_chaos.py).
 
 Pure stdlib, no jax — importable from any control-plane process
 (package rule).

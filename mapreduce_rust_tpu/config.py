@@ -150,7 +150,7 @@ class Config:
                                     # → <repo>/.jax_cache; None/"" disables).
                                     # XLA compiles of the step fns are tens
                                     # of seconds; without this every process
-                                    # (bench, each worker, the dryrun) pays
+                                    # (each worker, the dryrun) pays
                                     # them again.
 
     # ---- Bounded-memory egress tiers (VERDICT r4 missing 3) ----
@@ -175,8 +175,7 @@ class Config:
                                     # MR_DISPATCH_SYNC=1 for a whole
                                     # process tree) runs the dispatch
                                     # inline on the router thread: the
-                                    # measurement/debug plane the bench's
-                                    # A/B pair runs. Outputs are
+                                    # measurement/debug plane. Outputs are
                                     # bit-identical either way at a fixed
                                     # coalesce setting.
     dispatch_coalesce: bool = True  # cross-window coalescing: successive
@@ -232,9 +231,8 @@ class Config:
                                     # O(2 x budget) per tier. False (or
                                     # MR_SPILL_SYNC=1 for a whole process
                                     # tree) restores the inline write: the
-                                    # debugging/measurement plane the
-                                    # bench's slow-disk chaos pair runs to
-                                    # show what the overlap hides. Outputs
+                                    # debugging/measurement plane that
+                                    # shows what the overlap hides. Outputs
                                     # are bit-identical either way.
 
     # ---- Data-plane checkpointing (single-process mesh driver) ----
@@ -301,8 +299,8 @@ class Config:
                                     # into manifests as stats.timeseries,
                                     # shipped coordinator-ward in the
                                     # renewal envelope. Cheap enough to
-                                    # default on; --no-metrics (bench's
-                                    # overhead pair) turns it off.
+                                    # default on; --no-metrics turns it
+                                    # off.
     metrics_sample_period_s: float = 1.0  # wall-clock bucket width of the
                                     # ring's points: one point per bucket
                                     # however many loops tick the sampler
@@ -324,9 +322,7 @@ class Config:
                                     # plane-thread names, embedded in the
                                     # manifest as stats.profile and in
                                     # flight-recorder partials. Off by
-                                    # default (--profile / MR_PROFILE=1);
-                                    # tax gated ≤2% by bench's
-                                    # --profile-overhead pair.
+                                    # default (--profile / MR_PROFILE=1).
     profile_hz: float = 97.0        # sampler rate; prime, so it never
                                     # phase-locks with 1/10/100 Hz work
 
@@ -341,9 +337,7 @@ class Config:
                                     # backward/blast-radius queries.
                                     # Observational only — outputs stay
                                     # bit-identical ON vs OFF. Off by
-                                    # default (--lineage / MR_LINEAGE=1);
-                                    # tax gated ≤2% by bench's
-                                    # --lineage-overhead pair.
+                                    # default (--lineage / MR_LINEAGE=1).
 
     # ---- Fleet scheduler (ISSUE 17) ----
     sched: str = "fifo"             # task-grant scheduling mode. "fifo"

@@ -1130,8 +1130,7 @@ def _chaos_slow_dispatch(dispatch_index: int) -> None:
     """The ``slow_dispatch`` injection checkpoint: ONE site in the dispatch
     plane (fires per merge dispatch, so ``p=`` samples by dispatch index
     and reruns delay the same dispatches). The async plane hides the delay
-    on the dispatch thread; the sync plane eats it on the router's wall —
-    the pair bench.py measures."""
+    on the dispatch thread; the sync plane eats it on the router's wall."""
     spec = os.environ.get("MR_CHAOS")
     if not spec:
         return
@@ -2921,7 +2920,7 @@ def run_job(
         # sampler-derived splitters onto the app BEFORE anything streams. The
         # pre-pass is seeded and pure in (inputs, config), so every engine and
         # every re-execution derives identical routing; its cost lands in
-        # stats.splitter_s/splitter_samples for the bench sort leg.
+        # stats.splitter_s/splitter_samples.
         from mapreduce_rust_tpu.runtime.splitter import prepare_app
 
         app = prepare_app(app, cfg, inputs, corpus_bounds or (), stats=stats)

@@ -414,9 +414,8 @@ def build_fleet_report(target: str) -> dict:
 
 
 def fleet_history_row(report: dict) -> dict:
-    """The three trend-watched series the bench history records — one
-    place, so bench.py and any future caller agree on the names doctor
-    trend follows."""
+    """The three fleet series ``doctor trend`` watches, named in one place
+    for any writer of its history file."""
     f = report.get("fleet") or {}
     return {
         "fleet_bubble_frac": f.get("bubble_frac", 0.0),

@@ -14,8 +14,8 @@ bookkeeping the coordinator already ships.
 
 Contracts (the prof.py plane doctrine):
 - **Observational only.** Nothing the data plane reads is touched, so
-  outputs are bit-identical lineage ON vs OFF; the tax is gated ≤2% by
-  bench's ``--lineage-overhead`` interleaved pair.
+  outputs are bit-identical lineage ON vs OFF; the tax is meant to stay
+  ≤2% of wall.
 - **Crash-durable.** Records are flushed line-by-line (the reader pops a
   torn tail, like the coordinator journal's parser) and the flight
   recorder embeds the in-memory tail in every ``*.partial.json``, so a
@@ -82,7 +82,7 @@ def lineage_forced() -> bool:
 #: window). The sample is a pure function of the bytes, so digests stay
 #: reproducible and comparable; what it trades away is detection of a
 #: same-length in-place edit that dodges every sampled byte. That trade
-#: is what keeps the ledger inside bench's ≤2% wall contract on a
+#: is what keeps the ledger inside its ≤2% wall budget on a
 #: CPU-saturated host (a full blake2b of every window byte costs more
 #: than the 2% budget on any box whose scan runs near the hash's own
 #: speed) — and the common corpus edits (append, truncate, touch a
@@ -214,7 +214,7 @@ class LineageLedger:
         plane owns what happens below. It runs inline on purpose —
         write + flush per line is what makes the file torn-tail-safe
         under SIGKILL, and the ledger is an explicit opt-in measurement
-        path whose tax bench gates at ≤2% (--lineage-overhead)."""
+        path whose tax is budgeted at ≤2% of wall."""
         self._f.write(json.dumps(rec, separators=(",", ":")) + "\n")
         self._f.flush()
 

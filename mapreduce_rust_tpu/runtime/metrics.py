@@ -214,8 +214,8 @@ class JobStats:
     splitter_samples: int = 0     # tokens the sampled-splitter pre-pass
     # drew across all inputs (range apps only; 0 = no pre-pass ran)
     splitter_s: float = 0.0       # wall seconds of the sample+derive
-    # pre-pass — the splitter-overhead the bench sort leg records; it
-    # must stay O(samples), invisible next to the stream
+    # pre-pass — the splitter overhead; it must stay O(samples),
+    # invisible next to the stream
     mesh_shard_rows: list = dataclasses.field(default_factory=list)
     # final valid records per mesh shard (hash-class skew across chips)
     hists: dict = dataclasses.field(default_factory=dict)

@@ -7,8 +7,8 @@ satellite 1 establishes (``mr/scan-*``, ``mr/fold-*``, ``mr/spill-*``,
 ``mr/dispatch``, ``mr/ingest``, the router/consumer on ``MainThread``).
 Everything is observational — the sampler takes no lock any plane thread
 holds and mutates nothing the data plane reads, so outputs are
-bit-identical profile ON vs OFF and the tax is bounded by the bench's
-interleaved ``--profile-overhead`` leg (≤ 2 % wall).
+bit-identical profile ON vs OFF and the tax is budgeted at ≤ 2 % of
+wall.
 
 Memory is bounded by a capped frame table (distinct code locations) and
 a capped stack table (distinct collapsed stacks); past either cap new

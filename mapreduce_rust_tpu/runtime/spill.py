@@ -71,8 +71,8 @@ _TRUTHY = ("1", "true", "on", "yes")
 
 def sync_spill_forced() -> bool:
     """``MR_SPILL_SYNC`` — process-tree opt-out of the async writer (the
-    MR_SANITIZE enablement pattern): the bench's slow-disk chaos pair runs
-    the same job sync-vs-async to measure exactly what the overlap hides."""
+    MR_SANITIZE enablement pattern): the same job runs sync instead of
+    async, to show what the overlap hides."""
     return os.environ.get("MR_SPILL_SYNC", "").strip().lower() in _TRUTHY
 
 

@@ -29,8 +29,7 @@ keyed by a job id that rides every task RPC as a trailing default arg
   ``(app, corpus-digest, config-digest)``; a repeated identical
   submission is answered from cache with ZERO new task grants (its
   ``job_status`` says ``cached`` and carries no task counts). Hits,
-  misses and evictions are metrics series and ride the bench service
-  leg's history row.
+  misses and evictions are metrics series.
 - **Graceful drain / restart** — SIGTERM (or the ``shutdown`` RPC) stops
   admitting, lets running jobs finish, flushes per-job journals and
   reports, and exits; queued jobs stay in the SERVICE journal
@@ -292,7 +291,7 @@ class _ResultCache:
     Values are {job, outputs} records; a hit re-validates that every
     output file still exists (a wiped output dir is a miss, recompute —
     the cache must never serve paths that are gone). Hit/miss/eviction
-    counters feed the metrics registry and the bench service leg."""
+    counters feed the metrics registry."""
 
     def __init__(self, capacity: int) -> None:
         self.capacity = capacity

@@ -3,7 +3,7 @@
 This simulates a multi-chip mesh on the single-host test machine
 (SURVEY.md §4): shard_map/all_to_all code paths run unchanged. Child
 processes the tests spawn inherit JAX_PLATFORMS=cpu and the device count.
-Tests are never chip evidence; chip_smoke.py on the chip is.
+Tests are never chip evidence; benchmark/run.py on the chip is.
 """
 
 import os

@@ -15,7 +15,6 @@ import collections
 import dataclasses
 import json
 import pathlib
-import socket
 import time
 
 import pytest
@@ -26,17 +25,13 @@ from mapreduce_rust_tpu.coordinator.server import Coordinator
 from mapreduce_rust_tpu.core.normalize import reference_word_counts
 from mapreduce_rust_tpu.worker.runtime import Worker
 
+from cluster_harness import CHAOS_TEXTS, chaos_cluster, free_port
+
 TEXTS = [
     "the quick brown fox jumps over the lazy dog " * 30,
     "pack my box with five dozen liquor jugs don’t stop " * 20,
     "sphinx of black quartz judge my vow " * 25,
 ]
-
-
-def free_port() -> int:
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        return s.getsockname()[1]
 
 
 def make_cfg(tmp_path, n_files, **kw) -> Config:
@@ -176,16 +171,22 @@ def test_plan_records_fired_events():
 
 async def _cluster_timed(cfg, worker_cfgs, engine="host", timeout=90):
     """Coordinator + one Worker per cfg; returns (coord, workers,
-    job_wall_s) where job_wall is measured at COORDINATOR completion —
-    a paused straggler unwinding after the job must not count."""
+    job_wall_s) where job_wall is measured when the coordinator marks the
+    job done — a paused straggler unwinding after the job must not count.
+    Not when serve() returns: on Python 3.12 its wait_closed() returns
+    only after every connection has closed, the paused loser's included."""
     coord = Coordinator(cfg)
     serve = asyncio.create_task(coord.serve())
     await asyncio.sleep(0.1)
     ws = [Worker(c, engine=engine) for c in worker_cfgs]
     t0 = time.perf_counter()
     workers = asyncio.gather(*(w.run() for w in ws))
-    await asyncio.wait_for(serve, timeout=timeout)
+    deadline = t0 + timeout
+    while (not coord.done() and not serve.done()
+           and time.perf_counter() < deadline):
+        await asyncio.sleep(0.01)
     job_wall = time.perf_counter() - t0
+    await asyncio.wait_for(serve, timeout=timeout)
     await asyncio.wait_for(workers, timeout=timeout)
     return coord, ws, job_wall
 
@@ -193,17 +194,11 @@ async def _cluster_timed(cfg, worker_cfgs, engine="host", timeout=90):
 # ---------------------------------------------------------------------------
 # Tier-1: seeded chaos smoke (pause + SIGKILL, real OS processes)
 # ---------------------------------------------------------------------------
-#
-# The subprocess cluster harness is bench.py's `_chaos_cluster` — ONE
-# implementation drives both the benched chaos matrix and these tests, so
-# the benched cluster and the tested cluster can never drift apart.
-
-import bench  # noqa: E402  (repo root is on sys.path via conftest)
 
 
 def _chaos_oracle() -> dict:
     total = collections.Counter()
-    for t in bench._CHAOS_TEXTS:
+    for t in CHAOS_TEXTS:
         total.update(reference_word_counts(t))
     return {w.encode(): c for w, c in total.items()}
 
@@ -213,12 +208,12 @@ def test_chaos_smoke_pause_plus_sigkill(tmp_path):
     combining a pause (slow-but-alive straggler) and a SIGKILL (dead
     worker) completes, and the output is BIT-IDENTICAL to the fault-free
     run of the same cluster binaries."""
-    clean = bench._chaos_cluster("clean", tmp_path, None, False)
+    clean = chaos_cluster("clean", tmp_path, None, False)
     assert clean["recovered"]
     assert clean["outputs"], "fault-free run produced no outputs"
     assert read_outputs(pathlib.Path(clean["dir"]) / "out") == _chaos_oracle()
 
-    chaos = bench._chaos_cluster(
+    chaos = chaos_cluster(
         "chaos", tmp_path, "seed=9;pause:map:0:0.8;kill:reduce:1", False
     )
     assert chaos["recovered"]
@@ -249,9 +244,9 @@ def test_chaos_pipeline_kill_bit_identical(tmp_path):
     fault-free FIFO run of the same binaries — the A/B oracle across
     both the scheduler and the fault. mrcheck (early-reduce-grant
     included) replays both legs."""
-    clean = bench._chaos_cluster("clean", tmp_path, None, False)
+    clean = chaos_cluster("clean", tmp_path, None, False)
     assert clean["recovered"]
-    pipe = bench._chaos_cluster(
+    pipe = chaos_cluster(
         "kill-pipe", tmp_path, "seed=2;kill:map:1", False, sched="pipeline"
     )
     assert pipe["recovered"]
@@ -379,13 +374,13 @@ def test_full_chaos_matrix_bit_identical(tmp_path):
     criterion, against the real binaries."""
     from mapreduce_rust_tpu.analysis.mrcheck import run_check
 
-    clean = bench._chaos_cluster("clean", tmp_path, None, False)
+    clean = chaos_cluster("clean", tmp_path, None, False)
     assert clean["recovered"] and clean["outputs"]
     assert read_outputs(pathlib.Path(clean["dir"]) / "out") == _chaos_oracle()
     assert run_check(str(pathlib.Path(clean["dir"]) / "work"))["ok"]
     for name, spec in SCENARIOS.items():
-        r = bench._chaos_cluster(name, tmp_path, spec,
-                                 speculate=(name == "slow_scan"))
+        r = chaos_cluster(name, tmp_path, spec,
+                          speculate=(name == "slow_scan"))
         assert r["recovered"], name
         assert r["outputs"] == clean["outputs"], name
         # The zero-false-positive half of the ISSUE 7 acceptance: every
@@ -407,7 +402,7 @@ def test_speculation_race_visible_in_merged_trace(tmp_path):
     # A longer slow-scan than the canonical scenario: under heavy machine
     # load the speculative grant can itself arrive seconds late, and the
     # WINNER of the race must stay deterministic for the chain asserts.
-    r = bench._chaos_cluster(
+    r = chaos_cluster(
         "spec", tmp_path, "seed=5;slow_scan:w0:6.0", speculate=True,
         trace=True,
     )

@@ -40,7 +40,7 @@ def test_check_exits_zero_on_canonical_cluster_run(tmp_path):
 
     assert (pathlib.Path(cfg.work_dir) / "job_report.json").exists()
     assert main(["check", cfg.work_dir]) == 0
-    # JSON document form, as the bench harness consumes it.
+    # JSON document form, as a program consumes it.
     from mapreduce_rust_tpu.analysis.mrcheck import run_check
 
     doc = run_check(cfg.work_dir)

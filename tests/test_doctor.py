@@ -297,9 +297,9 @@ def test_trend_insufficient_data_is_not_a_drift(tmp_path):
 
 
 def test_trend_watches_metrics_overhead_frac():
-    # ISSUE 8 satellite: the sampler-tax series from bench.py's on/off
-    # pair is a watched metric whose BAD direction is UP — a creeping
-    # overhead fraction drifts, a noisy-but-flat one stays quiet.
+    # The sampler-tax series (metrics on vs off) is a watched metric
+    # whose BAD direction is UP — a creeping overhead fraction drifts, a
+    # noisy-but-flat one stays quiet.
     from mapreduce_rust_tpu.analysis.doctor import analyze_trend
 
     creeping = [{"value": 1.0, "metrics_overhead_frac": round(0.002 * (1.5 ** i), 5)}

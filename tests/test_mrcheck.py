@@ -7,7 +7,7 @@ recorded run's artifacts with the mutation harness (mrcheck.MUTATIONS)
 and proves EVERY invariant fires — exit 1, offending event pair named —
 while the unmutated run passes with zero findings (the false-positive
 half of the acceptance criterion; the chaos matrix covers the rest in
-tests/test_check_clean.py and bench.py --chaos).
+tests/test_check_clean.py and tests/test_chaos.py).
 """
 
 import argparse

@@ -205,14 +205,14 @@ def test_global_slot_compare_and_clear():
 
 
 # ---------------------------------------------------------------------------
-# sampler tax (loose bound; the real estimator is bench --profile-overhead)
+# sampler tax (loose bound: a smoke alarm, not a measurement)
 # ---------------------------------------------------------------------------
 
 def test_sample_cost_leaves_headroom_under_budget():
     # Direct per-sample cost: at 97 Hz the sampler must stay far below
     # one core. 25% of a core is ~12x looser than the 2% acceptance bar
-    # the bench's interleaved A/B enforces — this is the smoke alarm,
-    # not the measurement.
+    # of an interleaved on/off A/B — this is the smoke alarm, not the
+    # measurement.
     p = SamplingProfiler(hz=97.0)
     my = threading.get_ident()
     n = 200

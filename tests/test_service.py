@@ -890,9 +890,9 @@ def test_finished_job_labels_dropped_from_registry(tmp_path):
 # ---------------------------------------------------------------------------
 
 def _cpu_env() -> dict:
-    import bench
+    from cluster_harness import cpu_env
 
-    env = bench._cpu_env()
+    env = cpu_env()
     env["PYTHONPATH"] = REPO
     return env
 

@@ -509,22 +509,21 @@ def test_chaos_kill_on_sort_job_rederives_identical_splitters(tmp_path):
     bit-identical to the fault-free run (one re-derivation disagreement
     would route keys to different partitions and the byte compare would
     catch it)."""
-    import bench
+    from cluster_harness import CHAOS_TEXTS, chaos_cluster
 
-    clean = bench._chaos_cluster("sort-clean", tmp_path, None, False,
-                                 app="sort")
+    clean = chaos_cluster("sort-clean", tmp_path, None, False, app="sort")
     assert clean["recovered"], clean.get("error")
     assert clean["outputs"]
     oracle = sorted(
-        tok for t in bench._CHAOS_TEXTS for tok in tokenize_host(t)
+        tok for t in CHAOS_TEXTS for tok in tokenize_host(t)
     )
     got = []
     for _name, data in sorted(clean["outputs"].items()):
         got.extend(data.splitlines())
     assert got == oracle
 
-    chaos = bench._chaos_cluster("sort-kill", tmp_path, "seed=5;kill:map:1",
-                                 False, app="sort")
+    chaos = chaos_cluster("sort-kill", tmp_path, "seed=5;kill:map:1",
+                          False, app="sort")
     assert chaos["recovered"], chaos.get("error")
     assert chaos["outputs"] == clean["outputs"]
     rep = json.loads(

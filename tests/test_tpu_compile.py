@@ -3,8 +3,10 @@
 The TPU compiler is installed here and compiles for a topology that is
 described, not attached: it refuses what the chip's compiler would refuse
 (misaligned slices, too much fast memory) — what interpret-mode tests
-cannot see. Only kernel-sized compiles belong here (about a second each);
-the full-width step programs are rehearsed by hand (see the verify skill).
+cannot see. Kernel-sized compiles take about a second each; the step
+program the driver runs on a TPU is compiled at a small width (about half a
+minute per app) to show that it carries the kernel. The full-width step
+programs are rehearsed by hand (see the verify skill).
 
 The topology is described inside a fixture, never at import time: only one
 process may load the TPU library, and every xdist worker imports this file.
@@ -36,6 +38,29 @@ def topo():
     yield desc
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("app_name", ["word_count", "inverted_index"])
+def test_step_map_combine_carries_pallas_scan_for_v5e(topo, app_name):
+    # The apps of the device cells: word count drops the doc table,
+    # inverted index reads it, so they compile to different programs. A
+    # map_combine without the kernel would show on the chip only as a
+    # slowdown.
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from mapreduce_rust_tpu.apps import get_app
+    from mapreduce_rust_tpu.runtime.chunker import DOC_SLOTS
+    from mapreduce_rust_tpu.runtime.driver import make_step_fns
+
+    map_combine = make_step_fns(get_app(app_name), 1 << 12, use_pallas=True)[0]
+    on_dev = SingleDeviceSharding(topo.devices[0])
+    hlo = map_combine.lower(
+        jax.ShapeDtypeStruct((1 << 16,), jnp.uint8, sharding=on_dev),
+        jax.ShapeDtypeStruct((1 + DOC_SLOTS,), jnp.int32, sharding=on_dev),
+    ).compile().as_text()
+    assert "tpu_custom_call" in hlo
 
 
 @pytest.mark.parametrize("n", [1 << 14, 1 << 20, 1 << 22])
