@@ -125,10 +125,23 @@ class App:
         Default: one 'word value' line. Sort emits the word ``value``
         times (a global sort's output is the input multiset); join emits
         one line per cross-product pair and [] for one-sided keys. Both
-        egress tiers (in-RAM and streaming) call exactly this, so an app
+        egress tiers (in-RAM and streaming) call exactly this (a distinct
+        app's through emit_postings below), so an app
         that only customizes emission never loses the bounded-memory
         spill path the way a finalize override does."""
         return [self.format_line(word, value)]
+
+    def emit_postings(self, words: list[bytes], docs: list[int],
+                      starts: list[int], ends: list[int], routes: list[int],
+                      parts: list[list[bytes]]) -> None:
+        """Output lines for a BLOCK of a distinct app's terms — the block
+        emission seam both egress tiers call. Term t is ``words[t]``, its
+        ascending posting list is ``docs[starts[t]:ends[t]]``, and its
+        lines go to ``parts[routes[t]]`` (unsorted), each from
+        ``emit_lines``, so every app keeps its exact output."""
+        emit = self.emit_lines
+        for w, r, s, e in zip(words, routes, starts, ends):
+            parts[r].extend(emit(w, docs[s:e]))
 
     def finalize(
         self, items: Iterable[tuple[bytes, "FinalValue", tuple[int, int]]], reduce_n: int

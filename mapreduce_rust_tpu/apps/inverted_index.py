@@ -16,7 +16,8 @@ file's index (runtime/chunker.py). The TPU formulation:
   device state's capacity spill to the host accumulator, which folds them
   by set union;
 - finalize groups the surviving (term, doc) pairs by term on the host and
-  emits 'word d0,d1,...' with doc_ids ascending.
+  emits 'word d0,d1,...' with doc_ids ascending: each term's postings are
+  a slice of the fold's sorted doc column (App.emit_postings).
 """
 
 from __future__ import annotations
@@ -51,4 +52,4 @@ class InvertedIndex(App):
         return np.full(len(counts), doc_id, dtype=np.uint32)
 
     def format_line(self, word: bytes, value) -> bytes:
-        return b"%s %s" % (word, ",".join(str(d) for d in value).encode())
+        return b"%s %s" % (word, b",".join([b"%d" % d for d in value]))

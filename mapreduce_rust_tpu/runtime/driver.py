@@ -391,6 +391,16 @@ def _combine_rows(op: str, keys: np.ndarray, vals: np.ndarray) -> np.ndarray:
     return _unpack_rows(uniq, folded)
 
 
+def _key_starts(packed: np.ndarray) -> np.ndarray:
+    """Index of each key's first row, given the sorted fold rows' packed
+    key column (_pack_key_cols): one neighbour compare. A distinct app's
+    term t owns rows[starts[t]:starts[t + 1]], its doc ids already
+    ascending."""
+    if not len(packed):
+        return np.empty(0, np.int64)
+    return np.flatnonzero(np.r_[True, packed[1:] != packed[:-1]])
+
+
 def _combine_pending(op: str, keys_list, vals_list) -> np.ndarray:
     """Combine pending (keys, vals) batches into sorted deduped rows
     [n, 3] (k1, k2, value) — value-keyed for "distinct", else folded.
@@ -419,8 +429,8 @@ class HostAccumulator:
     pending arrays (ISSUE 11), so the consumer keeps draining the device
     while the disk works; ``fold_arrays()`` drains the writer and merges
     the runs back exactly at finalize; ``.table`` (the Python-dict view)
-    stays for the in-RAM paths, while the streaming egress reads the
-    arrays.
+    stays for the scalar in-RAM egress and the distributed worker, while
+    the streaming egress and every distinct app's egress read the arrays.
     """
 
     def __init__(self, op: str, budget_bytes: int | None = None,
@@ -3061,12 +3071,6 @@ def run_job(
 
         is_distinct = app.combine_op == "distinct"
 
-        def postings():
-            """A distinct app's grouping of (term, doc) rows into posting
-            lists and their formatting (as in _stream_finalize)."""
-            return (stats.timed("posting_format_s", "egress.postings")
-                    if is_distinct else contextlib.nullcontext())
-
         if streaming:
             # _stream_finalize opens its own finalize/egress phase blocks —
             # nesting both here would double-count one interval under two keys.
@@ -3074,28 +3078,32 @@ def run_job(
                 cfg, app, stats, acc, dictionary, write_outputs
             )
         else:
-            with stats.phase("finalize"), postings():
-                stats.distinct_keys = len(acc.table)
+            with stats.phase("finalize"), _postings_span(app, stats):
                 stats.dictionary_words = len(dictionary)
                 stats.hash_collisions = len(dictionary.collisions)
-                items = []
                 lookup = dictionary.lookup
                 if dictionary.spilled:
                     # Rehydrate fallback: serve point lookups from the full
                     # sorted stream (runs + RAM) materialized once.
                     full = {(k1, k2): w for _p, k1, k2, w in dictionary.iter_sorted()}
                     lookup = lambda k1, k2: full.get((k1, k2))  # noqa: E731
-                for key, v in acc.table.items():
-                    word = lookup(*key)
-                    if word is None:
-                        stats.unknown_keys += 1
-                        continue
-                    value = sorted(v) if is_distinct else v
-                    items.append((word, value, key))
-                    table[word] = value
+                if is_distinct:
+                    # Grouped, looked up and formatted inside this one span.
+                    parts = _posting_parts(cfg, app, acc.fold_arrays(), lookup,
+                                           stats, table)
+                else:
+                    stats.distinct_keys = len(acc.table)
+                    items = []
+                    for key, v in acc.table.items():
+                        word = lookup(*key)
+                        if word is None:
+                            stats.unknown_keys += 1
+                            continue
+                        items.append((word, v, key))
+                        table[word] = v
 
             with stats.phase("egress"):
-                with postings():
+                if not is_distinct:
                     parts = app.finalize(items, cfg.reduce_n)
                 if write_outputs:
                     os.makedirs(cfg.output_dir, exist_ok=True)
@@ -3106,11 +3114,11 @@ def run_job(
                     suffix = f".p{jax.process_index()}" if jax.process_count() > 1 else ""
                     for r in range(cfg.reduce_n):
                         path = os.path.join(cfg.output_dir, f"mr-{r}{suffix}.txt")
-                        written = 0
+                        lines = parts.get(r)
+                        buf = b"\n".join(lines) + b"\n" if lines else b""
+                        written = len(buf)
                         with open(path, "wb") as f:
-                            for line in parts.get(r, []):
-                                f.write(line + b"\n")
-                                written += len(line) + 1
+                            f.write(buf)
                         # Per-partition output bytes: the reduce-side skew
                         # signal the doctor scores (index = partition r).
                         stats.partition_bytes.append(written)
@@ -3221,6 +3229,48 @@ def run_job(
     return JobResult(stats=stats, table=table, output_files=output_files)
 
 
+def _postings_span(app: App, stats: JobStats):
+    """"egress.postings" (posting_format_s), once a job in either egress
+    tier: a distinct app's grouping of (term, doc) rows into posting
+    lists, their words and their formatting. The two tiers' readings
+    differ in what else the span holds: in RAM the fold and the partition
+    sort, streaming the dictionary's merge and the per-block part-file
+    writes, which interleave with the formatting there."""
+    if app.combine_op != "distinct":
+        return contextlib.nullcontext()
+    return stats.timed("posting_format_s", "egress.postings")
+
+
+def _posting_parts(cfg: Config, app: App, rows: np.ndarray, lookup,
+                   stats: JobStats, table: dict) -> dict[int, list[bytes]]:
+    """A distinct app's in-RAM egress from its sorted fold rows [n, 3]:
+    terms are cut at key changes, a term's posting list is its slice of
+    the doc column (ascending and deduplicated by the fold), its word is
+    looked up once, and its lines come from the block seam
+    App.emit_postings. Fills ``table`` (word → ascending list[int]) and
+    returns partition → sorted lines, as App.finalize would."""
+    starts = _key_starts(_pack_key_cols(rows[:, :2]))
+    ends = np.append(starts[1:], len(rows))
+    stats.distinct_keys = len(starts)
+    k1s, k2s = rows[starts, 0].tolist(), rows[starts, 1].tolist()
+    words = list(map(lookup, k1s, k2s))
+    s_l, e_l = starts.tolist(), ends.tolist()
+    known = [i for i, w in enumerate(words) if w is not None]
+    if len(known) < len(words):
+        stats.unknown_keys += len(words) - len(known)
+        words, k1s, s_l, e_l = ([xs[i] for i in known]
+                                for xs in (words, k1s, s_l, e_l))
+    docs = rows[:, 2].tolist()
+    values = list(map(docs.__getitem__, map(slice, s_l, e_l)))
+    table.update(zip(words, values))
+    routes = app.route_block(words, k1s, cfg.reduce_n)
+    parts: list[list[bytes]] = [[] for _ in range(cfg.reduce_n)]
+    app.emit_postings(words, docs, s_l, e_l, routes, parts)
+    for lines in parts:
+        lines.sort()
+    return dict(enumerate(parts))
+
+
 def _stream_finalize(cfg: Config, app: App, stats: JobStats, acc: HostAccumulator,
                      dictionary: Dictionary, write_outputs: bool) -> list[str]:
     """Bounded-memory egress: a single merge-join of the accumulator's
@@ -3243,18 +3293,9 @@ def _stream_finalize(cfg: Config, app: App, stats: JobStats, acc: HostAccumulato
     with stats.phase("finalize"):
         rows = acc.fold_arrays()  # sorted by (k1, k2[, value])
         is_distinct = app.combine_op == "distinct"
-        packed_rows = (rows[:, 0].astype(np.uint64) << np.uint64(32)) | rows[
-            :, 1
-        ].astype(np.uint64)
+        packed_rows = _pack_key_cols(rows[:, :2])
         n = len(rows)
-        if is_distinct:
-            key_change = np.empty(n, dtype=bool)
-            if n:
-                key_change[0] = True
-                key_change[1:] = packed_rows[1:] != packed_rows[:-1]
-            stats.distinct_keys = int(key_change.sum())
-        else:
-            stats.distinct_keys = n
+        stats.distinct_keys = len(_key_starts(packed_rows)) if is_distinct else n
         stats.dictionary_words = len(dictionary)
         stats.hash_collisions = len(dictionary.collisions)
 
@@ -3286,71 +3327,76 @@ def _stream_finalize(cfg: Config, app: App, stats: JobStats, acc: HostAccumulato
                 sources = dictionary.run_sources()
                 stats.merge_fanin = len(sources)
                 merge_it = spill_io.merge_sources(sources)
-                while True:
-                    t0 = time.perf_counter()
-                    blk = next(merge_it, None)
-                    if blk is None:
-                        break
-                    keys_b, src_b, idx_b = blk
-                    ends_g = None
-                    if n:
-                        pos = np.searchsorted(packed_rows, keys_b)
-                        posc = np.minimum(pos, n - 1)
-                        hit = (pos < n) & (packed_rows[posc] == keys_b)
-                        if is_distinct:
-                            # Fold rows repeat per (key, doc): the group's
-                            # exclusive end, found once per block.
-                            ends_g = np.searchsorted(
-                                packed_rows, keys_b, side="right"
-                            )
-                    else:
-                        hit = np.zeros(len(keys_b), dtype=bool)
-                    stats.record_hist(
-                        "egress.merge_s", time.perf_counter() - t0
-                    )
-                    hits = np.nonzero(hit)[0]
-                    if not len(hits):
-                        continue  # dictionary words absent from the fold
-                    # Batched word slicing (spill_io.slice_block_words,
-                    # shared with the streaming save): word bytes are
-                    # materialized only for keys the fold holds — at
-                    # millions of matched words the per-item .word() path
-                    # was a measurable slice of egress.
-                    words = spill_io.slice_block_words(
-                        sources, src_b[hits], idx_b[hits]
-                    )
-                    # Routing goes through the app's partition seam
-                    # (ISSUE 15): hash apps keep k1 % reduce_n, range
-                    # apps (sort) searchsorted the word prefixes over
-                    # their sampler-bound splitters — element-wise equal
-                    # to App.route, the in-RAM tier's router.
-                    rr = app.route_block(
-                        words,
-                        (keys_b[hits] >> np.uint64(32)).astype(np.int64),
-                        cfg.reduce_n,
-                    )
-                    pos_h = pos[hits]
-                    emit = app.emit_lines
-                    # One buffered write per (block, partition), not one
-                    # per line: the formatted lines batch through a join.
-                    blk_lines: list[list] = [[] for _ in range(cfg.reduce_n)]
-                    if is_distinct:
-                        with stats.timed("posting_format_s", "egress.postings"):
-                            for w, r, i, j2 in zip(
-                                words, rr, pos_h.tolist(), ends_g[hits].tolist()
-                            ):
-                                blk_lines[r].extend(
-                                    emit(w, sorted(rows[i:j2, 2].tolist()))
+                # One "egress.postings" span over the whole join for a
+                # distinct app: word lookup, grouping and formatting.
+                with _postings_span(app, stats):
+                    while True:
+                        t0 = time.perf_counter()
+                        blk = next(merge_it, None)
+                        if blk is None:
+                            break
+                        keys_b, src_b, idx_b = blk
+                        ends_g = None
+                        if n:
+                            pos = np.searchsorted(packed_rows, keys_b)
+                            posc = np.minimum(pos, n - 1)
+                            hit = (pos < n) & (packed_rows[posc] == keys_b)
+                            if is_distinct:
+                                # Fold rows repeat per (key, doc): the group's
+                                # exclusive end, found once per block.
+                                ends_g = np.searchsorted(
+                                    packed_rows, keys_b, side="right"
                                 )
-                    else:
-                        for w, r, v in zip(
-                            words, rr, rows[pos_h, 2].tolist()
-                        ):
-                            blk_lines[r].extend(emit(w, v))
-                    for r, ls in enumerate(blk_lines):
-                        if ls:
-                            parts[r].write(b"\n".join(ls) + b"\n")
-                    matched += len(hits)
+                        else:
+                            hit = np.zeros(len(keys_b), dtype=bool)
+                        stats.record_hist(
+                            "egress.merge_s", time.perf_counter() - t0
+                        )
+                        hits = np.nonzero(hit)[0]
+                        if not len(hits):
+                            continue  # dictionary words absent from the fold
+                        # Batched word slicing (spill_io.slice_block_words,
+                        # shared with the streaming save): word bytes are
+                        # materialized only for keys the fold holds — at
+                        # millions of matched words the per-item .word() path
+                        # was a measurable slice of egress.
+                        words = spill_io.slice_block_words(
+                            sources, src_b[hits], idx_b[hits]
+                        )
+                        # Routing goes through the app's partition seam
+                        # (ISSUE 15): hash apps keep k1 % reduce_n, range
+                        # apps (sort) searchsorted the word prefixes over
+                        # their sampler-bound splitters — element-wise equal
+                        # to App.route, the in-RAM tier's router.
+                        rr = app.route_block(
+                            words,
+                            (keys_b[hits] >> np.uint64(32)).astype(np.int64),
+                            cfg.reduce_n,
+                        )
+                        pos_h = pos[hits]
+                        # One buffered write per (block, partition), not one
+                        # per line: the formatted lines batch through a join.
+                        blk_lines: list[list] = [[] for _ in range(cfg.reduce_n)]
+                        if is_distinct:
+                            # The block's terms own fold rows lo:hi; each
+                            # term's postings are its slice, already ascending.
+                            ends_h = ends_g[hits]
+                            lo, hi = int(pos_h.min()), int(ends_h.max())
+                            app.emit_postings(
+                                words, rows[lo:hi, 2].tolist(),
+                                (pos_h - lo).tolist(), (ends_h - lo).tolist(),
+                                rr, blk_lines,
+                            )
+                        else:
+                            emit = app.emit_lines
+                            for w, r, v in zip(
+                                words, rr, rows[pos_h, 2].tolist()
+                            ):
+                                blk_lines[r].extend(emit(w, v))
+                        for r, ls in enumerate(blk_lines):
+                            if ls:
+                                parts[r].write(b"\n".join(ls) + b"\n")
+                        matched += len(hits)
             finally:
                 for f in parts:
                     f.close()

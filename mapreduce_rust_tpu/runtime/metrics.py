@@ -105,7 +105,7 @@ class JobStats:
     spill_evict_s: float = 0.0    # "spill": the single-chip stream moving
     # evicted state tails device → host accumulator (drain, replays)
     posting_format_s: float = 0.0  # "egress.postings": a distinct app's
-    # per-term grouping of (term, doc) rows and posting-list formatting
+    # grouping of (term, doc) rows and posting-list formatting, once a job
     job_teardown_s: float = 0.0   # "job.teardown": run_job's finally
     # (spill cleanup, profiler/ledger stop, manifest flush) — the manifest
     # it writes is flushed inside this interval, so it reads 0 there

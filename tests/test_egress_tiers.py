@@ -14,8 +14,11 @@ import pathlib
 import numpy as np
 import pytest
 
-from mapreduce_rust_tpu.apps import InvertedIndex
+from mapreduce_rust_tpu.apps import InvertedIndex, get_app
+from mapreduce_rust_tpu.apps.grep import Grep
+from mapreduce_rust_tpu.apps.join import Join
 from mapreduce_rust_tpu.config import Config
+from mapreduce_rust_tpu.core.hashing import tokenize_host
 from mapreduce_rust_tpu.runtime.dictionary import Dictionary
 from mapreduce_rust_tpu.runtime.driver import HostAccumulator, run_job
 
@@ -79,23 +82,121 @@ def test_budgeted_outputs_identical_and_runs_on_disk(tmp_path, app_engine):
     assert res.stats.dictionary_words == 3000
 
 
-def test_budgeted_inverted_index_exact(tmp_path):
-    d = tmp_path / "in"
-    d.mkdir()
-    texts = ["alpha beta gamma " * 50, "beta delta " * 40, "gamma alpha epsilon " * 30]
-    inputs = []
-    for i, t in enumerate(texts):
-        p = d / f"doc-{i}.txt"
-        p.write_bytes(t.encode())
-        inputs.append(str(p))
-    plain = cfg_for(tmp_path, "ii-plain")
-    r1 = run_job(plain, inputs, app=InvertedIndex())
-    tiered = cfg_for(tmp_path, "ii-tiered", host_accum_budget_mb=0,
-                     dictionary_budget_words=2)
-    r2 = run_job(tiered, inputs, app=InvertedIndex())
-    assert read_outputs(tiered) == read_outputs(plain)
-    assert r2.stats.unknown_keys == 0
-    assert r1.table  # the RAM path still returns the table
+def _many_docs() -> list[str]:
+    """120 documents, so doc ids are 1-3 digits wide: "every" is in each,
+    solo<i> in one, mod<k> in every k-th, and a tail of shared tokens."""
+    return [
+        "every every " + f"solo{i:03d} "
+        + " ".join(f"mod{k}" for k in (2, 3, 5, 7, 11) if i % k == 0) + " "
+        + " ".join(f"tail{(i * 37 + j) % 300:03d}" for j in range(6))
+        for i in range(120)
+    ]
+
+
+_PAIRS = " ".join(f"pair{i:03d}" for i in range(300))
+EGRESS_CASES = {
+    "three_docs": (InvertedIndex(), {"in": [
+        "alpha beta gamma " * 50, "beta delta " * 40, "gamma alpha epsilon " * 30]}),
+    "many_docs": (InvertedIndex(), {"in": _many_docs()}),
+    "empty": (InvertedIndex(), {"in": [""]}),
+    "grep": (Grep(query=("every", "solo007", "mod7", "tail123", "absent")),
+             {"in": _many_docs()}),
+    "join": (Join(), {"in-a": ["apple banana shared dup dup onlyleft " * 9 + _PAIRS,
+                               "banana fig shared " * 7],
+                      "in-b": ["banana shared onlyright " * 8 + _PAIRS,
+                               "shared fig " * 5, "banana " * 4]}),
+}
+
+
+def egress_oracle(app, corpora) -> tuple[dict, list[bytes]]:
+    """word → ascending global doc ids, and the job's sorted output lines,
+    from the host tokenizer alone."""
+    postings: dict = {}
+    bound = len(next(iter(corpora.values())))
+    texts = [t for ts in corpora.values() for t in ts]
+    for doc, t in enumerate(texts):
+        for w in tokenize_host(t.encode()):
+            postings.setdefault(w, set()).add(doc)
+    postings = {w: sorted(ds) for w, ds in postings.items()
+                if not isinstance(app, Grep) or w.decode() in app.query}
+    if isinstance(app, Join):
+        lines = [b"%s %d %d" % (w, a, b - bound) for w, ds in postings.items()
+                 for a in ds if a < bound for b in ds if b >= bound]
+    else:
+        lines = [w + b" " + b",".join(b"%d" % d for d in ds)
+                 for w, ds in postings.items()]
+    return postings, sorted(lines)
+
+
+@pytest.mark.parametrize("case", sorted(EGRESS_CASES))
+def test_budgeted_inverted_index_exact(tmp_path, monkeypatch, case):
+    """A distinct app's output bytes are one and the same from the in-RAM
+    tier, the streaming tier and the str-joining posting formatter that
+    InvertedIndex.format_line replaced, and the in-RAM table maps each
+    word to its ascending doc ids."""
+    app, corpora = EGRESS_CASES[case]
+    for name, texts in corpora.items():
+        d = tmp_path / name
+        d.mkdir()
+        for i, t in enumerate(texts):
+            (d / f"doc-{i:03d}.txt").write_bytes(t.encode())
+    where = ({"input_dirs": tuple((n, str(tmp_path / n)) for n in corpora)}
+             if isinstance(app, Join) else {})
+
+    def run(tag, **kw):
+        cfg = cfg_for(tmp_path, f"ii-{tag}", reduce_n=4, **where, **kw)
+        return cfg, run_job(cfg, app=app)
+
+    plain, r1 = run("plain")
+    tiered, r2 = run("tiered", host_accum_budget_mb=0, dictionary_budget_words=2)
+    monkeypatch.setattr(
+        InvertedIndex, "format_line",
+        lambda self, word, value: b"%s %s" % (
+            word, ",".join(str(d) for d in value).encode()))
+    str_joined, r3 = run("str-joined")
+    assert read_outputs(tiered) == read_outputs(plain) == read_outputs(str_joined)
+    postings, lines = egress_oracle(app, corpora)
+    got = b"".join(read_outputs(plain).values()).splitlines()
+    assert sorted(got) == lines
+    assert r1.table == r3.table == postings  # word → ascending list[int]
+    assert all(type(v) is list for v in r1.table.values())
+    assert r2.table == {} and r2.stats.unknown_keys == 0
+    assert r1.stats.distinct_keys == r2.stats.distinct_keys == len(r1.table)
+    if case == "many_docs":
+        assert b"every " + b",".join(b"%d" % d for d in range(120)) in got
+        assert r2.stats.accum_spill_runs > 0 and r2.stats.dict_spill_runs > 0
+    if case == "join":
+        assert b"pair000 0 0" in got and len(got) > 300  # the cross product
+
+
+@pytest.mark.parametrize("app_name", ["inverted_index", "word_count", "join"])
+def test_distinct_keys_counts_terms_in_either_tier(tmp_path, app_name):
+    """distinct_keys is the job's key count in both egress tiers: terms cut
+    at key changes of the fold for a distinct app (inverted index, join),
+    fold rows for word count."""
+    texts = ["alpha beta alpha", "beta gamma", "gamma delta alpha"]
+    for side in ("in", "in-b"):
+        (tmp_path / side).mkdir()
+        for i, t in enumerate(texts):
+            (tmp_path / side / f"doc-{i}.txt").write_bytes(t.encode())
+    kw = ({"input_dirs": (("a", str(tmp_path / "in")), ("b", str(tmp_path / "in-b")))}
+          if app_name == "join" else {})
+    for tag, tier in (("ram", {}), ("streaming", {"host_accum_budget_mb": 0,
+                                                   "dictionary_budget_words": 2})):
+        cfg = cfg_for(tmp_path, f"{app_name}-{tag}", **kw, **tier)
+        res = run_job(cfg, app=get_app(app_name))
+        assert (res.table == {}) == bool(tier)  # the streaming tier ran
+        assert res.stats.distinct_keys == 4 and res.stats.unknown_keys == 0
+
+
+def test_posting_line_format():
+    """One posting line: the word, a space, ascending doc ids by commas,
+    for Python and NumPy ints alike (the worker path and the fold's
+    column)."""
+    ii = InvertedIndex()
+    assert ii.format_line(b"w", [0, 7, 120]) == b"w 0,7,120"
+    assert ii.format_line(b"w", np.array([3, 45], np.uint32)) == b"w 3,45"
+    assert ii.format_line(b"solo", [9]) == b"solo 9"
 
 
 def test_budgeted_mesh_run_exact(tmp_path):

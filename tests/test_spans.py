@@ -57,10 +57,15 @@ def oracle() -> dict:
     return {w.encode(): c for w, c in total.items()}
 
 
-def host_span_names(trace_dir) -> set:
+def host_span_counts(trace_dir) -> collections.Counter:
     (xplane,) = glob.glob(str(trace_dir / "plugins/profile/*/*.xplane.pb"))
-    return {e.name for p in ProfileData.from_file(xplane).planes
-            if p.name.startswith("/host:") for line in p.lines for e in line.events}
+    return collections.Counter(
+        e.name for p in ProfileData.from_file(xplane).planes
+        if p.name.startswith("/host:") for line in p.lines for e in line.events)
+
+
+def host_span_names(trace_dir) -> set:
+    return set(host_span_counts(trace_dir))
 
 
 @pytest.mark.parametrize("engine", ["device", "host"])
@@ -149,28 +154,37 @@ def test_manifest_flush_compiles_nothing(tmp_path):
     assert "merge_cost" not in m
 
 
-def test_dispatch_counters_spill_and_posting_spans(tmp_path):
+@pytest.mark.parametrize("tier", [
+    {},  # in-RAM egress
+    {"host_accum_budget_mb": 0, "dictionary_budget_words": 4},  # streaming
+])
+def test_dispatch_counters_spill_and_posting_spans(tmp_path, tier):
     """chunks_dispatched and chunk_payload_bytes count what the device
     engine sent; spill_evict_s times the "spill" span of the stream's
     evictions and posting_format_s the "egress.postings" span of a distinct
-    app's egress, both seen on the profiler's host plane."""
+    app's egress, once a job in either egress tier, both seen on the
+    profiler's host plane."""
     from mapreduce_rust_tpu.apps import InvertedIndex
 
-    cfg, inputs = tiny_job(tmp_path, "device", merge_capacity=8)
+    cfg, inputs = tiny_job(tmp_path, "device", merge_capacity=8, **tier)
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     jax.profiler.start_trace(str(tmp_path / "prof"), profiler_options=opts)
     try:
-        s = run_job(cfg, inputs, app=InvertedIndex()).stats
+        res = run_job(cfg, inputs, app=InvertedIndex())
     finally:
         jax.profiler.stop_trace()
+    s = res.stats
+    assert (res.table == {}) == bool(tier)  # the streaming tier ran
     # Two documents of 1,760 and 1,200 bytes share one 4,096-byte chunk.
     assert s.chunks_dispatched == 1
     assert s.chunk_payload_bytes == sum(len(t) for t in TEXTS)
     assert s.spill_events > 0 and s.spill_evict_s > 0
     assert s.posting_format_s > 0
     assert s.posting_format_s <= s.phase_seconds["finalize"] + s.phase_seconds["egress"]
-    assert {"spill", "egress.postings"} <= host_span_names(tmp_path / "prof")
+    assert s.distinct_keys > 0
+    spans = host_span_counts(tmp_path / "prof")
+    assert spans["spill"] > 0 and spans["egress.postings"] == 1
     # Word count groups no postings and, here, spills nothing.
     (tmp_path / "wc").mkdir()
     w = run_job(*tiny_job(tmp_path / "wc", "device")).stats
