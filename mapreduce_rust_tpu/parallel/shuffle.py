@@ -115,9 +115,11 @@ def _chip_shuffle_tail(kv: KVBatch, doc_table, app: App, u_cap: int,
     and the driver replays through a wider tier — which is what lets
     merges dispatch before any flag reaches the host.
 
-    Returns (local KVBatch, p_flag, b_flag): per-chip raw counters, or the
-    psum-reduced (replicated) totals when replicate_flags — the form a
-    multi-process driver needs, since it can only read its own shards."""
+    Returns (local KVBatch, p_flag, b_flag, live): per-chip raw counters,
+    or the psum-reduced (replicated) totals when replicate_flags — the
+    form a multi-process driver needs, since it can only read its own
+    shards. ``live`` is this chip's count of valid bucket rows sent into
+    the all_to_all, the used part of its fixed-capacity buckets."""
     op = app.combine_op
     # named_scope blocks label the lowered XLA ops, so a device profile
     # (Config.profile_dir) shows combine / all_to_all / reduce as named
@@ -133,6 +135,7 @@ def _chip_shuffle_tail(kv: KVBatch, doc_table, app: App, u_cap: int,
         # (apps/base.App.route_block — hashes alone cannot order words).
         buckets, b_ovf = bucket_scatter(update, num_buckets=d,
                                         capacity=bucket_cap, mode="hash")
+        live = jnp.sum(buckets.valid.astype(jnp.int32))
     with jax.named_scope("shuffle.all_to_all"):
         recv = jax.tree.map(
             lambda x: jax.lax.all_to_all(x, AXIS, split_axis=0, concat_axis=0, tiled=True),
@@ -147,8 +150,8 @@ def _chip_shuffle_tail(kv: KVBatch, doc_table, app: App, u_cap: int,
     # if clamped records become SENTINEL padding (ops/groupby.clamp_batch).
     local = clamp_batch(local, (p_tot + b_tot) == 0)
     if replicate_flags:
-        return local, p_tot, b_tot
-    return local, p_ovf, b_ovf
+        return local, p_tot, b_tot, live
+    return local, p_ovf, b_ovf, live
 
 
 _KV_SHUFFLE_FNS: dict = {}  # (app, u_cap, bucket_cap, mesh, width) → fn
@@ -179,7 +182,7 @@ def _build_kv_shuffle(app: App, u_cap: int, bucket_cap: int, mesh: Mesh):
         out_specs=(P(AXIS), P(AXIS), P(AXIS)),
     )
     def map_shuffle_kv(kv: KVBatch, doc_tables: jnp.ndarray):
-        local, p_ovf, b_ovf = _chip_shuffle_tail(
+        local, p_ovf, b_ovf, _live = _chip_shuffle_tail(
             KVBatch(*(x[0] for x in kv)), doc_tables[0], app, u_cap, bucket_cap,
             d, replicate_flags=False,
         )
@@ -198,11 +201,13 @@ def _build_shuffle_step_fns(app: App, u_cap: int, bucket_cap: int, mesh: Mesh,
 
     map_shuffle: chunks [D, chunk_bytes], doc tables [D, 1 + DOC_SLOTS]
         (runtime/chunker.doc_table) →
-        (local KVBatch [D, D*bucket_cap], partial_ovf [D], bucket_ovf [D]).
+        (local KVBatch [D, D*bucket_cap], partial_ovf [D], bucket_ovf [D],
+        live_rows [D]).
         partial_ovf counts capacity faults on the map side — distinct keys
         past u_cap plus raw tokens past the compaction cap
         (ops/groupby.compaction_cap); bucket_ovf counts records dropped by
-        bucket skew beyond bucket_cap.
+        bucket skew beyond bucket_cap; live_rows counts each chip's valid
+        bucket rows, of the D*bucket_cap it sends.
         Either nonzero → the driver replays the group through a wider tier
         (bucket_cap=u_cap kills bucket overflow by construction;
         u_cap=chunk capacity kills partial overflow) — results stay exact.
@@ -218,10 +223,10 @@ def _build_shuffle_step_fns(app: App, u_cap: int, bucket_cap: int, mesh: Mesh,
     @functools.partial(
         shard_map, mesh=mesh,
         in_specs=(P(AXIS), P(AXIS)),
-        out_specs=(P(AXIS), P(AXIS), P(AXIS)),
+        out_specs=(P(AXIS), P(AXIS), P(AXIS), P(AXIS)),
     )
     def map_shuffle(chunks: jnp.ndarray, doc_tables: jnp.ndarray):
-        local, p_ovf, b_ovf = _chip_shuffle_tail(
+        local, p_ovf, b_ovf, live = _chip_shuffle_tail(
             tokenize_and_hash(chunks[0], use_pallas=use_pallas),
             doc_tables[0], app, u_cap, bucket_cap,
             d, replicate_flags,
@@ -230,6 +235,7 @@ def _build_shuffle_step_fns(app: App, u_cap: int, bucket_cap: int, mesh: Mesh,
             KVBatch(*(x[None] for x in local)),
             p_ovf[None],
             b_ovf[None],
+            live[None],
         )
 
     @functools.partial(jax.jit, donate_argnums=(0,))

@@ -726,7 +726,9 @@ def _a2a_span(stats, **span_args):
     split exists even for untraced runs (the tracer's per-round summary
     rides along only when tracing is on). Covers tokenize + bucket scatter
     + collective + merge dispatch of the round — on an async backend this
-    is dispatch-side time; the blocking tail lands in device_wait_s."""
+    is dispatch-side time; the blocking tail lands in device_wait_s, and
+    the chunked mesh stream puts its group on the mesh before the block
+    (mesh.feed, mesh_feed_s)."""
     t0 = time.perf_counter()
     try:
         with trace_span("mesh.all_to_all", **span_args):
@@ -2326,7 +2328,7 @@ def _stream_multihost(cfg: Config, app: App, inputs, stats, acc, dictionary) -> 
         stats.shuffle_wire_bytes += wire_bytes_per_round(d, bucket_cap)
         with _a2a_span(stats, round=stats.mesh_rounds, tier="fast",
                        wire_bytes=wire_bytes_per_round(d, bucket_cap)):
-            local, bad_p, bad_b = fast[0](chunks_g, docs_g)
+            local, bad_p, bad_b, _live = fast[0](chunks_g, docs_g)
             state, evicted, ev_counts = fast[1](state, local)
             flags = round_fn(
                 jax.make_array_from_process_local_data(
@@ -2366,7 +2368,7 @@ def _stream_multihost(cfg: Config, app: App, inputs, stats, acc, dictionary) -> 
             stats.shuffle_wire_bytes += wire_bytes_per_round(d, tier_cap)
             with _a2a_span(stats, round=stats.mesh_rounds, tier="replay",
                            wire_bytes=wire_bytes_per_round(d, tier_cap)):
-                local, _p, _b = fns[0](chunks_g, docs_g)
+                local, _p, _b, _live = fns[0](chunks_g, docs_g)
                 state, evicted2, ev2 = fns[1](state, local)
             # Fetch + fold outside the a2a block (rare: own fetch) — the
             # blocking shard read must not inflate all_to_all_s.
@@ -2469,13 +2471,10 @@ def _finish_mesh_state(app: App, mesh, state, stats, acc) -> None:
     bytes the device doesn't have)."""
     from mapreduce_rust_tpu.parallel.shuffle import shard_fill_counts
 
-    try:
-        # Per-chip final distinct-key counts: the hash-class skew signal
-        # the doctor scores (a hot shard here means one chip's merge and
-        # egress carry the job). One readback at finalize, off the stream.
-        stats.mesh_shard_rows = shard_fill_counts(state)
-    except Exception:
-        pass  # telemetry stays best-effort
+    # Per-chip final distinct-key counts: the hash-class skew signal the
+    # doctor scores, and what shows each key on exactly one shard. One
+    # readback at finalize, off the stream; a failed readback fails the job.
+    stats.mesh_shard_rows = shard_fill_counts(state)
     k = app.device_select_k
     with stats.timed("state_readback_s", "state.readback"):
         if k and stats.spill_events == 0:
@@ -2735,15 +2734,17 @@ def _stream_mesh(cfg: Config, app: App, inputs, stats, acc, dictionary) -> None:
         stats.shuffle_wire_bytes += wire_bytes_per_round(d, tier_cap)
         with _a2a_span(stats, round=stats.mesh_rounds, tier="replay",
                        wire_bytes=wire_bytes_per_round(d, tier_cap)):
-            local, _, _ = fns[0](chunks_dev, docs_dev)
+            local, _, _, live = fns[0](chunks_dev, docs_dev)
             state, evicted, ev_counts = fns[1](state, local)
         # Blocking readback + spill fold OUTSIDE the a2a block: they are
         # device-wait/host work, and inside they would inflate all_to_all_s
         # — the ICI numerator — with non-interconnect time.
         t0 = time.perf_counter()
         with trace_span("device.drain", steps=1):
-            ev_n = int(np.asarray(jax.device_get(ev_counts)).sum())
+            ev_arr, live_arr = jax.device_get([ev_counts, live])
         stats.device_wait_s += time.perf_counter() - t0
+        ev_n = int(np.asarray(ev_arr).sum())
+        stats.shuffle_live_rows += int(np.asarray(live_arr).sum())
         if ev_n > 0:
             stats.spill_events += 1
             stats.spilled_keys += ev_n
@@ -2756,15 +2757,13 @@ def _stream_mesh(cfg: Config, app: App, inputs, stats, acc, dictionary) -> None:
         batch = [pending.popleft() for _ in range(n)]
         t0 = time.perf_counter()
         with trace_span("device.drain", steps=n):
-            flat = jax.device_get(
-                [x for (p, b, e, *_rest) in batch for x in (p, b, e)]
-            )
+            flat = jax.device_get([x for row in batch for x in row[:4]])
         dt = time.perf_counter() - t0
         stats.device_wait_s += dt
         stats.record_hist("device.drain_s", dt)
         _sample_device_memory(stats)
-        for (p, b, e, evicted, chunks_host, docs_host), p_arr, b_arr, e_arr in zip(
-            batch, flat[::3], flat[1::3], flat[2::3]
+        for (*_flags, evicted, chunks_host, docs_host), p_arr, b_arr, e_arr, l_arr in zip(
+            batch, flat[::4], flat[1::4], flat[2::4], flat[3::4]
         ):
             ev_n = int(np.asarray(e_arr).sum())
             if ev_n > 0:
@@ -2775,31 +2774,35 @@ def _stream_mesh(cfg: Config, app: App, inputs, stats, acc, dictionary) -> None:
             p_n = int(np.asarray(p_arr).sum())
             if p_n > 0 or int(np.asarray(b_arr).sum()) > 0:
                 replay_group(chunks_host, docs_host, p_n)
+            else:  # an overflowed group's rows count in its replay
+                stats.shuffle_live_rows += int(np.asarray(l_arr).sum())
 
     group_chunks: list[np.ndarray] = []
     group_docs: list[np.ndarray] = []
 
     def submit_group() -> None:
         nonlocal state, groups_done
-        while len(group_chunks) < d:  # pad the tail group with space chunks
-            group_chunks.append(np.full(cfg.chunk_bytes, 0x20, dtype=np.uint8))
-            group_docs.append(doc_table(0))
-        chunks_host = np.stack(group_chunks)
-        docs_host = np.stack(group_docs)
-        group_chunks.clear()
-        group_docs.clear()
+        with stats.timed("mesh_feed_s", "mesh.feed"):
+            while len(group_chunks) < d:  # pad the tail group with space chunks
+                group_chunks.append(np.full(cfg.chunk_bytes, 0x20, dtype=np.uint8))
+                group_docs.append(doc_table(0))
+            chunks_host = np.stack(group_chunks)
+            docs_host = np.stack(group_docs)
+            group_chunks.clear()
+            group_docs.clear()
+            chunks_dev = jax.device_put(chunks_host, in_shard)
+            docs_dev = jax.device_put(docs_host, in_shard)
         stats.mesh_rounds += 1
         stats.shuffle_wire_bytes += wire_bytes_per_round(d, bucket_cap)
         with _a2a_span(stats, round=stats.mesh_rounds, tier="fast",
                        wire_bytes=wire_bytes_per_round(d, bucket_cap)):
-            local, p_ovf, b_ovf = fast[0](
-                jax.device_put(chunks_host, in_shard), jax.device_put(docs_host, in_shard)
-            )
+            local, p_ovf, b_ovf, live = fast[0](chunks_dev, docs_dev)
             # Merge dispatches immediately — an overflowed group is empty on
             # device, so merging before the flags reach the host is safe.
             # Host arrays are kept for the rare replay, not device buffers.
             state, evicted, ev_counts = fast[1](state, local)
-            pending.append((p_ovf, b_ovf, ev_counts, evicted, chunks_host, docs_host))
+            pending.append((p_ovf, b_ovf, ev_counts, live, evicted,
+                            chunks_host, docs_host))
         groups_done += 1
         if (
             cfg.checkpoint_every_groups > 0

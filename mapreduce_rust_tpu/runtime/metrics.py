@@ -83,6 +83,11 @@ class JobStats:
     # records x 13 B (k1+k2+value+valid). This is what actually crosses the
     # interconnect (buckets are fixed-capacity under jit), so mesh runs can
     # attribute time to ICI vs compute before any multi-chip perf claim.
+    shuffle_live_rows: int = 0    # valid bucket rows those rounds sent:
+    # each chip's update rows before the all_to_all, summed over chips and
+    # over the rounds whose result was kept (a group that overflowed and
+    # was replayed counts its replay's rows). Over shuffle_wire_bytes / 13,
+    # the share of the wire that carried records and not padding.
     accum_spill_runs: int = 0     # accrun-* disk runs the accumulator's
                                   # budget tier wrote (counted at job end,
                                   # before the run files are deleted — the
@@ -188,6 +193,9 @@ class JobStats:
     # included) — the ICI-vs-compute split's numerator: with the per-round
     # wire bytes (shuffle_wire_bytes) this attributes mesh time to the
     # interconnect before any multi-chip perf claim
+    mesh_feed_s: float = 0.0      # "mesh.feed": the chunked mesh stream
+    # stacking a group's host chunks and doc tables and putting both on
+    # the mesh, once a group, outside the mesh.all_to_all block
     host_arena_bytes: int = 0     # native scan scratch resident across ALL
     # scan threads at job end (native/host.arena_bytes): the memory price
     # of host_map_workers, flat per thread by construction

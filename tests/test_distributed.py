@@ -45,7 +45,7 @@ _WORKER = textwrap.dedent(
     tables = np.stack([doc_table(0)] * nloc)
     docs_g = jax.make_array_from_process_local_data(
         sh, tables, global_shape=(d, tables.shape[1]))
-    local, p_ovf, b_ovf = fns[0](chunks_g, docs_g)
+    local, p_ovf, b_ovf, _live = fns[0](chunks_g, docs_g)
     state, evicted, ev = fns[1](state, local)
     n_local_keys = sum(
         int(np.asarray(s.data).sum()) for s in state.valid.addressable_shards

@@ -133,8 +133,11 @@ def test_shuffle_partitions_keys_by_hash_class():
     for i, t in enumerate(texts):
         chunks[i, : len(t)] = np.frombuffer(t, dtype=np.uint8)
     tables = np.stack([doc_table(0)] * 4)
-    local, p_ovf, b_ovf = fns[0](chunks, tables)
+    local, p_ovf, b_ovf, live = fns[0](chunks, tables)
     assert int(np.sum(p_ovf)) == 0 and int(np.sum(b_ovf)) == 0
+    # Each chip's valid bucket rows before the all_to_all: its chunk's
+    # distinct words (12, 4, 2, 3).
+    assert np.asarray(live).tolist() == [12, 4, 2, 3]
     k1 = np.asarray(local.k1)
     valid = np.asarray(local.valid)
     total = 0

@@ -4,6 +4,7 @@ state readback and teardown are spans with ``JobStats`` fields of their
 own, and the off path builds no annotation."""
 
 import collections
+import dataclasses
 import glob
 
 import jax
@@ -129,6 +130,27 @@ def test_span_builds_an_annotation_only_when_someone_collects(
         stop_tracing()
     assert _CountingAnnotation.made == built
     assert (len(tr) if tr is not None else 0) == (50 if mode == "tracer" else 0)
+
+
+def test_mesh_feed_span_once_per_group(tmp_path):
+    """The chunked mesh stream feeds each group of chunks in one
+    "mesh.feed" span, beside (not inside) the round's mesh.all_to_all."""
+    cfg, inputs = tiny_job(tmp_path, "mesh")
+    cfg = dataclasses.replace(cfg, chunk_bytes=512)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path / "prof"), profiler_options=opts)
+    try:
+        res = run_job(cfg, inputs)
+    finally:
+        jax.profiler.stop_trace()
+    assert res.table == oracle()
+    s = res.stats
+    groups = -(-s.chunks_dispatched // 4)
+    assert groups >= 2 and s.mesh_rounds == groups
+    counts = host_span_counts(tmp_path / "prof")
+    assert counts["mesh.feed"] == counts["mesh.all_to_all"] == groups
+    assert 0 < s.mesh_feed_s <= s.phase_seconds["stream"]
 
 
 @pytest.mark.parametrize("engine", ["device", "host", "mesh"])
