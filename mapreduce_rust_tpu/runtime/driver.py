@@ -361,22 +361,49 @@ def _unpack_rows(packed: np.ndarray, vals: np.ndarray) -> np.ndarray:
     ])
 
 
-def _combine_rows(op: str, keys: np.ndarray, vals: np.ndarray) -> np.ndarray:
-    """The shared fold kernel: (keys [n,2], vals [n]) → sorted deduped
-    rows [m, 3], value-keyed for "distinct", else folded per key. All key
-    work happens on the packed 1-D column (see _pack_key_cols)."""
+def _sort_key_value(packed: np.ndarray, vals: np.ndarray):
+    """(packed, vals) in (key, value) order, and the rows the value repair
+    moved. Every batch the fold sees is a sorted run (the device merge
+    sorts by (key, value); evicted tails and readbacks keep that order), so
+    one stable key sort — a timsort that merges the runs it finds — does
+    most of the work. Only keys whose values still descend after it (a
+    spill cut inside a key, runs that share keys) get their values sorted,
+    by a lexsort of those keys' rows alone. Exact for any input; only the
+    speed depends on its order."""
+    order = np.argsort(packed, kind="stable")
+    p_s, v_s = packed[order], vals[order]
+    same = p_s[1:] == p_s[:-1]
+    desc = same & (v_s[1:] < v_s[:-1])
+    if not desc.any():
+        return p_s, v_s, 0
+    # The rows of every key with a descent, found from its ends alone.
+    bad = np.unique(p_s[1:][desc])
+    start = np.searchsorted(p_s, bad, side="left")
+    size = np.searchsorted(p_s, bad, side="right") - start
+    g = np.repeat(np.arange(len(bad)), size)  # key index: 0 .. keys - 1
+    rows = np.arange(len(g)) + np.repeat(start - (np.cumsum(size) - size), size)
+    sub = v_s[rows]
+    fixed = sub[np.lexsort((sub, g))]
+    v_s[rows] = fixed
+    return p_s, v_s, int(np.count_nonzero(fixed != sub))
+
+
+def _combine_rows(op: str, keys: np.ndarray, vals: np.ndarray):
+    """The shared fold kernel: (keys [n,2], vals [n]) → (sorted deduped
+    rows [m, 3], rows the "distinct" value repair moved), value-keyed for
+    "distinct", else folded per key. All key work happens on the packed
+    1-D column (see _pack_key_cols)."""
     packed = _pack_key_cols(keys)
     if op == "distinct":
         # Sort by (key, value) then mask repeats — same output order as
         # np.unique over (k1, k2, value) rows, minus the structured sort.
-        order = np.lexsort((vals, packed))
-        p_s, v_s = packed[order], vals[order]
+        p_s, v_s, moved = _sort_key_value(packed, vals)
         if len(p_s):
             keep = np.empty(len(p_s), dtype=bool)
             keep[0] = True
             keep[1:] = (p_s[1:] != p_s[:-1]) | (v_s[1:] != v_s[:-1])
             p_s, v_s = p_s[keep], v_s[keep]
-        return _unpack_rows(p_s, v_s)
+        return _unpack_rows(p_s, v_s), moved
     uniq, inv = np.unique(packed, return_inverse=True)
     inv = inv.reshape(-1)
     if op == "sum":
@@ -388,7 +415,7 @@ def _combine_rows(op: str, keys: np.ndarray, vals: np.ndarray) -> np.ndarray:
     else:
         folded = np.full(len(uniq), np.iinfo(np.int64).max)
         np.minimum.at(folded, inv, vals)
-    return _unpack_rows(uniq, folded)
+    return _unpack_rows(uniq, folded), 0
 
 
 def _key_starts(packed: np.ndarray) -> np.ndarray:
@@ -401,11 +428,12 @@ def _key_starts(packed: np.ndarray) -> np.ndarray:
     return np.flatnonzero(np.r_[True, packed[1:] != packed[:-1]])
 
 
-def _combine_pending(op: str, keys_list, vals_list) -> np.ndarray:
+def _combine_pending(op: str, keys_list, vals_list):
     """Combine pending (keys, vals) batches into sorted deduped rows
-    [n, 3] (k1, k2, value) — value-keyed for "distinct", else folded.
-    Module-level and pure so the async spill writer can run it against a
-    frozen snapshot off the consumer thread (ISSUE 11)."""
+    [n, 3] (k1, k2, value) — value-keyed for "distinct", else folded —
+    and the rows the value repair moved (_combine_rows). Module-level and
+    pure so the async spill writer can run it against a frozen snapshot
+    off the consumer thread."""
     return _combine_rows(op, np.concatenate(keys_list),
                          np.concatenate(vals_list))
 
@@ -449,6 +477,10 @@ class HostAccumulator:
         self._table: dict | None = None
         self._run_token = new_run_token()
         self._writer = None
+        # Rows the "distinct" value repair moved, over every combine of
+        # this accumulator (JobStats.acc_fold_repaired_rows): 0 where the
+        # batches arrived in (key, value) order.
+        self.repaired_rows = 0
 
     def add(self, keys: np.ndarray, vals: np.ndarray) -> None:
         keys = np.asarray(keys, dtype=np.int64).reshape(-1, 2)
@@ -476,7 +508,9 @@ class HostAccumulator:
     def _pending_rows(self) -> np.ndarray:
         """Combine the in-RAM pending batches into sorted deduped rows
         [n, 3] (k1, k2, value) — value-keyed for "distinct", else folded."""
-        return _combine_pending(self.op, self._keys, self._vals)
+        rows, moved = _combine_pending(self.op, self._keys, self._vals)
+        self.repaired_rows += moved
+        return rows
 
     def _clear_pending(self) -> None:
         self._keys.clear()
@@ -516,8 +550,10 @@ class HostAccumulator:
 
         def task() -> int:
             with trace_span("accumulator.flush_run", run=run_index):
-                rows = _combine_pending(op, keys, vals)
+                rows, moved = _combine_pending(op, keys, vals)
                 written = write_npy_run(path, rows, run_index=run_index)
+            # The consumer reads and adds to this only after drain_spills().
+            self.repaired_rows += moved
             log.info("host accumulator: spilled run %d (%d rows)",
                      run_index + 1, len(rows))
             return written
@@ -551,12 +587,14 @@ class HostAccumulator:
         self.close_spills(abort=True)
         remove_run_files(self._runs)
 
-    def _combine_sorted(self, a: np.ndarray, b: np.ndarray) -> np.ndarray:
-        """Merge two sorted deduped [n,3] row arrays into one (the same
+    def _combine_sorted(self, *parts: np.ndarray) -> np.ndarray:
+        """Merge sorted deduped [n,3] row arrays into one (the same
         packed-column kernel as the pending fold — one implementation, so
         the run merge and the pending combine cannot order differently)."""
-        rows = np.concatenate([a, b])
-        return _combine_rows(self.op, rows[:, :2], rows[:, 2])
+        rows = np.concatenate(parts)
+        rows, moved = _combine_rows(self.op, rows[:, :2], rows[:, 2])
+        self.repaired_rows += moved
+        return rows
 
     def fold_arrays(self) -> np.ndarray:
         """The exact fold as sorted rows [n, 3] (k1, k2, value) — one row
@@ -564,7 +602,9 @@ class HostAccumulator:
         ("distinct"). Runs merge through a binary-counter tree (LSM-style:
         equal-size partials merge first), so a K-run fold costs
         O(total log K) combine work instead of re-combining the full
-        accumulated result once per run; peak memory stays O(result)."""
+        accumulated result once per run; peak memory stays O(result).
+        Every entry is already folded, so one entry is the result as it
+        is, and the entries left over merge in one combine."""
         self.drain_spills()  # every enqueued run must be on disk first
         stack: list[tuple[int, np.ndarray]] = []  # (level, rows)
 
@@ -580,10 +620,9 @@ class HostAccumulator:
             push(np.load(path))
         if self._keys:
             push(self._pending_rows())
-        rows = np.empty((0, 3), np.int64)
-        for _, r in stack:
-            rows = self._combine_sorted(rows, r)
-        return rows
+        if len(stack) > 1:
+            return self._combine_sorted(*(rows for _, rows in stack))
+        return stack[0][1] if stack else np.empty((0, 3), np.int64)
 
     def snapshot(self) -> tuple[np.ndarray, np.ndarray]:
         """(keys [n,2], vals [n]) of everything accumulated so far — for
@@ -3092,8 +3131,8 @@ def run_job(
                     lookup = lambda k1, k2: full.get((k1, k2))  # noqa: E731
                 if is_distinct:
                     # Grouped, looked up and formatted inside this one span.
-                    parts = _posting_parts(cfg, app, acc.fold_arrays(), lookup,
-                                           stats, table)
+                    parts = _posting_parts(cfg, app, _timed_fold(acc, stats),
+                                           lookup, stats, table)
                 else:
                     stats.distinct_keys = len(acc.table)
                     items = []
@@ -3232,6 +3271,16 @@ def run_job(
     return JobResult(stats=stats, table=table, output_files=output_files)
 
 
+def _timed_fold(acc: HostAccumulator, stats: JobStats) -> np.ndarray:
+    """The job's egress fold, acc.fold_arrays(), as the span
+    "accumulator.fold" (acc_fold_s), with the rows its value repair moved
+    copied into acc_fold_repaired_rows."""
+    with stats.timed("acc_fold_s", "accumulator.fold"):
+        rows = acc.fold_arrays()
+    stats.acc_fold_repaired_rows = acc.repaired_rows
+    return rows
+
+
 def _postings_span(app: App, stats: JobStats):
     """"egress.postings" (posting_format_s), once a job in either egress
     tier: a distinct app's grouping of (term, doc) rows into posting
@@ -3294,7 +3343,7 @@ def _stream_finalize(cfg: Config, app: App, stats: JobStats, acc: HostAccumulato
     ledger = active_ledger()
 
     with stats.phase("finalize"):
-        rows = acc.fold_arrays()  # sorted by (k1, k2[, value])
+        rows = _timed_fold(acc, stats)  # sorted by (k1, k2[, value])
         is_distinct = app.combine_op == "distinct"
         packed_rows = _pack_key_cols(rows[:, :2])
         n = len(rows)

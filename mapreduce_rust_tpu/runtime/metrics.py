@@ -111,6 +111,12 @@ class JobStats:
     # evicted state tails device → host accumulator (drain, replays)
     posting_format_s: float = 0.0  # "egress.postings": a distinct app's
     # grouping of (term, doc) rows and posting-list formatting, once a job
+    acc_fold_s: float = 0.0       # "accumulator.fold": the egress fold of
+    # the host accumulator's batches and runs into sorted rows, inside
+    # "egress.postings" in the in-RAM tier of a distinct app
+    acc_fold_repaired_rows: int = 0  # rows that fold's value repair moved
+    # (keys whose values still descended after the key sort), summed over
+    # the job's combines: 0 where every batch arrived in (key, value) order
     job_teardown_s: float = 0.0   # "job.teardown": run_job's finally
     # (spill cleanup, profiler/ledger stop, manifest flush) — the manifest
     # it writes is flushed inside this interval, so it reads 0 there

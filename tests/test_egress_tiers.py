@@ -328,3 +328,146 @@ def test_dictionary_spill_dedup_and_iter_sorted(tmp_path):
     assert got == want
     packed = [p for p, *_ in tiered.iter_sorted()]
     assert packed == sorted(packed) and len(set(packed)) == len(packed)
+
+
+# ---- the host fold: one stable key sort over sorted runs, one combine ----
+
+def _sorted_rows(rng, n_keys, n_rows, lo=0, hi=96):
+    """Distinct (k1, k2, value) rows in (key, value) order, as the device
+    merge leaves them: keys uint32-ranged hash lanes, values doc ids."""
+    keys = rng.integers(0, 2**32, size=(n_keys, 2))
+    rows = np.column_stack([keys[rng.integers(0, n_keys, n_rows)],
+                            rng.integers(lo, hi, n_rows)])
+    return np.unique(rows, axis=0)  # (k1, k2, value) order == packed order
+
+
+def _cut_at_keys(rows, parts):
+    """Split sorted rows into ``parts`` runs that share no key."""
+    edges = np.flatnonzero(np.r_[True, np.any(rows[1:, :2] != rows[:-1, :2], axis=1)])
+    cuts = edges[np.linspace(0, len(edges), parts + 1, dtype=int)[1:-1]]
+    return np.split(rows, cuts)
+
+
+def _fold_case(case, rng):
+    """The batches (sorted runs or not) a HostAccumulator is fed."""
+    if case == "empty":
+        return []
+    if case == "single_run":
+        return [_sorted_rows(rng, 60, 400)]
+    if case == "sorted_disjoint":
+        runs = _cut_at_keys(_sorted_rows(rng, 80, 600), 5)
+        return [runs[i] for i in (3, 0, 4, 1, 2)]
+    if case == "shared_keys":
+        # Two runs over the same keys, docs interleaved: even docs in one,
+        # odd in the other, and every fourth row in both.
+        rows = _sorted_rows(rng, 40, 800)
+        odd = rows[:, 2] % 2 == 1
+        return [rows[odd | (np.arange(len(rows)) % 4 == 0)], rows[~odd]]
+    if case == "mid_key_cut":
+        # A spill cut inside a key: the evicted tail (that key's larger
+        # docs) reaches the accumulator before the state (its smaller ones).
+        rows = _sorted_rows(rng, 30, 500)
+        key = rows[len(rows) // 2, :2]
+        cut = np.flatnonzero((rows[:, :2] == key).all(axis=1))[0] + 1
+        return [rows[cut:], rows[:cut]]
+    if case == "unsorted":
+        keys = rng.integers(0, 2**32, size=(25, 2))
+        rows = np.column_stack([keys[rng.integers(0, 25, 700)],
+                                rng.integers(0, 40, 700)])
+        return np.array_split(rows, 3)  # repeats and all, no order
+    if case == "negative_and_wide":
+        # Values below 0 and past 2**32, in runs that share keys.
+        rows = _sorted_rows(rng, 20, 600, lo=-(2**40), hi=2**40)
+        return [rows[1::2], rows[::2]]
+    if case == "full_int64_range":
+        # Values over the whole int64 range, both ends included.
+        rows = _sorted_rows(rng, 20, 600, lo=-(2**63), hi=2**63 - 1)
+        rows[:4, 2] = [-(2**63), 2**63 - 1, -1, 2**32]
+        return [rows[1::2], rows[::2], rows[:5]]
+    raise AssertionError(case)
+
+
+def _plain_fold(op, batches):
+    """The reference: rows sorted by (k1, k2, value) with np.lexsort and
+    deduplicated ("distinct"), else a dict fold per key, sorted by key."""
+    rows = np.concatenate(batches) if batches else np.empty((0, 3), np.int64)
+    if op == "distinct":
+        rows = rows[np.lexsort((rows[:, 2], rows[:, 1], rows[:, 0]))]
+        keep = np.r_[True, np.any(rows[1:] != rows[:-1], axis=1)][:len(rows)]
+        return rows[keep].astype(np.int64)
+    f = {"sum": lambda a, b: a + b, "max": max, "min": min}[op]
+    fold: dict = {}
+    for a, b, v in rows.tolist():
+        fold[(a, b)] = f(fold[(a, b)], v) if (a, b) in fold else v
+    return np.array([(a, b, v) for (a, b), v in sorted(fold.items())],
+                    dtype=np.int64).reshape(-1, 3)
+
+
+FOLD_CASES = [("distinct", c) for c in (
+    "sorted_disjoint", "shared_keys", "mid_key_cut", "unsorted",
+    "negative_and_wide", "full_int64_range", "empty", "single_run")] + [
+    (op, c) for op in ("sum", "max", "min") for c in ("sorted_disjoint", "unsorted")]
+
+
+@pytest.mark.parametrize("op,case", FOLD_CASES)
+def test_combine_rows_matches_lexsort_reference(op, case):
+    """_combine_rows over the concatenated batches, and HostAccumulator
+    fed them one by one, give exactly the plain reference's rows: the
+    same rows in the same order, int64, whatever the input's order."""
+    from mapreduce_rust_tpu.runtime.driver import _combine_rows
+
+    batches = [b.astype(np.int64) for b in _fold_case(case, np.random.default_rng(5))]
+    want = _plain_fold(op, batches)
+    cat = np.concatenate(batches) if batches else np.empty((0, 3), np.int64)
+    got, _moved = _combine_rows(op, cat[:, :2], cat[:, 2])
+    assert got.dtype == np.int64 and np.array_equal(got, want)
+    acc = HostAccumulator(op)
+    for b in batches:
+        acc.add(b[:, :2], b[:, 2])
+    folded = acc.fold_arrays()
+    assert folded.dtype == np.int64 and np.array_equal(folded, want)
+
+
+@pytest.mark.parametrize("op", ["distinct", "sum", "max", "min"])
+def test_fold_arrays_combines_once(tmp_path, monkeypatch, op):
+    """With only pending batches the fold is one combine; with three disk
+    runs plus pending ones it still equals the plain reference."""
+    from mapreduce_rust_tpu.runtime import driver
+
+    rng = np.random.default_rng(11)
+    batches = [b.astype(np.int64) for b in _fold_case("shared_keys", rng)
+               + _fold_case("unsorted", rng)]
+    calls = []
+    real = driver._combine_rows
+    monkeypatch.setattr(driver, "_combine_rows",
+                        lambda *a: calls.append(a[0]) or real(*a))
+    acc = HostAccumulator(op)
+    for b in batches:
+        acc.add(b[:, :2], b[:, 2])
+    assert np.array_equal(acc.fold_arrays(), _plain_fold(op, batches))
+    assert calls == [op]
+    # Seven equal batches over a budget of one: every second add writes a
+    # run, so three runs on disk and the seventh batch pending.
+    rows = _sorted_rows(rng, 50, 2000)[:7 * 100]
+    seven = [rows[i::7] for i in range(7)]
+    tiered = HostAccumulator(op, budget_bytes=100 * 24, spill_dir=str(tmp_path),
+                             async_spill=False)
+    for b in seven:
+        tiered.add(b[:, :2], b[:, 2])
+    assert tiered.run_count == 3 and len(tiered._keys) == 1
+    assert np.array_equal(tiered.fold_arrays(), _plain_fold(op, seven))
+    tiered.remove_runs()
+
+
+def test_fold_repaired_rows_counts_the_value_repair():
+    """acc.repaired_rows (JobStats.acc_fold_repaired_rows) is 0 where the
+    runs arrive in (key, value) order, and counts the rows the repair moved
+    after a spill's mid-key cut."""
+    rng = np.random.default_rng(2)
+    for case, moved in (("sorted_disjoint", False), ("single_run", False),
+                        ("mid_key_cut", True)):
+        acc = HostAccumulator("distinct")
+        for b in _fold_case(case, rng):
+            acc.add(b[:, :2], b[:, 2])
+        acc.fold_arrays()
+        assert (acc.repaired_rows > 0) == moved, case

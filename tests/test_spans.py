@@ -184,7 +184,8 @@ def test_dispatch_counters_spill_and_posting_spans(tmp_path, tier):
     """chunks_dispatched and chunk_payload_bytes count what the device
     engine sent; spill_evict_s times the "spill" span of the stream's
     evictions and posting_format_s the "egress.postings" span of a distinct
-    app's egress, once a job in either egress tier, both seen on the
+    app's egress, once a job in either egress tier, and acc_fold_s the
+    "accumulator.fold" span of that egress's fold, all seen on the
     profiler's host plane."""
     from mapreduce_rust_tpu.apps import InvertedIndex
 
@@ -204,9 +205,11 @@ def test_dispatch_counters_spill_and_posting_spans(tmp_path, tier):
     assert s.spill_events > 0 and s.spill_evict_s > 0
     assert s.posting_format_s > 0
     assert s.posting_format_s <= s.phase_seconds["finalize"] + s.phase_seconds["egress"]
+    assert 0 < s.acc_fold_s <= s.phase_seconds["finalize"] + s.phase_seconds["egress"]
     assert s.distinct_keys > 0
     spans = host_span_counts(tmp_path / "prof")
     assert spans["spill"] > 0 and spans["egress.postings"] == 1
+    assert spans["accumulator.fold"] == 1
     # Word count groups no postings and, here, spills nothing.
     (tmp_path / "wc").mkdir()
     w = run_job(*tiny_job(tmp_path / "wc", "device")).stats
